@@ -41,14 +41,19 @@ open('/tmp/requirements.txt', 'w').write('\n'.join(deps) + '\n')" \
 
 COPY albedo_tpu ./albedo_tpu
 COPY tests ./tests
-COPY bench.py __graft_entry__.py Makefile ./
+COPY bench.py chip_smoke.py __graft_entry__.py Makefile ./
 
 RUN pip install --no-cache-dir --no-deps -e .
 
-# Artifacts (loadOrCreate parquet/npz cache, Orbax checkpoints, the
-# persistent XLA executable cache) live under one mountable volume, the
-# dataDir convention (settings/package.scala:12-13).
+# Artifacts (loadOrCreate parquet/npz cache, Orbax checkpoints) live under one
+# mountable volume, the dataDir convention (settings/package.scala:12-13).
+# The executable caches (XLA's persistent cache + the jax.export blobs) are
+# placed by JAX_COMPILATION_CACHE_DIR — on the same volume here, so they
+# survive the container; unset, they would land in /app/.jax-cache and die
+# with it (utils/compilation_cache.py: the code never derives the cache
+# directory from ALBEDO_DATA_DIR).
 ENV ALBEDO_DATA_DIR=/data
+ENV JAX_COMPILATION_CACHE_DIR=/data/jax-cache
 VOLUME /data
 
 # HTTP recommendation serving (app's web layer parity).

@@ -10,7 +10,7 @@ JOBS = popularity curation content train_als cv_als build_user_profile \
        tfidf_content ranking_mf collect_data drop_data sync_index serve play \
        run_pipeline datacheck run_stream build_bank
 
-.PHONY: $(JOBS) test test-all bench serve-bench datacheck-bench chaos \
+.PHONY: $(JOBS) test test-all bench chip-smoke serve-bench datacheck-bench chaos \
         chaos-serve chaos-stream chaos-elastic stream stream-bench dryrun \
         soak soak-smoke capacity-bench retrieval-bench lint lint-baseline \
         sanitize score score-bench loadgen chaos-load
@@ -54,8 +54,17 @@ sanitize:
 test-all:
 	$(PY) -m pytest tests/ -q
 
+# One process, JAX initialized once in it, no child process (a chip belongs
+# to one process at a time). Exits non-zero if ANY phase it ran failed.
 bench:
 	$(PY) bench.py
+
+# The quickest proof that the train -> serve path still starts on the chip
+# (README "Running on CPU and on the chip"). Needs a TPU: exits non-zero and
+# prints no result where JAX finds none. From the builders' sandbox:
+#   chiprun --chips 1 -- python chip_smoke.py
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # Online-engine scenario: micro-batched vs per-request throughput/p50/p99
 # under concurrent load (env knobs: ALBEDO_SERVE_USERS/ITEMS/CONCURRENCY/

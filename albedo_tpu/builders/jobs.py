@@ -264,7 +264,14 @@ class JobContext:
     def als_artifact_name(self, **kw) -> str:
         return self.artifact_name(self.als_key(**kw) + ".pkl")
 
-    def als_model(self, rank=50, reg=ALS_REG, alpha=ALS_ALPHA, iters=26):
+    def als_model(self, rank=50, reg=ALS_REG, alpha=ALS_ALPHA, iters=26, on_fit=None):
+        """The flagship ALS model, trained (or loaded from today's artifact).
+
+        ``on_fit(estimator, model)`` if given is called right after a REAL
+        fit — never on an artifact-store hit — with the estimator (its
+        ``last_fit_report``) and the still device-resident model, before the
+        factors round-trip through the store. ``chip_smoke.py`` reads the
+        admission verdict, compile source and shard placement there."""
         from albedo_tpu.models.als import ImplicitALS
 
         key = self.als_key(rank=rank, reg=reg, alpha=alpha, iters=iters)
@@ -279,14 +286,18 @@ class JobContext:
             )
             every, _, _ = self.checkpoint_opts()
             if every > 0:
-                return self.checkpointed_als(est, self.matrix(), key)
-            # Non-checkpointed fits still run under the divergence watchdog
-            # (check-final + one damped re-fit; utils.watchdog.guarded_fit).
-            from albedo_tpu.utils.watchdog import guarded_fit
+                model = self.checkpointed_als(est, self.matrix(), key)
+            else:
+                # Non-checkpointed fits still run under the divergence
+                # watchdog (check-final + one damped re-fit;
+                # utils.watchdog.guarded_fit).
+                from albedo_tpu.utils.watchdog import guarded_fit
 
-            model, trips = guarded_fit(est, self.matrix())
-            if trips:
-                self._cache.setdefault("watchdog_trips", []).extend(trips)
+                model, trips = guarded_fit(est, self.matrix())
+                if trips:
+                    self._cache.setdefault("watchdog_trips", []).extend(trips)
+            if on_fit is not None:
+                on_fit(est, model)
             return model
 
         if "als" not in self._cache:
@@ -808,30 +819,8 @@ def build_bank_job(args) -> int | None:
     _report("build_bank", "sources", float(len(bank.specs)), t0)
 
 
-@register_job("serve")
-def serve_job(args) -> None:
-    """The online inference engine over trained artifacts: micro-batched
-    top-k, optional two-stage candidate fan-out + LR re-rank, TTL result
-    cache, and the `/metrics` Prometheus plane (``albedo_tpu.serving``).
-
-    Extra flags: --port N (default 8080), --host ADDR (default 127.0.0.1;
-    use 0.0.0.0 inside containers), --duration SECONDS (0 = forever),
-    --no-batch (direct per-request GEMMs, the seed path), --no-warm (skip
-    pre-compiling the batch-shape ladder), --two-stage (register the
-    popularity + curation candidate sources and train/load the LR ranker
-    for online re-ranking), --cache-ttl SECONDS (default 30; 0 disables),
-    --max-batch N (default 64), --window-ms MS (batching window, default 2),
-    --reload-watch (poll the artifact store and hot-swap fresh run_pipeline
-    outputs through the validation gates), --reload-interval SECONDS (watch
-    poll period, default 10). SIGHUP triggers one validated reload
-    immediately (watched or not), and POST /admin/reload does the same over
-    HTTP — see the README live-ops runbook.
-    """
-    import signal
-
-    from albedo_tpu.recommenders import CurationRecommender, PopularityRecommender
-    from albedo_tpu.serving import HotSwapManager, RecommendationService, serve
-
+def serve_options(rest: list[str]) -> argparse.Namespace:
+    """The ``serve`` job's own flags (see :func:`serve_job`)."""
     extra = argparse.ArgumentParser()
     extra.add_argument("--port", type=int, default=8080)
     extra.add_argument("--host", default="127.0.0.1")
@@ -846,9 +835,20 @@ def serve_job(args) -> None:
     extra.add_argument("--reload-interval", type=float, default=10.0)
     extra.add_argument("--reload-require-stamp", action="store_true")
     extra.add_argument("--bank", action="store_true")
-    ns, _ = extra.parse_known_args(getattr(args, "_rest", []))
+    ns, _ = extra.parse_known_args(rest)
+    return ns
 
-    ctx = JobContext(args)
+
+def build_serving(ctx: JobContext, ns: argparse.Namespace):
+    """Assemble the online engine the way ``serve`` runs it: the
+    :class:`~albedo_tpu.serving.RecommendationService` over this context's
+    trained artifacts (two-stage sources, LR ranker and bank stage per
+    ``ns``) plus its hot-swap manager. One definition shared by
+    :func:`serve_job` and ``chip_smoke.py``; the caller starts the HTTP
+    server (``serving.serve``) and owns shutdown."""
+    from albedo_tpu.recommenders import CurationRecommender, PopularityRecommender
+    from albedo_tpu.serving import HotSwapManager, RecommendationService
+
     recommenders = None
     ranker = None
     bank_stage = None
@@ -909,6 +909,35 @@ def serve_job(args) -> None:
         watch_interval_s=ns.reload_interval,
         require_stamp=ns.reload_require_stamp,
     )
+    return service, manager
+
+
+@register_job("serve")
+def serve_job(args) -> None:
+    """The online inference engine over trained artifacts: micro-batched
+    top-k, optional two-stage candidate fan-out + LR re-rank, TTL result
+    cache, and the `/metrics` Prometheus plane (``albedo_tpu.serving``).
+
+    Extra flags: --port N (default 8080), --host ADDR (default 127.0.0.1;
+    use 0.0.0.0 inside containers), --duration SECONDS (0 = forever),
+    --no-batch (direct per-request GEMMs, the seed path), --no-warm (skip
+    pre-compiling the batch-shape ladder), --two-stage (register the
+    popularity + curation candidate sources and train/load the LR ranker
+    for online re-ranking), --cache-ttl SECONDS (default 30; 0 disables),
+    --max-batch N (default 64), --window-ms MS (batching window, default 2),
+    --reload-watch (poll the artifact store and hot-swap fresh run_pipeline
+    outputs through the validation gates), --reload-interval SECONDS (watch
+    poll period, default 10). SIGHUP triggers one validated reload
+    immediately (watched or not), and POST /admin/reload does the same over
+    HTTP — see the README live-ops runbook.
+    """
+    import signal
+
+    from albedo_tpu.serving import serve
+
+    ns = serve_options(getattr(args, "_rest", []))
+    ctx = JobContext(args)
+    service, manager = build_serving(ctx, ns)
     if ns.reload_watch:
         manager.start_watch()
     if hasattr(signal, "SIGHUP"):

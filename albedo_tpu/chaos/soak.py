@@ -397,13 +397,17 @@ def _cli_env(specs, extra_env=None) -> dict:
     env.pop("ALBEDO_FAULTS", None)
     if specs:
         env["ALBEDO_FAULTS"] = faults_env(specs)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # Drill children run on CPU, whatever platform the parent's environment
+    # names: a chip belongs to one process at a time, and a parent that has
+    # touched JAX holds it — a child that needed the chip would fail or
+    # hang. Only an explicit ``extra_env`` can place a child elsewhere.
+    env["JAX_PLATFORMS"] = "cpu"
     env.update(extra_env or {})
     return env
 
 
 def _run_cli(job: str, cli_args: list[str], specs, timeout: float,
-             extra_env=None) -> dict:
+             extra_env=None, keep_stdout: bool = False) -> dict:
     cmd = [sys.executable, "-m", "albedo_tpu.cli", job, *cli_args]
     t0 = time.time()
     try:
@@ -412,13 +416,20 @@ def _run_cli(job: str, cli_args: list[str], specs, timeout: float,
             env=_cli_env(specs, extra_env), timeout=timeout,
         )
         rc: int | str = proc.returncode
+        stdout = proc.stdout
         tail = (proc.stdout + proc.stderr)[-2000:]
     except subprocess.TimeoutExpired:
-        rc, tail = "timeout", ""
-    return {
+        rc, stdout, tail = "timeout", "", ""
+    rec = {
         "job": job, "rc": rc, "faults": [f"{s}:{k}@{a}" for s, k, a in specs],
         "wall_s": round(time.time() - t0, 1), "tail": tail,
     }
+    if keep_stdout:
+        # For callers that look up a job marker: the 2000-char tail of
+        # stdout+stderr loses it whenever the runtime is chatty on stderr
+        # (one XLA:CPU loader notice is longer than the window).
+        rec["stdout"] = stdout
+    return rec
 
 
 class _InProcessArm:
@@ -587,14 +598,14 @@ def _score_kill_resume_leg(
     source = str(tables_src or f"synthetic-{bool(getattr(args, 'small', False))}")
     tag = md5(source)[:10]
     kill = _run_cli("score_all", base, specs, timeout)
-    resume = _run_cli("score_all", [*base, "--resume"], [], timeout)
+    resume = _run_cli("score_all", [*base, "--resume"], [], timeout, keep_stdout=True)
     out_root = score_output_root(tag)
     violations: list[str] = []
     if kill["rc"] != KILL_CODE:
         violations.append(
             f"score kill leg exited {kill['rc']}, wanted {KILL_CODE}"
         )
-    resumed = "resume:" in resume["tail"]
+    resumed = "resume:" in resume["stdout"]
     if resume["rc"] != 0:
         violations.append(f"score resume leg exited {resume['rc']}")
     elif not resumed:

@@ -6,6 +6,7 @@ over ``mllib.RankingMetrics``) and the AUC check at
 """
 
 from albedo_tpu.evaluators.classification import area_under_roc
+from albedo_tpu.evaluators.normal_equations import normal_eq_residual
 from albedo_tpu.evaluators.ranking import (
     RankingEvaluator,
     UserItems,
@@ -22,6 +23,7 @@ __all__ = [
     "area_under_roc",
     "mean_average_precision",
     "ndcg_at_k",
+    "normal_eq_residual",
     "precision_at_k",
     "user_actual_items",
     "user_items_from_pairs",

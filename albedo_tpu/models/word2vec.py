@@ -30,6 +30,15 @@ from albedo_tpu.features.pipeline import Transformer, memo_map
 from albedo_tpu.parallel.mesh import DATA_AXIS, replicated
 from albedo_tpu.utils.aot import persistent_aot_executable
 
+# The Adam state rides the ``w2v_epoch`` program's signature, and optax's
+# state NamedTuples are types ``jax.export`` cannot name on its own: without
+# these registrations the export compiles but fails to serialize, and the
+# program never reaches the AOT disk layer the ALS and LR programs use.
+for _state in (optax.ScaleByAdamState, optax.EmptyState):
+    jax.export.register_namedtuple_serialization(
+        _state, serialized_name=f"optax.{_state.__name__}"
+    )
+
 
 def skipgram_pairs(
     ids: np.ndarray, lengths: np.ndarray, b: np.ndarray

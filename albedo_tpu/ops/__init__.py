@@ -1,4 +1,5 @@
-"""Device compute primitives (JAX/XLA; Pallas kernels under ``ops.pallas``).
+"""Device compute primitives: XLA programs written in ``jax.numpy``/``lax``
+(there are no Pallas kernels in the tree).
 
 This is the TPU-native replacement for the reference's netlib-BLAS hot loops
 (SURVEY.md section 2: the MLlib ALS normal-equation solves and the

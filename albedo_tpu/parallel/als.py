@@ -53,10 +53,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # 0.4.x spelling
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from albedo_tpu.datasets.ragged import Bucket, device_bucket
@@ -257,8 +254,11 @@ def _ring_solve(
     c1_full = alpha * val_l                      # (B_l, L); 0 on padding
     perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
     b_l = idx_l.shape[0]
-    corr0 = jnp.zeros((b_l, k, k), jnp.float32)
-    bvec0 = jnp.zeros((b_l, k), jnp.float32)
+    # The accumulators start as constants (unvarying over the mesh) but
+    # come back device-varying: shard_map's varying-axes check needs the
+    # loop carry's type fixed up front.
+    corr0 = jax.lax.pcast(jnp.zeros((b_l, k, k), jnp.float32), axis, to="varying")
+    bvec0 = jax.lax.pcast(jnp.zeros((b_l, k), jnp.float32), axis, to="varying")
 
     def phase(p, carry):
         src, corr, b_vec = carry

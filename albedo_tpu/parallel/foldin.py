@@ -46,10 +46,7 @@ import logging
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # 0.4.x spelling
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from albedo_tpu.ops.als import bucket_solve_body

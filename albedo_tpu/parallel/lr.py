@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from albedo_tpu.features.assembler import FeatureMatrix
+from albedo_tpu.ops.sparse_linear import expanded_batch
 from albedo_tpu.parallel.mesh import DATA_AXIS, pad_rows_to, row_sharded
 
 
@@ -36,21 +37,18 @@ def shard_feature_batch(
     n_shards = mesh.shape[axis]
     sharding = row_sharded(mesh, axis)
 
-    def put(x: np.ndarray, fill=0):
-        return jax.device_put(pad_rows_to(np.asarray(x), n_shards, fill=fill), sharding)
+    def put(x: np.ndarray):
+        return jax.device_put(pad_rows_to(np.asarray(x), n_shards), sharding)
 
-    # Expanded dense block: the row-sharded rectangle every device slices
-    # evenly (the factored vec layout would replicate the distinct vectors
-    # and shard only the rep gather — a later optimization; parity with the
-    # single-device fit is what matters here, and params/scales span the
-    # same logical width either way).
-    batch = {"dense": put(fm.expanded_dense().astype(np.float32))}
-    for f, v in fm.cat.items():
-        batch[f"cat:{f}"] = put(v)
-    for f in fm.bag_idx:
-        idx, val = fm.expanded_bag(f)  # per-row view of factored fields
-        batch[f"bag_idx:{f}"] = put(idx, fill=-1)
-        batch[f"bag_val:{f}"] = put(val)
+    # The expanded rectangle every device slices evenly (the factored vec
+    # layout would replicate the distinct vectors and shard only the rep
+    # gather — a later optimization; parity with the single-device fit is
+    # what matters here, and params/scales span the same logical width
+    # either way).
+    n_pad = -(-fm.n_rows // n_shards) * n_shards
+    batch = {
+        k: jax.device_put(v, sharding) for k, v in expanded_batch(fm, n_pad).items()
+    }
     y = put(np.asarray(labels, dtype=np.float32))
     w = put(np.asarray(weights, dtype=np.float32))
     return batch, y, w

@@ -19,15 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # 0.4.x spelling, where check_vma was still check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_04x
-
-    def shard_map(f, /, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_04x(f, **kwargs)
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from albedo_tpu.parallel.mesh import DATA_AXIS, ITEM_AXIS

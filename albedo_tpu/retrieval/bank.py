@@ -78,6 +78,15 @@ QUERY_FAULT = faults.site("retrieval.query")
 
 KINDS = ("user_rows", "item_mean")
 
+# Floor of the item_mean query-width ladder. Every More-Like-This provider in
+# the tree answers with at most top_k = 30 recent items
+# (``recommenders.base.recent_starred_provider``), so with the floor at
+# pow2(30) every request-sized query pads to ONE width and the request path
+# runs ONE fused program per source set — instead of compiling a new one for
+# each (history length, history length) combination it meets, seconds apiece
+# on a chip and each a ``bank_timeout``. Longer queries still ladder up.
+_MIN_QUERY_WIDTH = 32
+
 
 def bank_artifact_name(tag: str) -> str:
     """The bank artifact naming convention (one definition: build job,
@@ -633,7 +642,7 @@ class RetrievalBank:
             )
             for q in queries
         ]
-        width = _pow2(max(1, max((r.size for r in rows), default=1)))
+        width = max(_MIN_QUERY_WIDTH, _pow2(max((r.size for r in rows), default=1)))
         out = np.full((len(queries), width), -1, dtype=np.int32)
         for b, r in enumerate(rows):
             out[b, : r.size] = r

@@ -142,6 +142,16 @@ class BankStage:
             })
         return frames
 
+    def warm(self) -> None:
+        """Answer one real query OFF the request path so the fused program
+        behind ``query_frames`` is compiled before a client's stage budget
+        is on the clock (the bank's wait budget is ~1 s; a cold compile on
+        a chip is longer, and would surface as ``bank_timeout`` on the first
+        requests). Raises on failure: a bank that cannot answer at boot is
+        a boot failure, not a degraded first request."""
+        if self.matrix.n_users:
+            self.query_frames(int(self.matrix.user_ids[0]), exclude_seen=True)
+
     # ----------------------------------------------------------- generations
 
     _INCUMBENT_MESH = object()  # sentinel: "build on the incumbent's mesh"

@@ -394,6 +394,23 @@ class TwoStagePipeline:
                 collect(fb_futs, frames)
         return frames
 
+    def warm(self, user_id: int, extra_sources: dict | None = None) -> None:
+        """Run one request's device work OFF the request path, with no stage
+        deadline, so the programs behind it — the bank's fused query, the
+        ranker's logits — are compiled before a client's budget is on the
+        clock. The batcher warms its own ladder; this is the rest of the
+        ``warm`` contract for the two-stage path. A failure here raises: a
+        stage that cannot answer at boot is a boot failure, not a
+        ``*_timeout`` tag on the first requests."""
+        if self.bank_stage is not None:
+            self.bank_stage.warm()
+        if self.ranker is None:
+            return
+        frames = self.candidates(int(user_id), [], extra_sources=extra_sources)
+        order = [n for n in self._source_order(frames) if len(frames[n])]
+        if order:
+            self.ranker.score(fuse_candidates([frames[n] for n in order]))
+
     def _rank(self, candidates: pd.DataFrame) -> pd.DataFrame:
         _RANK_FAULT.hit()
         return self.ranker.score(candidates)

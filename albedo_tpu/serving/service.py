@@ -211,6 +211,15 @@ class RecommendationService:
         )
         self.metrics.model_generation.set(self._generation.number)
         self._max_generation = self._generation.number
+        if warm and self.pipeline is not None and matrix is not None and matrix.n_users:
+            # The batcher warmed its ladder in build_generation; the
+            # two-stage path's other device programs (bank query, ranker
+            # logits) compile here, not under a client's stage deadline.
+            als_source = self._generation.als_source
+            self.pipeline.warm(
+                int(matrix.user_ids[0]),
+                extra_sources={"als": als_source} if als_source is not None else None,
+            )
 
     # ------------------------------------------------- generation plumbing
 

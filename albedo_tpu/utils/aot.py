@@ -47,17 +47,19 @@ verification can make that reuse safe). Three scoped defenses:
 
 from __future__ import annotations
 
+import collections
+import functools
 import hashlib
 import json
 import logging
 import os
-import threading
-
-from albedo_tpu.analysis.locksmith import named_lock
+import re
 import time
 from collections import OrderedDict
 from pathlib import Path
 from typing import Any
+
+from albedo_tpu.analysis.locksmith import named_lock
 
 log = logging.getLogger(__name__)
 
@@ -112,15 +114,46 @@ def disk_cache_enabled() -> bool:
 
 
 def export_dir() -> Path:
-    """Serialized-export directory — beside the artifact store, like the
-    persistent XLA cache, so ``drop_data``-style cleanup removes both."""
-    from albedo_tpu.settings import get_settings
+    """Serialized-export directory: a sub-directory of whichever compile-cache
+    directory is in force (``utils.compilation_cache.cache_dir``), so ONE
+    variable — ``JAX_COMPILATION_CACHE_DIR`` — places both on-disk
+    executable caches."""
+    from albedo_tpu.utils.compilation_cache import cache_dir
 
-    return get_settings().data_dir / "aot-export"
+    return cache_dir() / "aot-export"
+
+
+@functools.cache
+def _code_fingerprint() -> str:
+    """SHA-256 over this package's source files. The export layer is keyed
+    by an explicit signature, not by the program: without this a blob
+    written by an older checkout into a long-lived cache directory would
+    replay a STALE program after any edit to the traced code. (JAX's own
+    persistent cache is keyed by the HLO and needs no such guard.)"""
+    root = Path(__file__).resolve().parents[1]
+    h = hashlib.sha256()
+    for src in sorted(root.rglob("*.py")):
+        h.update(str(src.relative_to(root)).encode("utf-8"))
+        h.update(src.read_bytes())
+    return h.hexdigest()[:16]
 
 
 def signature_digest(key_parts: tuple) -> str:
-    return hashlib.sha256(repr(key_parts).encode("utf-8")).hexdigest()[:24]
+    keyed = repr((_code_fingerprint(), key_parts))
+    return hashlib.sha256(keyed.encode("utf-8")).hexdigest()[:24]
+
+
+# Which branch of the layer each acquired program took (memory hits are the
+# hot path and are not logged): what ``chip_smoke.py`` prints per program so
+# a bring-up can see whether export, serialization, the probe and the
+# cross-process reuse actually work on the device at hand.
+_BRANCHES: collections.deque = collections.deque(maxlen=512)
+
+
+def branch_log() -> list[dict]:
+    """Records ``{name, source, branch, compile_s, custom_calls}`` of every
+    non-memory executable acquisition in this process, oldest first."""
+    return list(_BRANCHES)
 
 
 def fingerprint_enabled() -> bool:
@@ -171,32 +204,26 @@ def _xla_persistent_cache_engaged() -> bool:
     boundaries (such programs never enter the jax.export disk layer)."""
     import jax
 
-    try:
-        return bool(jax.config.jax_enable_compilation_cache) and bool(
-            jax.config.jax_compilation_cache_dir
-        )
-    except AttributeError:  # pragma: no cover — much older jax
-        return False
+    return bool(jax.config.jax_enable_compilation_cache) and bool(
+        jax.config.jax_compilation_cache_dir
+    )
 
 
 def _compile_bypassing_xla_cache(jitted, args, dyn_kwargs, static_kwargs):
     """A provably-fresh compile: the persistent XLA cache is switched off
     for just this lower+compile, then restored.
 
-    jax 0.4.x latches the is-cache-used decision process-globally on first
-    compile, so flipping the config alone is a silent no-op — the latch must
-    be reset around the toggle (and again after, so every other program
-    keeps its cache). The toggle is serialized under a module lock:
+    jax latches the is-cache-used decision process-globally on first
+    compile (``compilation_cache._cache_checked``/``_cache_used``), so
+    flipping the config alone is a silent no-op — ``reset_cache`` clears the
+    latch around the toggle (and again after, so every other program keeps
+    its cache). The toggle is serialized under a module lock:
     overlapping bypassers would otherwise save each other's mid-toggle
     state and could leave the cache disabled process-wide. A concurrent
     NON-bypass compile during the window at worst misses the cache once
     (slower, never wrong)."""
     import jax
-
-    try:
-        from jax._src.compilation_cache import reset_cache as _reset_latch
-    except (ImportError, AttributeError):  # pragma: no cover — future jax
-        _reset_latch = lambda: None  # noqa: E731
+    from jax._src.compilation_cache import reset_cache as _reset_latch
 
     with _BYPASS_LOCK:
         prev = bool(jax.config.jax_enable_compilation_cache)
@@ -228,20 +255,33 @@ def _output_fingerprint(compiled, args: tuple, dyn_kwargs: dict) -> str:
     return h.hexdigest()
 
 
-def _has_custom_calls(exported) -> bool:
-    """True if the exported module embeds any ``stablehlo.custom_call``.
+# Custom-call targets that are compiler-internal ops, not foreign functions:
+# XLA lowers them itself on every backend and nothing in them binds a host
+# library or an opaque ``backend_config``, so they round-trip like any other
+# HLO. ``lax.top_k`` lowers to ``mhlo.topk`` on CPU AND TPU — without this
+# entry every top-k program (the whole serving batcher ladder, the retrieval
+# bank's query) was "memory cache only" and recompiled, XLA cache bypassed,
+# in every process: 33 s of boot on a v5e.
+_PORTABLE_CUSTOM_CALLS = frozenset({"mhlo.topk"})
+
+
+def _custom_call_targets(exported) -> list[str]:
+    """Targets of every NON-portable ``stablehlo.custom_call`` the exported
+    module embeds (``_PORTABLE_CUSTOM_CALLS`` are not counted).
 
     Custom calls are the unstable part of ``jax.export``: their backend
     configs are not guaranteed to survive a cross-process round trip (the
     CPU LAPACK ``lapack_spotrf`` of the Cholesky solver segfaults when a
-    deserialized module executes in a fresh process on jaxlib 0.4.x), so
-    any module containing one stays memory-cached only. TPU lowers the same
-    solves to pure HLO — no custom calls — and the CG fast path has none on
-    any backend, so the disk layer still covers the paths that matter.
+    deserialized module executes in a fresh process), so any module
+    containing one stays memory-cached only. TPU lowers the same solves to
+    pure HLO — no custom calls — and the CG fast path has none on any
+    backend, so the disk layer still covers the paths that matter.
     """
-    import re
-
-    return bool(re.search(r"stablehlo\.custom_call", exported.mlir_module()))
+    text = exported.mlir_module()
+    targets = re.findall(r"stablehlo\.custom_call\s*@([\w.$-]+)", text)
+    if not targets and "stablehlo.custom_call" in text:
+        targets = ["?"]  # generic-form op: present, target not parsed
+    return sorted(set(targets) - _PORTABLE_CUSTOM_CALLS)
 
 
 def persistent_aot_call(
@@ -306,6 +346,10 @@ def persistent_aot_executable(
         return compiled, 0.0, "memory"
 
     source = "compile"
+    branch: list[str] = []  # what happened on the way, for branch_log()
+    # Non-portable custom-call targets of the export: None = unknown (not
+    # exported, or the export failed), [] = none, else memory cache only.
+    targets: list[str] | None = None
     compiled = None
     path = export_dir() / f"{name}-{digest}.jaxexport" if disk_cache_enabled() else None
     t0 = time.perf_counter()
@@ -317,8 +361,8 @@ def persistent_aot_executable(
             restored = jax_export.deserialize(bytearray(path.read_bytes()))
             # Belt and braces: refuse to execute a blob with custom calls
             # even if one was written by hand/an older build (see
-            # _has_custom_calls — executing one can crash the process).
-            if _has_custom_calls(restored):
+            # _custom_call_targets — executing one can crash the process).
+            if _custom_call_targets(restored):
                 raise ValueError("serialized module contains custom calls")
             compiled = jax.jit(restored.call).lower(*args, **dyn_kwargs).compile()
             # Self-check: the deserialized executable must reproduce the
@@ -344,38 +388,49 @@ def persistent_aot_executable(
                         except OSError:
                             pass
                     compiled = None
+                    branch.append("disk-fingerprint-mismatch")
                 else:
                     source = "disk"
+                    branch.append("disk-verified")
             else:
                 source = "disk"
+                branch.append("disk-unverified")
         except Exception as e:  # noqa: BLE001
             # Stale/incompatible blob: fall through to a fresh compile, but
             # say so — a silently dead disk layer reads exactly like a cold
             # cache and the 13s cold compile returns unnoticed.
             log.warning("AOT export %s unusable (%r); recompiling", path.name, e)
             compiled = None
+            branch.append(f"disk-unusable:{type(e).__name__}")
 
     if compiled is None:
         source = "compile"
         exported = None
-        custom_calls: bool | None = None  # None = export failed, can't tell
+        if path is None:
+            branch.append("disk-layer-off")
         if path is not None:
             try:
                 from jax import export as jax_export
 
                 exported = jax_export.export(jitted)(*args, **dyn_kwargs, **static_kwargs)
-                custom_calls = _has_custom_calls(exported)
-                if custom_calls:
+                targets = _custom_call_targets(exported)
+                if targets:
                     log.debug("%s embeds custom calls; memory cache only", name)
                     exported = None  # not round-trip-safe: memory cache only
+                else:
+                    # Compile the SAME StableHLO a later disk hit will
+                    # deserialize: fresh-compile and round-trip runs execute
+                    # the identical program. (A multi-device export called
+                    # with arguments not yet laid out on its mesh cannot
+                    # lower this way — that is an export failure too.)
+                    compiled = jax.jit(exported.call).lower(*args, **dyn_kwargs).compile()
             except Exception as e:  # noqa: BLE001
                 log.warning("jax.export of %s failed (%r); disk AOT layer off "
                             "for this program", name, e)
                 exported = None
+                targets = None
+                branch.append(f"export-failed:{type(e).__name__}")
         if exported is not None:
-            # Compile the SAME StableHLO a later disk hit will deserialize:
-            # fresh-compile and round-trip runs execute the identical program.
-            compiled = jax.jit(exported.call).lower(*args, **dyn_kwargs).compile()
             wrote_export = False
             try:
                 # serialize() can fail beyond IO: a pytree node type with no
@@ -389,7 +444,9 @@ def persistent_aot_executable(
                 tmp.write_bytes(blob)
                 os.replace(tmp, path)
                 wrote_export = True
+                branch.append("exported")
             except Exception as e:  # noqa: BLE001
+                branch.append(f"serialize-failed:{type(e).__name__}")
                 if not isinstance(e, OSError):
                     log.warning(
                         "serializing AOT export of %s failed (%r); disk "
@@ -409,7 +466,9 @@ def persistent_aot_executable(
                     fp_tmp = fp_path.with_name(fp_path.name + f".tmp{os.getpid()}")
                     fp_tmp.write_text(json.dumps({"sha256": fp}))
                     os.replace(fp_tmp, fp_path)
+                    branch.append("fingerprinted")
                 except Exception as e:  # noqa: BLE001
+                    branch.append(f"probe-failed:{type(e).__name__}")
                     log.warning(
                         "probe fingerprint of %s failed (%r); removing the "
                         "unverifiable export", name, e,
@@ -418,12 +477,12 @@ def persistent_aot_executable(
                         path.unlink()
                     except OSError:
                         pass
-        elif custom_calls and fingerprint_enabled() and _xla_persistent_cache_engaged():
+        elif targets and fingerprint_enabled() and _xla_persistent_cache_engaged():
             # Known custom-call program (the CPU Cholesky fit). Custom calls
             # are the unstable part of EVERY serialization layer, not just
             # jax.export: the persistent XLA cache's deserialized executables
             # for this program class corrupted numerics NONDETERMINISTICALLY
-            # on CPU/jaxlib 0.4.x (sub-1e-3 drift up to all-NaN factors —
+            # on the CPU backend (sub-1e-3 drift up to all-NaN factors —
             # root-caused by the PR 4 kill-resume drills; a probe fingerprint
             # passes and the same executable then NaNs on real data, so
             # verification cannot make this reuse safe). Do what we already
@@ -437,8 +496,10 @@ def persistent_aot_executable(
             compiled = _compile_bypassing_xla_cache(
                 jitted, args, dyn_kwargs, static_kwargs
             )
+            branch.append("custom-call-bypassed-compile")
         else:
             compiled = jitted.lower(*args, **dyn_kwargs, **static_kwargs).compile()
+            branch.append("plain-compile")
             # Export-failed programs (custom-call status unknown) still ride
             # the persistent XLA cache across processes — guard that reuse
             # with the probe fingerprint: the first process (cold cache)
@@ -455,6 +516,7 @@ def persistent_aot_executable(
                 try:
                     got = _output_fingerprint(compiled, args, dyn_kwargs)
                 except Exception as e:  # noqa: BLE001 — probe must not kill the job
+                    branch.append(f"probe-failed:{type(e).__name__}")
                     log.warning(
                         "probe fingerprint of %s failed (%r); skipping "
                         "cross-process verification for this program", name, e,
@@ -478,6 +540,9 @@ def persistent_aot_executable(
                             compiled = _compile_bypassing_xla_cache(
                                 jitted, args, dyn_kwargs, static_kwargs
                             )
+                            branch.append("xla-cache-fingerprint-mismatch")
+                        else:
+                            branch.append("xla-cache-verified")
                     else:
                         # Baseline creation must be provably fresh: THIS
                         # process's compile may itself have been fed by a
@@ -493,6 +558,7 @@ def persistent_aot_executable(
                             )
                             baseline = _output_fingerprint(fresh, args, dyn_kwargs)
                         except Exception as e:  # noqa: BLE001
+                            branch.append(f"baseline-failed:{type(e).__name__}")
                             log.warning(
                                 "fresh baseline compile of %s failed (%r); "
                                 "skipping cross-process verification", name, e,
@@ -504,7 +570,9 @@ def persistent_aot_executable(
                             )
                             fp_tmp.write_text(json.dumps({"sha256": baseline}))
                             os.replace(fp_tmp, fp_path)
+                            branch.append("baseline-second-compile")
                             if got != baseline:
+                                branch.append("xla-cache-fingerprint-mismatch")
                                 from albedo_tpu.utils import events
 
                                 events.aot_fingerprint_mismatches.inc(name=name)
@@ -519,5 +587,9 @@ def persistent_aot_executable(
                     pass  # fingerprint bookkeeping is best-effort
     compile_s = time.perf_counter() - t0
 
+    _BRANCHES.append({
+        "name": name, "source": source, "branch": "+".join(branch),
+        "compile_s": round(compile_s, 3), "custom_calls": targets,
+    })
     _EXECUTABLES.put(mem_key, compiled)
     return compiled, compile_s, source

@@ -33,14 +33,16 @@ new ``oom`` kind forces the over-budget path (the injected
 chaos drills exercise the real degraded machinery without a 16 GB
 allocation.
 
-Budget detection order (per device):
+Budget detection (per device):
 
 1. ``ALBEDO_DEVICE_MEM_BYTES`` — explicit override, the CPU-CI knob and the
    chaos-drill pressure valve (suffixes k/m/g accepted).
-2. ``jax.local_devices()[0].memory_stats()["bytes_limit"]`` — what the TPU
-   runtime actually reports.
-3. ``/proc/meminfo`` MemTotal (CPU backends: host RAM is device RAM).
-4. 16 GiB (the v5e figure) when nothing above answers.
+2. On an accelerator: ``jax.local_devices()[0].memory_stats()
+   ["bytes_limit"]`` — what the runtime actually reports. A device that
+   reports none is an ERROR, never priced at the host's RAM or a guess: an
+   admission against the wrong budget is how an over-HBM workload gets
+   dispatched.
+3. On the CPU backend: ``/proc/meminfo`` MemTotal (host RAM is device RAM).
 
 ``ALBEDO_MEM_HEADROOM`` (default 0.85) scales the detected total into the
 admission budget; ``ALBEDO_CAPACITY=off`` disables admission entirely
@@ -67,7 +69,6 @@ _ENV_BYTES = "ALBEDO_DEVICE_MEM_BYTES"
 _ENV_HEADROOM = "ALBEDO_MEM_HEADROOM"
 _ENV_TOGGLE = "ALBEDO_CAPACITY"
 _DEFAULT_HEADROOM = 0.85
-_FALLBACK_BYTES = 16 << 30  # v5e per-chip HBM; the "no signal at all" anchor
 
 
 class CapacityExceeded(MemoryError):
@@ -107,22 +108,23 @@ def device_memory_bytes() -> int:
     raw = os.environ.get(_ENV_BYTES)
     if raw:
         return _parse_bytes(raw)
-    try:
-        import jax
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:  # noqa: BLE001 — detection must never be the crash
-        pass
-    try:
-        with open("/proc/meminfo") as f:
-            for line in f:
-                if line.startswith("MemTotal:"):
-                    return int(line.split()[1]) * 1024
-    except OSError:
-        pass
-    return _FALLBACK_BYTES
+    dev = jax.local_devices()[0]
+    if dev.platform != "cpu":
+        stats = dev.memory_stats()
+        if not stats or not stats.get("bytes_limit"):
+            raise RuntimeError(
+                f"{dev.platform} device {dev.device_kind!r} reports no "
+                f"memory_stats()['bytes_limit'] (got {stats!r}); set "
+                f"{_ENV_BYTES} to price admissions on this backend"
+            )
+        return int(stats["bytes_limit"])
+    with open("/proc/meminfo") as f:
+        for line in f:
+            if line.startswith("MemTotal:"):
+                return int(line.split()[1]) * 1024
+    raise RuntimeError(f"/proc/meminfo has no MemTotal; set {_ENV_BYTES}")
 
 
 def headroom() -> float:

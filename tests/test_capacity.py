@@ -32,10 +32,45 @@ class TestDetection:
         monkeypatch.setenv("ALBEDO_DEVICE_MEM_BYTES", "512m")
         assert capacity.device_memory_bytes() == 512 << 20
 
-    def test_detection_without_env_is_positive(self, monkeypatch):
+    def test_cpu_backend_reads_proc_meminfo(self, monkeypatch):
         monkeypatch.delenv("ALBEDO_DEVICE_MEM_BYTES", raising=False)
-        # CPU CI: memory_stats is absent -> /proc/meminfo or the fallback.
-        assert capacity.device_memory_bytes() > 1 << 20
+        with open("/proc/meminfo") as f:
+            total_kb = next(
+                int(line.split()[1]) for line in f if line.startswith("MemTotal:")
+            )
+        assert capacity.device_memory_bytes() == total_kb * 1024
+
+    @pytest.mark.parametrize("stats", [None, {}, {"bytes_in_use": 5}])
+    def test_accelerator_without_bytes_limit_raises(self, monkeypatch, stats):
+        """A chip that reports no ``bytes_limit`` must never be priced at the
+        host's RAM (or a guess): that is how an over-HBM workload gets a
+        ``fit`` verdict. The explicit override still answers."""
+
+        class FakeChip:
+            platform = "tpu"
+            device_kind = "TPU test"
+
+            def memory_stats(self):
+                return stats
+
+        monkeypatch.delenv("ALBEDO_DEVICE_MEM_BYTES", raising=False)
+        monkeypatch.setattr(jax, "local_devices", lambda: [FakeChip()])
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            capacity.device_memory_bytes()
+        monkeypatch.setenv("ALBEDO_DEVICE_MEM_BYTES", "16g")
+        assert capacity.device_memory_bytes() == 16 << 30
+
+    def test_accelerator_budget_is_the_reported_limit(self, monkeypatch):
+        class FakeChip:
+            platform = "tpu"
+            device_kind = "TPU test"
+
+            def memory_stats(self):
+                return {"bytes_limit": 15 << 30, "bytes_in_use": 0}
+
+        monkeypatch.delenv("ALBEDO_DEVICE_MEM_BYTES", raising=False)
+        monkeypatch.setattr(jax, "local_devices", lambda: [FakeChip()])
+        assert capacity.device_memory_bytes() == 15 << 30
 
     def test_budget_applies_headroom(self, monkeypatch):
         monkeypatch.setenv("ALBEDO_DEVICE_MEM_BYTES", "1000000")

@@ -488,3 +488,71 @@ def test_w2v_shared_negatives_clusters(w2v_clusters):
     across = np.mean([v[idx[x]] @ v[idx[y]] for x in a for y in b])
     assert within > 0.8, within
     assert across < 0.5, across
+
+
+def test_request_sized_inference_is_shape_stable(rng):
+    """Online re-rank batches differ in row count, distinct documents and
+    bag entries on every request. Inference must not compile a program per
+    request (seconds each on a chip, against a 0.5 s stage deadline): batches
+    up to the rectangle threshold share ONE executable per power-of-two row
+    bucket, and agree with the factored flat layout the large-batch path
+    (and training) uses."""
+    import jax as _jax
+
+    from albedo_tpu.models import logistic_regression as lrmod
+    from albedo_tpu.ops.sparse_linear import block_logits, expanded_batch, feature_batch
+    from albedo_tpu.utils import aot
+
+    # Every block kind at once: scalars, a factored vec field, a cat field,
+    # a factored bag field.
+    n, u, d_vec, u_docs, v = 300, 14, 5, 11, 9
+    vec = rng.normal(size=(u, d_vec)).astype(np.float32)
+    vec_rep = rng.integers(0, u, n).astype(np.int32)
+    doc_idx = np.stack(
+        [np.sort(rng.choice(v, 4, replace=False)) for _ in range(u_docs)]
+    ).astype(np.int32)
+    doc_idx[rng.random(doc_idx.shape) < 0.3] = -1
+    doc_val = np.where(doc_idx >= 0, rng.integers(1, 4, doc_idx.shape), 0).astype(np.float32)
+    scalars = rng.normal(size=(n, 2)).astype(np.float32)
+    fm = FeatureMatrix(
+        dense=scalars, dense_names=["a", "b"] + [f"v[{i}]" for i in range(d_vec)],
+        cat={"c": rng.integers(0, 4, n).astype(np.int32)}, cat_sizes={"c": 4},
+        bag_idx={"b": doc_idx}, bag_val={"b": doc_val}, bag_sizes={"b": v},
+        vec={"v": vec}, vec_rep={"v": vec_rep},
+        bag_rep={"b": rng.integers(0, u_docs, n).astype(np.int32)},
+    )
+    y = (scalars[:, 0] + vec[vec_rep][:, 0] + rng.normal(scale=0.3, size=n) > 0).astype(np.float32)
+    model = LogisticRegression(max_iter=30, reg_param=0.05).fit(fm, y)
+
+    def acquisitions():
+        return [r for r in aot.branch_log() if r["name"] == "lr_block_logits"]
+
+    first = model.decision_function(fm.select(np.arange(7)))
+    n_programs = len(acquisitions())
+    # Different row counts, different distinct sets: same 256-row bucket.
+    for rows in (np.arange(40, 173), np.arange(5, 9), np.arange(0, 256)):
+        out = model.decision_function(fm.select(rows))
+        assert out.shape == (rows.size,)
+    assert len(acquisitions()) == n_programs
+    # Parity: rectangle (padded) vs the factored flat layout, same rows.
+    sub = fm.select(np.arange(7))
+    flat = np.asarray(_jax.jit(block_logits)(
+        model.params, model.scales, feature_batch(sub), model.center
+    ))
+    rect = np.asarray(_jax.jit(block_logits)(
+        model.params, model.scales, expanded_batch(sub, 256), model.center
+    ))[:7]
+    np.testing.assert_allclose(rect, flat, atol=1e-5)
+    np.testing.assert_allclose(first, flat, atol=1e-5)
+    # Past the threshold the factored layout takes over (one compile per
+    # job, sized by the batch) — same numbers.
+    big = fm.select(np.arange(300))
+    want = model.decision_function(big)
+    monkey_max = lrmod._RECTANGLE_MAX_ROWS
+    lrmod._RECTANGLE_MAX_ROWS = 100
+    try:
+        np.testing.assert_allclose(model.decision_function(big), want, atol=1e-5)
+    finally:
+        lrmod._RECTANGLE_MAX_ROWS = monkey_max
+    with pytest.raises(ValueError):
+        expanded_batch(fm, 8)

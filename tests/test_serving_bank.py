@@ -271,3 +271,83 @@ def test_readiness_reports_bank_snapshot(server):
     snap = body["retrieval_bank"]
     assert snap["sources"] == ["als", "tfidf"]
     assert snap["generation"] == 1 and snap["version"]
+
+
+def test_query_width_floor_keeps_the_request_path_on_one_program(world):
+    """More-Like-This queries are a user's <= top_k recent stars, so history
+    length differs per request. The bank pads every such query to one floor
+    width: users with 1 star and users with 20 run the SAME fused program —
+    the request path must not compile one per history length (seconds each
+    on a chip, each a ``bank_timeout``)."""
+    from albedo_tpu.utils import aot
+
+    _tables, matrix, _model, _als, _tfidf, _pop = world
+    stage = _stage(world)
+    indptr, _cols, _ = matrix.csr()
+    lens = np.diff(indptr)
+    users = [int(matrix.user_ids[i]) for i in (np.argmin(lens), np.argmax(lens))]
+    assert lens.min() != lens.max()
+    stage.query_frames(users[0], exclude_seen=True)
+    n = len([r for r in aot.branch_log() if r["name"] == "retrieval_query"])
+    for uid in users + [int(u) for u in matrix.user_ids[:20]]:
+        frames = stage.query_frames(uid, exclude_seen=True)
+        assert set(frames) == {"als", "tfidf"}
+    assert len([r for r in aot.branch_log() if r["name"] == "retrieval_query"]) == n
+
+
+def test_warm_service_compiles_bank_and_ranker_before_the_first_request(world):
+    """``warm=True`` is the promise that no request pays a trace+compile.
+    For the two-stage path that covers the bank's fused query and the
+    ranker's logits as well as the batcher ladder: the first request after a
+    warm boot is a clean ``two_stage`` answer and acquires no executable."""
+    from albedo_tpu.utils import aot
+
+    _tables, matrix, model, _als, _tfidf, pop = world
+
+    class CountingRanker:
+        calls = 0
+
+        def score(self, candidates):
+            CountingRanker.calls += 1
+            out = candidates.copy()
+            out["probability"] = np.linspace(0.9, 0.1, len(out))
+            return out
+
+    service = RecommendationService(
+        model, matrix, recommenders={"popularity": pop},
+        ranker=CountingRanker(), bank_stage=_stage(world), warm=True,
+        default_k=K,
+    )
+    try:
+        assert service.batcher.warmed
+        assert CountingRanker.calls == 1  # the warm pass ran the ranker once
+        before = len(aot.branch_log())
+        status, body = service.handle_recommend(int(matrix.user_ids[3]))
+        assert status == 200 and body["stage"] == "two_stage"
+        assert body["degraded"] == []
+        assert len(aot.branch_log()) == before  # nothing compiled on the path
+        assert events.retrieval_fallbacks.total() == 0
+    finally:
+        service.close()
+
+
+def test_cold_service_does_not_warm(world):
+    _tables, matrix, model, _als, _tfidf, pop = world
+
+    class Ranker:
+        calls = 0
+
+        def score(self, candidates):
+            Ranker.calls += 1
+            out = candidates.copy()
+            out["probability"] = 0.5
+            return out
+
+    service = RecommendationService(
+        model, matrix, recommenders={"popularity": pop}, ranker=Ranker(),
+        bank_stage=_stage(world), warm=False, default_k=K,
+    )
+    try:
+        assert Ranker.calls == 0
+    finally:
+        service.close()

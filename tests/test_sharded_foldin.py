@@ -130,6 +130,25 @@ class TestRouting:
         assert len(set(pos.tolist())) == len(rows)
 
 
+# --- ring mode stays alive in tier-1 ------------------------------------------
+
+
+def test_ring_foldin_two_shards_matches_single_device(trained):
+    """Tier-1 pin for ``parallel.als._ring_solve`` under shard_map's
+    varying-axes check: the ring carry broke on a JAX upgrade while every
+    ring fold-in test sat in the slow lane, and ring mode was dead for the
+    sharded fit AND the mesh fold-in until a chip bring-up tripped over it.
+    Two shards is the smallest mesh whose ``ppermute`` actually moves data."""
+    matrix, model = trained
+    rows = _random_rows(matrix.n_items, 11, seed=5)
+    want = FoldInEngine(model, reg_param=REG, alpha=ALPHA, max_batch=16).fold_in(rows)
+    got = FoldInEngine(
+        model, reg_param=REG, alpha=ALPHA, max_batch=16,
+        mesh=make_mesh(2), shard_mode="ring",
+    ).fold_in(rows)
+    assert np.allclose(got, want, atol=1e-5), np.abs(got - want).max()
+
+
 # --- 1-device mesh parity -----------------------------------------------------
 
 # Everything below compiles shard_map programs (engine construction alone
