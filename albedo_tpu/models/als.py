@@ -37,7 +37,8 @@ from albedo_tpu.ops.als import als_fit_fused, als_init_fit_fused
 from albedo_tpu.ops.topk import topk_scores
 from albedo_tpu.utils import capacity as capacity_mod
 from albedo_tpu.utils import faults
-from albedo_tpu.utils.aot import persistent_aot_call, persistent_aot_executable
+from albedo_tpu.utils.aot import persistent_aot_executable
+from albedo_tpu.utils.profiling import Timer
 
 # Chaos hook for the chunked host-streamed fallback: fires ahead of every
 # chunked half-sweep, so drills can kill/fail a degraded fit mid-stream
@@ -311,7 +312,9 @@ class ImplicitALS:
             self.mesh, jax.default_backend(),
         )
 
-    def device_groups(self, matrix: StarMatrix) -> tuple[list[tuple], list[tuple], Any, Any]:
+    def device_groups(
+        self, matrix: StarMatrix, timer: Timer | None = None
+    ) -> tuple[list[tuple], list[tuple], Any, Any]:
         """(user_groups, item_groups, user_landing, item_landing) on device, as
         ``als_fit_fused`` consumes them — shared by ``fit`` and the bench's
         phase breakdown so both always measure the same shapes. Memoized per
@@ -331,8 +334,14 @@ class ImplicitALS:
         ``jax.device_put`` while later groups are still being packed, and the
         landing permutations are built while those transfers are in flight.
         ``self.last_prep_timings`` records the split: ``bucket_s`` (host
-        planning + fills) and ``upload_s`` (upload dispatch + landing build;
-        the transfers themselves overlap the packing).
+        planning + fills) and ``upload_s`` (upload dispatch of the slabs and
+        the landing permutations; the transfers themselves overlap the
+        packing). ``timer`` (the fit's own, fresh per fit) gets the same work
+        as spans: ``fit.prep.index`` (the CSR + CSC build, with ``.csr``/``.csc`` inside
+        the two worker threads), ``fit.prep.fill`` (packing both sides, with
+        ``.user``/``.item`` likewise) — both wall-clock on the calling thread
+        — and ``fit.prep.upload``, which is ``upload_s``: dispatch seconds
+        summed over the side threads, inside ``fit.prep.fill`` in time.
         """
         key = self._groups_cache_key()
         cache = _matrix_cache(matrix)
@@ -344,11 +353,17 @@ class ImplicitALS:
             cache[key] = self._device_groups_mesh(matrix)
             return cache[key]
 
+        timer = Timer() if timer is None else timer
+
+        def spanned(name, fn, *args):
+            with timer.section(name):
+                return fn(*args)
+
         workers = _bucket_workers()
         t0 = time.perf_counter()
-        with ThreadPoolExecutor(max_workers=2) as sides:
-            csr_f = sides.submit(matrix.csr)
-            csc_f = sides.submit(matrix.csc)
+        with timer.section("fit.prep.index"), ThreadPoolExecutor(max_workers=2) as sides:
+            csr_f = sides.submit(spanned, "fit.prep.index.csr", matrix.csr)
+            csc_f = sides.submit(spanned, "fit.prep.index.csc", matrix.csc)
             csr, csc = csr_f.result(), csc_f.result()
 
         def put(g: Bucket) -> tuple:
@@ -361,35 +376,36 @@ class ImplicitALS:
 
         def build_side(csx, n_target):
             """Pack one side's groups, uploading each as soon as it's full;
-            returns (device groups, device landing, upload dispatch secs)."""
+            returns (device groups, device landing)."""
             device_groups: list[tuple] = []
-            upload_s = [0.0]
 
             def on_group(_i, g):
-                s = time.perf_counter()
-                device_groups.append(put(g))  # device_put is async: transfer
-                upload_s[0] += time.perf_counter() - s  # overlaps later packing
+                # device_put is async: the transfer overlaps later packing
+                with timer.section("fit.prep.upload"):
+                    device_groups.append(put(g))
             grouped = grouped_bucket_rows(
                 *csx, **self._layout_kwargs(), workers=side_workers, on_group=on_group
             )
             # Landing perm is pure host work — runs while H2D is in flight.
             landing = _landing_perm(grouped, n_target)
-            s = time.perf_counter()
-            landing_dev = jax.device_put(landing)
-            upload_s[0] += time.perf_counter() - s
-            return device_groups, landing_dev, upload_s[0]
+            with timer.section("fit.prep.upload"):
+                landing_dev = jax.device_put(landing)
+            return device_groups, landing_dev
 
-        if workers:
-            with ThreadPoolExecutor(max_workers=2) as sides:
-                user_f = sides.submit(build_side, csr, matrix.n_users)
-                item_f = sides.submit(build_side, csc, matrix.n_items)
-                ug, u_land, u_up = user_f.result()
-                ig, i_land, i_up = item_f.result()
-        else:
-            ug, u_land, u_up = build_side(csr, matrix.n_users)
-            ig, i_land, i_up = build_side(csc, matrix.n_items)
+        with timer.section("fit.prep.fill"):
+            if workers:
+                with ThreadPoolExecutor(max_workers=2) as sides:
+                    user_f = sides.submit(
+                        spanned, "fit.prep.fill.user", build_side, csr, matrix.n_users)
+                    item_f = sides.submit(
+                        spanned, "fit.prep.fill.item", build_side, csc, matrix.n_items)
+                    ug, u_land = user_f.result()
+                    ig, i_land = item_f.result()
+            else:
+                ug, u_land = spanned("fit.prep.fill.user", build_side, csr, matrix.n_users)
+                ig, i_land = spanned("fit.prep.fill.item", build_side, csc, matrix.n_items)
         total = time.perf_counter() - t0
-        upload = u_up + i_up
+        upload = timer.totals["fit.prep.upload"]
         self.last_prep_timings = {
             "bucket_s": round(max(0.0, total - upload), 4),
             "upload_s": round(upload, 4),
@@ -570,8 +586,22 @@ class ImplicitALS:
         ``upload_s`` parts, ``compile_s`` (AOT executable acquisition — 0 on
         an in-memory hit; ``compile_source`` says memory/disk/compile),
         ``device_s`` (the fused training dispatch, synchronized), and
-        ``prep_cached`` (whether the layout cache was warm).
+        ``prep_cached`` (whether the layout cache was warm). ``spans`` is the
+        same call as a per-fit ``Timer`` snapshot (``{"totals", "counts"}``;
+        each also an ``albedo.<name>`` host span in a profiler trace):
+        ``fit`` > ``fit.admission``, ``fit.prep`` (children: see
+        :meth:`device_groups`), ``fit.acquire`` (children: see
+        ``utils.aot.persistent_aot_executable``), ``fit.dispatch`` (scalars,
+        key and the compiled call until it returns) and ``fit.wait`` (the
+        health read that is the completion barrier).
         """
+        timer = Timer()
+        with timer.section("fit"):
+            model = self._fit(matrix, callback, timer)
+        self.last_fit_report["spans"] = timer.snapshot()
+        return model
+
+    def _fit(self, matrix: StarMatrix, callback: Any | None, timer: Timer) -> ALSModel:
         t0 = time.perf_counter()
         cache_warm = self._groups_cache_key() in _matrix_cache(matrix)
         admission = None
@@ -579,10 +609,11 @@ class ImplicitALS:
         if use_chunked is None:
             use_chunked = False
             if self.mesh is None and not cache_warm and capacity_mod.enabled():
-                admission = self.admission(matrix)
+                with timer.section("fit.admission"):
+                    admission = self.admission(matrix)
                 use_chunked = admission.verdict == "degrade"
         if use_chunked:
-            return self._fit_chunked(matrix, callback, admission, t0)
+            return self._fit_chunked(matrix, callback, admission, t0, timer)
         if self.mesh is not None:
             # The mesh path is no longer capacity-exempt: the admission
             # LADDER picks replicated-resident -> sharded -> sharded +
@@ -591,7 +622,8 @@ class ImplicitALS:
             if sharded is None:
                 sharded = False
                 if not cache_warm and capacity_mod.enabled():
-                    admission = self.admission_mesh(matrix)
+                    with timer.section("fit.admission"):
+                        admission = self.admission_mesh(matrix)
                     sharded = {
                         "als_fit": False,
                         "als_fit_sharded": "resident",
@@ -600,31 +632,42 @@ class ImplicitALS:
                     }[admission.chosen]
             if sharded:
                 return self._fit_sharded(
-                    matrix, callback, admission, t0,
+                    matrix, callback, admission, t0, timer,
                     streamed=(sharded in ("streamed", "streamed_sync")),
                     # "streamed_sync" is the admission ladder's single-slab
                     # rung (or forced triage): the synchronous dataflow.
                     # Everything else defers to the ALBEDO_PIPELINE switch.
                     pipelined=False if sharded == "streamed_sync" else None,
                 )
-        ug, ig, u_land, i_land = self.device_groups(matrix)
+        with timer.section("fit.prep"):
+            ug, ig, u_land, i_land = self.device_groups(matrix, timer)
         prep_split = dict(getattr(self, "last_prep_timings", {}))
         t1 = time.perf_counter()
 
-        reg = jnp.float32(self.reg_param)
-        alpha = jnp.float32(self.alpha)
+        def acquire(jitted, args, dyn_kwargs, static_kwargs, name):
+            with timer.section("fit.acquire"):
+                return persistent_aot_executable(
+                    jitted, args, dyn_kwargs, static_kwargs,
+                    key_parts=self._aot_key_parts(name, matrix, ug, ig),
+                    name=name, timer=timer, span="fit.acquire",
+                )
+
         compile_s = 0.0
         compile_source = None
         compiled_handle = None  # for the capacity cross-check, when held
+        with timer.section("fit.dispatch"):
+            reg = jnp.float32(self.reg_param)
+            alpha = jnp.float32(self.alpha)
         if self.init_factors is None and callback is None:
             # Seeded init fused into the training program: the whole fit is
             # ONE dispatch (ops.als.als_init_fit_fused), AOT-compiled through
             # the persistent executable cache (utils.aot) so a fresh process
             # with the same bucket layout skips the trace+compile entirely.
-            fused_args = (jax.random.PRNGKey(self.seed), ug, ig, reg, alpha,
-                          jnp.int32(self.max_iter))
-            fused_kwargs = dict(user_landing=u_land, item_landing=i_land)
-            compiled_handle, compile_s, compile_source = persistent_aot_executable(
+            with timer.section("fit.dispatch"):
+                fused_args = (jax.random.PRNGKey(self.seed), ug, ig, reg, alpha,
+                              jnp.int32(self.max_iter))
+                fused_kwargs = dict(user_landing=u_land, item_landing=i_land)
+            compiled_handle, compile_s, compile_source = acquire(
                 als_init_fit_fused,
                 fused_args,
                 fused_kwargs,
@@ -633,10 +676,10 @@ class ImplicitALS:
                     rank=self.rank, solver=self.solver, cg_steps=self.cg_steps,
                     gather_dtype=self.gather_dtype,
                 ),
-                key_parts=self._aot_key_parts("als_init_fit_fused", matrix, ug, ig),
-                name="als_init_fit_fused",
+                "als_init_fit_fused",
             )
-            user_f, item_f = compiled_handle(*fused_args, **fused_kwargs)
+            with timer.section("fit.dispatch"):
+                user_f, item_f = compiled_handle(*fused_args, **fused_kwargs)
         else:
             if self.init_factors is not None:
                 user_f = jnp.asarray(self.init_factors[0], jnp.float32)
@@ -652,19 +695,17 @@ class ImplicitALS:
 
                 user_f = jax.device_put(user_f, replicated(self.mesh))
                 item_f = jax.device_put(item_f, replicated(self.mesh))
+            statics = dict(solver=self.solver, cg_steps=self.cg_steps,
+                           gather_dtype=self.gather_dtype)
+            step_kwargs = dict(user_landing=u_land, item_landing=i_land)
             if callback is None:
-                (user_f, item_f), compile_s, compile_source = persistent_aot_call(
-                    als_fit_fused,
-                    args=(user_f, item_f, ug, ig, reg, alpha,
-                          jnp.int32(self.max_iter)),
-                    dyn_kwargs=dict(user_landing=u_land, item_landing=i_land),
-                    static_kwargs=dict(
-                        solver=self.solver, cg_steps=self.cg_steps,
-                        gather_dtype=self.gather_dtype,
-                    ),
-                    key_parts=self._aot_key_parts("als_fit_fused", matrix, ug, ig),
-                    name="als_fit_fused",
+                fit_args = (user_f, item_f, ug, ig, reg, alpha,
+                            jnp.int32(self.max_iter))
+                compiled_fit, compile_s, compile_source = acquire(
+                    als_fit_fused, fit_args, step_kwargs, statics, "als_fit_fused"
                 )
+                with timer.section("fit.dispatch"):
+                    user_f, item_f = compiled_fit(*fit_args, **step_kwargs)
             else:
                 # One fused dispatch per iteration (same executable: n_iter
                 # is traced), surfacing factors to the host for the callback.
@@ -675,20 +716,16 @@ class ImplicitALS:
                 # verified too (a plain jit call here rode the persistent
                 # XLA cache unguarded — the source of the PR 3 drift).
                 one = jnp.int32(1)
-                step_kwargs = dict(user_landing=u_land, item_landing=i_land)
-                compiled_step, compile_s, compile_source = persistent_aot_executable(
+                compiled_step, compile_s, compile_source = acquire(
                     als_fit_fused,
                     (user_f, item_f, ug, ig, reg, alpha, one),
-                    step_kwargs,
-                    dict(solver=self.solver, cg_steps=self.cg_steps,
-                         gather_dtype=self.gather_dtype),
-                    key_parts=self._aot_key_parts("als_fit_step", matrix, ug, ig),
-                    name="als_fit_step",
+                    step_kwargs, statics, "als_fit_step",
                 )
                 for it in range(self.max_iter):
-                    user_f, item_f = compiled_step(
-                        user_f, item_f, ug, ig, reg, alpha, one, **step_kwargs
-                    )
+                    with timer.section("fit.dispatch"):
+                        user_f, item_f = compiled_step(
+                            user_f, item_f, ug, ig, reg, alpha, one, **step_kwargs
+                        )
                     # The checkpoint callback's contract IS a host copy per
                     # chunk boundary (utils/checkpoint materializes exactly
                     # these) — an intentional, paid-for sync, not a hidden one.
@@ -703,7 +740,8 @@ class ImplicitALS:
         # the happy path.
         from albedo_tpu.utils.watchdog import factor_health, health_dict
 
-        health = health_dict(factor_health(user_f, item_f))
+        with timer.section("fit.wait"):
+            health = health_dict(factor_health(user_f, item_f))
         t2 = time.perf_counter()
         # Cross-check the static cost model against the compiler's own
         # memory analysis when the executable handle is held — advisory
@@ -736,6 +774,7 @@ class ImplicitALS:
         callback: Any | None,
         admission,
         t0: float,
+        timer: Timer,
     ) -> ALSModel:
         """The degraded-capacity fit: host-streamed bucket groups.
 
@@ -755,7 +794,8 @@ class ImplicitALS:
 
         if self.solver not in ("cholesky", "cg"):
             raise ValueError(f"unknown solver {self.solver!r}")
-        user_buckets, item_buckets = self._host_buckets(matrix)
+        with timer.section("fit.prep"):
+            user_buckets, item_buckets = self._host_buckets(matrix)
         t1 = time.perf_counter()
 
         if self.init_factors is not None:
@@ -827,8 +867,10 @@ class ImplicitALS:
 
         from albedo_tpu.utils.watchdog import factor_health, health_dict
 
-        health = health_dict(factor_health(user_f, item_f))
+        with timer.section("fit.wait"):
+            health = health_dict(factor_health(user_f, item_f))
         t2 = time.perf_counter()
+        timer.add("fit.acquire", compile_s)
         self.last_fit_report = {
             "prep_s": round(t1 - t0, 4),
             "bucket_s": round(t1 - t0, 4),
@@ -850,6 +892,7 @@ class ImplicitALS:
         callback: Any | None,
         admission,
         t0: float,
+        timer: Timer,
         streamed: bool,
         pipelined: bool | None = None,
     ) -> ALSModel:
@@ -874,7 +917,8 @@ class ImplicitALS:
             self.mesh, DATA_AXIS, self.solver, self.cg_steps,
             self.gather_dtype, self.shard_mode,
         )
-        user_buckets, item_buckets = self._host_buckets(matrix)
+        with timer.section("fit.prep"):
+            user_buckets, item_buckets = self._host_buckets(matrix)
         t1 = time.perf_counter()
 
         if self.init_factors is not None:
@@ -899,9 +943,11 @@ class ImplicitALS:
 
         # The d2h health read doubles as the completion barrier, exactly as
         # on the resident path.
-        health = health_dict(factor_health(user_f, item_f))
+        with timer.section("fit.wait"):
+            health = health_dict(factor_health(user_f, item_f))
         t2 = time.perf_counter()
         compile_s = stats["compile_s"]
+        timer.add("fit.acquire", compile_s)
         self.last_fit_report = {
             "prep_s": round(t1 - t0, 4),
             "bucket_s": round(t1 - t0, 4),

@@ -30,6 +30,14 @@ custom kernel loses to the compiler's gather+einsum fusion. Pallas pays off
 when fusion FAILS (e.g. data-dependent inner structure); everything in this
 sweep is fusion-friendly by construction — that is what the tier-packed
 fixed-shape bucket layout is for.
+
+Phases carry ``jax.named_scope`` names (HLO metadata only: the compiled code
+does not move) so a profiler trace splits the one fused program by what the
+source says and not by what kind of fusion XLA emitted: ``als.init``,
+``als.gramian``, ``als.gather``, ``als.warm_start``, ``als.cg`` (``.rhs``,
+``.precond``, ``.matvec``, ``.update`` inside it), ``als.cholesky``,
+``als.landing``. They sit in the shared bodies, so the chunked and sharded
+paths inherit them.
 """
 
 from __future__ import annotations
@@ -44,7 +52,8 @@ from albedo_tpu.datasets.ragged import Bucket
 
 def gramian(factors: jax.Array) -> jax.Array:
     """``F^T F`` in float32 — the shared ``YtY`` term of every implicit solve."""
-    return factors.T @ factors
+    with jax.named_scope("als.gramian"):
+        return factors.T @ factors
 
 
 def scatter_solved(
@@ -55,8 +64,16 @@ def scatter_solved(
     shared by the per-bucket reference path, the chunked host-streamed path,
     and the scan fallback; the sharded landing (``parallel.als.
     _landing_scatter``) is the owner-shard variant of the same rule."""
-    safe_rows = jnp.where(row_ids < 0, target.shape[0], row_ids)
-    return target.at[safe_rows].set(solved, mode="drop")
+    with jax.named_scope("als.landing"):
+        safe_rows = jnp.where(row_ids < 0, target.shape[0], row_ids)
+        return target.at[safe_rows].set(solved, mode="drop")
+
+
+def warm_start(target: jax.Array, row_ids: jax.Array) -> jax.Array:
+    """The CG's starting iterates: the bucket's current rows of ``target``
+    (padding slots read row 0; their solves are dropped at landing)."""
+    with jax.named_scope("als.warm_start"):
+        return target[jnp.where(row_ids < 0, 0, row_ids)]
 
 
 def _gather(source: jax.Array, idx: jax.Array, gather_dtype) -> jax.Array:
@@ -68,9 +85,10 @@ def _gather(source: jax.Array, idx: jax.Array, gather_dtype) -> jax.Array:
     streamed bytes of the bandwidth-bound sweep. All contractions over the
     gathered blocks accumulate in float32 (``preferred_element_type``), the
     MXU's native bf16-in/f32-out mode."""
-    if gather_dtype is None:
-        return source[idx]
-    return source.astype(jnp.dtype(gather_dtype))[idx]
+    with jax.named_scope("als.gather"):
+        if gather_dtype is None:
+            return source[idx]
+        return source.astype(jnp.dtype(gather_dtype))[idx]
 
 
 def _gdot(spec: str, gathered: jax.Array, other: jax.Array) -> jax.Array:
@@ -119,20 +137,22 @@ def bucket_partial_terms(
     full-gather terms. Factored out so the ring path's math IS
     ``bucket_solve_body``'s math, not a reimplementation.
     """
-    # A_b correction = sum_l c1 * y y^T
-    corr = jnp.einsum(
-        "blk,bl,blm->bkm", gathered, c1.astype(gathered.dtype), gathered,
-        preferred_element_type=jnp.float32,
-    )
-    # b-vector weights stay float32 even under bf16 gathers: w = 1 + alpha*r
-    # spends ~8 significant bits on the integer part alone, so a bf16 cast
-    # adds ~0.4% relative error per entry (ADVICE r5 #3). The MXU consumes
-    # mixed bf16/f32 inputs with f32 accumulation natively — only the big
-    # gathered block needs the reduced dtype to save bandwidth.
-    b_vec = jnp.einsum(
-        "blk,bl->bk", gathered, w, preferred_element_type=jnp.float32
-    )
-    return corr, b_vec
+    with jax.named_scope("als.cholesky"):
+        # A_b correction = sum_l c1 * y y^T
+        corr = jnp.einsum(
+            "blk,bl,blm->bkm", gathered, c1.astype(gathered.dtype), gathered,
+            preferred_element_type=jnp.float32,
+        )
+        # b-vector weights stay float32 even under bf16 gathers: w = 1 +
+        # alpha*r spends ~8 significant bits on the integer part alone, so a
+        # bf16 cast adds ~0.4% relative error per entry (ADVICE r5 #3). The
+        # MXU consumes mixed bf16/f32 inputs with f32 accumulation natively —
+        # only the big gathered block needs the reduced dtype to save
+        # bandwidth.
+        b_vec = jnp.einsum(
+            "blk,bl->bk", gathered, w, preferred_element_type=jnp.float32
+        )
+        return corr, b_vec
 
 
 def solve_corrected(
@@ -144,11 +164,12 @@ def solve_corrected(
 ) -> jax.Array:
     """Batched Cholesky solve of ``(YtY + corr + reg n_b I) x = b`` — the
     shared tail of the full-gather and ring-accumulated bucket solves."""
-    k = yty.shape[0]
-    eye = jnp.eye(k, dtype=jnp.float32)
-    a_mat = yty[None] + corr + (reg * n_b)[:, None, None] * eye
-    chol = jnp.linalg.cholesky(a_mat)
-    return jax.scipy.linalg.cho_solve((chol, True), b_vec[..., None])[..., 0]
+    with jax.named_scope("als.cholesky"):
+        k = yty.shape[0]
+        eye = jnp.eye(k, dtype=jnp.float32)
+        a_mat = yty[None] + corr + (reg * n_b)[:, None, None] * eye
+        chol = jnp.linalg.cholesky(a_mat)
+        return jax.scipy.linalg.cho_solve((chol, True), b_vec[..., None])[..., 0]
 
 
 def bucket_cg_body(
@@ -178,46 +199,59 @@ def bucket_cg_body(
     remains the parity reference.
     """
     gathered = _gather(source, idx, gather_dtype)  # (B, L, k)
-    c1 = alpha * val                            # (B, L); 0 on padding
-    w = jnp.where(mask, 1.0 + c1, 0.0)
-    n_b = mask.sum(axis=1).astype(jnp.float32)
-    # f32 weights for the b-vector under bf16 gathers — see bucket_solve_body.
-    b_vec = jnp.einsum(
-        "blk,bl->bk", gathered, w, preferred_element_type=jnp.float32
-    )
+    with jax.named_scope("als.cg"):
+        return _cg_solve(gathered, yty, val, mask, x0, reg, alpha, cg_steps)
+
+
+def _cg_solve(gathered, yty, val, mask, x0, reg, alpha, cg_steps):
+    """``bucket_cg_body`` after its gather, under the ``als.cg`` scope."""
+    with jax.named_scope("als.cg.rhs"):
+        c1 = alpha * val                            # (B, L); 0 on padding
+        w = jnp.where(mask, 1.0 + c1, 0.0)
+        n_b = mask.sum(axis=1).astype(jnp.float32)
+        # f32 weights for the b-vector under bf16 gathers — see
+        # bucket_partial_terms.
+        b_vec = jnp.einsum(
+            "blk,bl->bk", gathered, w, preferred_element_type=jnp.float32
+        )
 
     # Jacobi preconditioner: diag(A) = diag(YtY) + sum_l c1 y_l^2 + reg n.
-    diag = (
-        jnp.diagonal(yty)[None]
-        + _gdot("blk,bl->bk", gathered * gathered, c1)
-        + (reg * n_b)[:, None]
-    )
-    diag = jnp.maximum(diag, 1e-12)
+    with jax.named_scope("als.cg.precond"):
+        diag = (
+            jnp.diagonal(yty)[None]
+            + _gdot("blk,bl->bk", gathered * gathered, c1)
+            + (reg * n_b)[:, None]
+        )
+        diag = jnp.maximum(diag, 1e-12)
 
     def matvec(p):
-        t = c1 * _gdot("blk,bk->bl", gathered, p)
-        return (
-            p @ yty
-            + _gdot("blk,bl->bk", gathered, t)
-            + (reg * n_b)[:, None] * p
-        )
+        with jax.named_scope("als.cg.matvec"):
+            t = c1 * _gdot("blk,bk->bl", gathered, p)
+            return (
+                p @ yty
+                + _gdot("blk,bl->bk", gathered, t)
+                + (reg * n_b)[:, None] * p
+            )
 
     tiny = jnp.float32(1e-30)
     x = x0
-    r = b_vec - matvec(x)
-    z = r / diag
-    p = z
-    rz = jnp.sum(r * z, axis=1)
+    ax = matvec(x)
+    with jax.named_scope("als.cg.update"):
+        r = b_vec - ax
+        z = r / diag
+        p = z
+        rz = jnp.sum(r * z, axis=1)
     for _ in range(cg_steps):  # static unroll: fixed shapes, no host sync
         ap = matvec(p)
-        step = rz / (jnp.sum(p * ap, axis=1) + tiny)
-        x = x + step[:, None] * p
-        r = r - step[:, None] * ap
-        z = r / diag
-        rz_new = jnp.sum(r * z, axis=1)
-        beta = rz_new / (rz + tiny)
-        p = z + beta[:, None] * p
-        rz = rz_new
+        with jax.named_scope("als.cg.update"):
+            step = rz / (jnp.sum(p * ap, axis=1) + tiny)
+            x = x + step[:, None] * p
+            r = r - step[:, None] * ap
+            z = r / diag
+            rz_new = jnp.sum(r * z, axis=1)
+            beta = rz_new / (rz + tiny)
+            p = z + beta[:, None] * p
+            rz = rz_new
     return x
 
 
@@ -271,7 +305,7 @@ def chunked_bucket_update(
     sequential scatters land exactly what the fused landing gather lands.
     """
     if solver == "cg":
-        x0 = target[jnp.where(row_ids < 0, 0, row_ids)]
+        x0 = warm_start(target, row_ids)
         solved = bucket_cg_body(
             source, yty, idx, val, mask, x0, reg, alpha, cg_steps,
             gather_dtype=gather_dtype,
@@ -347,7 +381,7 @@ def scan_half_sweep(
     def body(_, g):
         row_ids, idx, val, mask = g
         if solver == "cg":
-            x0 = target[jnp.where(row_ids < 0, 0, row_ids)]
+            x0 = warm_start(target, row_ids)
             solved = bucket_cg_body(
                 source, yty, idx, val, mask, x0, reg, alpha, cg_steps,
                 gather_dtype=gather_dtype,
@@ -366,8 +400,9 @@ def scan_half_sweep(
         all_rows.append(g.row_ids.reshape(-1))
         all_solved.append(solved.reshape(-1, k))
     if landing is not None:
-        pool = jnp.concatenate(all_solved + [target])
-        return pool[landing]
+        with jax.named_scope("als.landing"):
+            pool = jnp.concatenate(all_solved + [target])
+            return pool[landing]
     rows = jnp.concatenate(all_rows)
     solved = jnp.concatenate(all_solved)
     return scatter_solved(target, rows, solved)
@@ -457,10 +492,11 @@ def als_init_fit_fused(
     program makes the whole train ONE dispatch and the values identical
     (same traced PRNG ops, same key).
     """
-    ukey, ikey = jax.random.split(key)
-    scale = 1.0 / jnp.sqrt(jnp.float32(rank))
-    user_f = jax.random.normal(ukey, (n_users, rank), jnp.float32) * scale
-    item_f = jax.random.normal(ikey, (n_items, rank), jnp.float32) * scale
+    with jax.named_scope("als.init"):
+        ukey, ikey = jax.random.split(key)
+        scale = 1.0 / jnp.sqrt(jnp.float32(rank))
+        user_f = jax.random.normal(ukey, (n_users, rank), jnp.float32) * scale
+        item_f = jax.random.normal(ikey, (n_items, rank), jnp.float32) * scale
     return _fit_loop(
         user_f, item_f, user_groups, item_groups, reg, alpha, n_iter,
         solver, cg_steps, user_landing, item_landing, gather_dtype,

@@ -63,6 +63,7 @@ from albedo_tpu.ops.als import (
     bucket_solve_body,
     scatter_solved,
     solve_corrected,
+    warm_start,
 )
 from albedo_tpu.parallel.mesh import DATA_AXIS, pad_rows_to, row_sharded
 from albedo_tpu.utils import faults
@@ -217,7 +218,7 @@ def _assembled_solve(
         # shard owns them — assemble the target too (priced by the cost
         # model as the CG mode's extra transient).
         target = jax.lax.all_gather(target_l, axis, axis=0, tiled=True)
-        x0 = target[jnp.where(row_ids_l < 0, 0, row_ids_l)]
+        x0 = warm_start(target, row_ids_l)
         return bucket_cg_body(
             source, yty, idx_l, val_l, mask_l, x0, reg, alpha, cg_steps,
             gather_dtype=gather_dtype,

@@ -17,7 +17,7 @@ from albedo_tpu.utils.checkpoint import (  # noqa: E402
     save_pytree,
 )
 from albedo_tpu.utils.faults import FaultInjected
-from albedo_tpu.utils.profiling import Timer, profiler_trace, timed, timing
+from albedo_tpu.utils.profiling import Timer
 from albedo_tpu.utils.retry import (
     RetriesExhausted,
     RetryAfter,
@@ -39,10 +39,7 @@ __all__ = [
     "assert_columns",
     "checkpointed_als_fit",
     "equals_ignore_nullability",
-    "profiler_trace",
     "restore_pytree",
     "retry_call",
     "save_pytree",
-    "timed",
-    "timing",
 ]

@@ -209,9 +209,10 @@ def _xla_persistent_cache_engaged() -> bool:
     )
 
 
-def _compile_bypassing_xla_cache(jitted, args, dyn_kwargs, static_kwargs):
+def _compile_bypassing_xla_cache(jitted, args, dyn_kwargs, static_kwargs, section):
     """A provably-fresh compile: the persistent XLA cache is switched off
-    for just this lower+compile, then restored.
+    for just this lower+compile (one ``lower_compile`` span of the caller's
+    ``section``), then restored.
 
     jax latches the is-cache-used decision process-globally on first
     compile (``compilation_cache._cache_checked``/``_cache_used``), so
@@ -225,7 +226,7 @@ def _compile_bypassing_xla_cache(jitted, args, dyn_kwargs, static_kwargs):
     import jax
     from jax._src.compilation_cache import reset_cache as _reset_latch
 
-    with _BYPASS_LOCK:
+    with section("lower_compile"), _BYPASS_LOCK:
         prev = bool(jax.config.jax_enable_compilation_cache)
         try:
             jax.config.update("jax_enable_compilation_cache", False)
@@ -236,23 +237,37 @@ def _compile_bypassing_xla_cache(jitted, args, dyn_kwargs, static_kwargs):
             _reset_latch()
 
 
-def _output_fingerprint(compiled, args: tuple, dyn_kwargs: dict) -> str:
+def _output_fingerprint(compiled, args: tuple, dyn_kwargs: dict, section) -> str:
     """Run ``compiled`` on the deterministic probe and hash the raw output
-    bytes (shape + dtype + buffer; NaNs compare by representation)."""
+    bytes (shape + dtype + buffer; NaNs compare by representation). Every
+    probe run is one ``probe`` span of the caller's ``section``."""
     import jax
     import numpy as np
 
-    probe_args, probe_kwargs = jax.tree_util.tree_map(
-        _probe_leaf, (tuple(args), dict(dyn_kwargs))
-    )
-    out = compiled(*probe_args, **probe_kwargs)
-    h = hashlib.sha256()
-    for leaf in jax.tree_util.tree_leaves(out):
-        arr = np.asarray(leaf)
-        h.update(str(arr.shape).encode())
-        h.update(str(arr.dtype).encode())
-        h.update(arr.tobytes())
-    return h.hexdigest()
+    with section("probe"):
+        probe_args, probe_kwargs = jax.tree_util.tree_map(
+            _probe_leaf, (tuple(args), dict(dyn_kwargs))
+        )
+        out = compiled(*probe_args, **probe_kwargs)
+        h = hashlib.sha256()
+        for leaf in jax.tree_util.tree_leaves(out):
+            arr = np.asarray(leaf)
+            h.update(str(arr.shape).encode())
+            h.update(str(arr.dtype).encode())
+            h.update(arr.tobytes())
+        return h.hexdigest()
+
+
+def _named_call(call, name: str):
+    """``call`` (an ``Exported.call``) under a function named ``name``: jit
+    names the XLA module after the function it wraps, so the program reads
+    ``jit_<name>`` in a profiler trace instead of ``jit_call``."""
+
+    def named(*args, **kwargs):
+        return call(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = name
+    return named
 
 
 # Custom-call targets that are compiler-internal ops, not foreign functions:
@@ -319,6 +334,8 @@ def persistent_aot_executable(
     static_kwargs: dict | None,
     key_parts: tuple,
     name: str = "fn",
+    timer: Any | None = None,
+    span: str = "acquire",
 ) -> tuple[Any, float, str]:
     """Resolve the cached executable WITHOUT calling it.
 
@@ -327,10 +344,18 @@ def persistent_aot_executable(
     serving micro-batcher pre-warming one executable per batch bucket) hold
     it and invoke ``compiled(*args, **dyn_kwargs)`` directly per request,
     skipping the digest + LRU lookup on the hot path entirely.
+
+    A non-memory acquisition marks its branches as sections of ``timer``
+    (``utils.profiling.Timer``; a throwaway one when none is given, so the
+    trace spans exist either way) named ``<span>.deserialize``,
+    ``.lower_compile``, ``.export``, ``.serialize`` and ``.probe``.
     """
+    t0 = time.perf_counter()
     import jax
 
     from albedo_tpu.utils.compilation_cache import harden_jax_cache_writes
+    from albedo_tpu.utils.profiling import Timer
+
 
     # About to compile (and possibly persist the executable): make sure the
     # persistent cache's writes are torn-write-safe first (idempotent).
@@ -345,6 +370,11 @@ def persistent_aot_executable(
     if compiled is not None:
         return compiled, 0.0, "memory"
 
+    timer = Timer() if timer is None else timer
+
+    def section(part: str):
+        return timer.section(f"{span}.{part}")
+
     source = "compile"
     branch: list[str] = []  # what happened on the way, for branch_log()
     # Non-portable custom-call targets of the export: None = unknown (not
@@ -352,19 +382,22 @@ def persistent_aot_executable(
     targets: list[str] | None = None
     compiled = None
     path = export_dir() / f"{name}-{digest}.jaxexport" if disk_cache_enabled() else None
-    t0 = time.perf_counter()
 
     if path is not None and path.exists():
         try:
             from jax import export as jax_export
 
-            restored = jax_export.deserialize(bytearray(path.read_bytes()))
-            # Belt and braces: refuse to execute a blob with custom calls
-            # even if one was written by hand/an older build (see
-            # _custom_call_targets — executing one can crash the process).
-            if _custom_call_targets(restored):
-                raise ValueError("serialized module contains custom calls")
-            compiled = jax.jit(restored.call).lower(*args, **dyn_kwargs).compile()
+            with section("deserialize"):
+                restored = jax_export.deserialize(bytearray(path.read_bytes()))
+                # Belt and braces: refuse to execute a blob with custom calls
+                # even if one was written by hand/an older build (see
+                # _custom_call_targets — executing one can crash the process).
+                if _custom_call_targets(restored):
+                    raise ValueError("serialized module contains custom calls")
+            with section("lower_compile"):
+                compiled = jax.jit(_named_call(restored.call, name)).lower(
+                    *args, **dyn_kwargs
+                ).compile()
             # Self-check: the deserialized executable must reproduce the
             # exporting process's probe output bit-for-bit. A mismatch means
             # some cache layer handed back a divergent program — discard the
@@ -372,7 +405,7 @@ def persistent_aot_executable(
             fp_path = _fingerprint_path(path)
             if fingerprint_enabled() and fp_path.exists():
                 expected = json.loads(fp_path.read_text()).get("sha256")
-                got = _output_fingerprint(compiled, args, dyn_kwargs)
+                got = _output_fingerprint(compiled, args, dyn_kwargs, section)
                 if got != expected:
                     from albedo_tpu.utils import events
 
@@ -412,8 +445,11 @@ def persistent_aot_executable(
             try:
                 from jax import export as jax_export
 
-                exported = jax_export.export(jitted)(*args, **dyn_kwargs, **static_kwargs)
-                targets = _custom_call_targets(exported)
+                with section("export"):
+                    exported = jax_export.export(jitted)(
+                        *args, **dyn_kwargs, **static_kwargs
+                    )
+                    targets = _custom_call_targets(exported)
                 if targets:
                     log.debug("%s embeds custom calls; memory cache only", name)
                     exported = None  # not round-trip-safe: memory cache only
@@ -423,7 +459,10 @@ def persistent_aot_executable(
                     # the identical program. (A multi-device export called
                     # with arguments not yet laid out on its mesh cannot
                     # lower this way — that is an export failure too.)
-                    compiled = jax.jit(exported.call).lower(*args, **dyn_kwargs).compile()
+                    with section("lower_compile"):
+                        compiled = jax.jit(_named_call(exported.call, name)).lower(
+                            *args, **dyn_kwargs
+                        ).compile()
             except Exception as e:  # noqa: BLE001
                 log.warning("jax.export of %s failed (%r); disk AOT layer off "
                             "for this program", name, e)
@@ -438,11 +477,12 @@ def persistent_aot_executable(
                 # states) raises ValueError. The program still compiled fine
                 # — it just cannot cross processes via the export layer, so
                 # the write is best-effort for ANY failure, never fatal.
-                blob = exported.serialize()
-                tmp = path.with_name(path.name + f".tmp{os.getpid()}")
-                path.parent.mkdir(parents=True, exist_ok=True)
-                tmp.write_bytes(blob)
-                os.replace(tmp, path)
+                with section("serialize"):
+                    blob = exported.serialize()
+                    tmp = path.with_name(path.name + f".tmp{os.getpid()}")
+                    path.parent.mkdir(parents=True, exist_ok=True)
+                    tmp.write_bytes(blob)
+                    os.replace(tmp, path)
                 wrote_export = True
                 branch.append("exported")
             except Exception as e:  # noqa: BLE001
@@ -461,7 +501,7 @@ def persistent_aot_executable(
                 # but it also must not leave a sidecar-less export behind
                 # for later processes to trust unverified.
                 try:
-                    fp = _output_fingerprint(compiled, args, dyn_kwargs)
+                    fp = _output_fingerprint(compiled, args, dyn_kwargs, section)
                     fp_path = _fingerprint_path(path)
                     fp_tmp = fp_path.with_name(fp_path.name + f".tmp{os.getpid()}")
                     fp_tmp.write_text(json.dumps({"sha256": fp}))
@@ -494,11 +534,12 @@ def persistent_aot_executable(
                 "cache bypassed for this program)", name
             )
             compiled = _compile_bypassing_xla_cache(
-                jitted, args, dyn_kwargs, static_kwargs
+                jitted, args, dyn_kwargs, static_kwargs, section
             )
             branch.append("custom-call-bypassed-compile")
         else:
-            compiled = jitted.lower(*args, **dyn_kwargs, **static_kwargs).compile()
+            with section("lower_compile"):
+                compiled = jitted.lower(*args, **dyn_kwargs, **static_kwargs).compile()
             branch.append("plain-compile")
             # Export-failed programs (custom-call status unknown) still ride
             # the persistent XLA cache across processes — guard that reuse
@@ -514,7 +555,7 @@ def persistent_aot_executable(
                 fp_path = export_dir() / f"{name}-{digest}.fp"
                 got = None
                 try:
-                    got = _output_fingerprint(compiled, args, dyn_kwargs)
+                    got = _output_fingerprint(compiled, args, dyn_kwargs, section)
                 except Exception as e:  # noqa: BLE001 — probe must not kill the job
                     branch.append(f"probe-failed:{type(e).__name__}")
                     log.warning(
@@ -538,7 +579,7 @@ def persistent_aot_executable(
                                 name, got[:12], str(expected)[:12],
                             )
                             compiled = _compile_bypassing_xla_cache(
-                                jitted, args, dyn_kwargs, static_kwargs
+                                jitted, args, dyn_kwargs, static_kwargs, section
                             )
                             branch.append("xla-cache-fingerprint-mismatch")
                         else:
@@ -554,9 +595,11 @@ def persistent_aot_executable(
                         # anchor it, and hold ourselves to the same check.
                         try:
                             fresh = _compile_bypassing_xla_cache(
-                                jitted, args, dyn_kwargs, static_kwargs
+                                jitted, args, dyn_kwargs, static_kwargs, section
                             )
-                            baseline = _output_fingerprint(fresh, args, dyn_kwargs)
+                            baseline = _output_fingerprint(
+                                fresh, args, dyn_kwargs, section
+                            )
                         except Exception as e:  # noqa: BLE001
                             branch.append(f"baseline-failed:{type(e).__name__}")
                             log.warning(

@@ -1,25 +1,30 @@
-"""Timing and profiling harness.
+"""Timing and tracing: one span primitive.
 
 Reference parity: the reference measures wall-clock by prefixing ``time`` on
 every spark-submit (``Makefile:64,78,131``) and decorating crawler methods
 with ``timing_decorator`` (``app/utils_timing.py:7-15``); deeper inspection
-goes through the Spark UI. Here timing is a first-class module (SURVEY.md §5):
-``timed``/``Timer`` synchronize device work (``block_until_ready``) so numbers
-mean what they say, and ``profiler_trace`` wraps the JAX profiler (the
-TensorBoard-viewable trace is the Spark-UI analogue).
+goes through the Spark UI. Here ``Timer.section`` is the one mechanism
+(SURVEY.md §5): a section is a wall-clock total and count in
+``Timer.snapshot()`` (fit reports, ``/metrics``) AND a host span named
+``albedo.<name>`` in any running ``jax.profiler`` trace, on the same clock as
+the device's ``XLA Ops`` line (the TensorBoard-viewable trace is the Spark-UI
+analogue). Names are dotted, parent first (``fit.prep.index``): a span's self
+time is its total less its children's.
 """
 
 from __future__ import annotations
 
 import contextlib
-import functools
-import threading
-
-from albedo_tpu.analysis.locksmith import named_lock
 import time
 from typing import Any, Callable, Iterator
 
 import jax
+
+from albedo_tpu.analysis.locksmith import named_lock
+
+# Every section's host span in a profiler trace carries this prefix, so a
+# reader finds the program's spans among the runtime's own host events.
+SPAN_PREFIX = "albedo."
 
 
 def _sync(value: Any) -> None:
@@ -49,15 +54,24 @@ class Timer:
 
     @contextlib.contextmanager
     def section(self, name: str, sync: Any = None) -> Iterator[None]:
+        """Time the block under ``name`` and mark it ``albedo.<name>`` in a
+        running profiler trace (with no profiler session the annotation is a
+        flag test)."""
         t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(SPAN_PREFIX + name):
+                try:
+                    yield
+                finally:
+                    _sync(sync)
         finally:
-            _sync(sync)
-            dt = time.perf_counter() - t0
-            with self._lock:
-                self.totals[name] = self.totals.get(name, 0.0) + dt
-                self.counts[name] = self.counts.get(name, 0) + 1
+            self.add(name, time.perf_counter() - t0)
+
+    def add(self, name: str, seconds: float) -> None:
+        """Record a duration the caller's own clock reads measured."""
+        with self._lock:
+            self.totals[name] = self.totals.get(name, 0.0) + seconds
+            self.counts[name] = self.counts.get(name, 0) + 1
 
     def snapshot(self) -> dict[str, dict]:
         """Point-in-time copy of the accumulated sections:
@@ -76,40 +90,3 @@ class Timer:
                 f"{name}: {self.totals[name]:.3f}s over {self.counts[name]} call(s)"
             )
         return dict(self.totals)
-
-
-@contextlib.contextmanager
-def timed(label: str, sync: Any = None, printer: Callable[[str], None] = print):
-    """One-shot timed block; syncs ``sync`` (a pytree of jax arrays) on exit."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        _sync(sync)
-        printer(f"[{label}] {time.perf_counter() - t0:.3f}s")
-
-
-def timing(fn: Callable) -> Callable:
-    """Decorator parity with the crawler's ``timing_decorator``
-    (``app/utils_timing.py:7-15``): prints the wall-clock of each call,
-    synchronizing any jax outputs first."""
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        _sync(out)
-        print(f"[{fn.__name__}] {time.perf_counter() - t0:.3f}s")
-        return out
-
-    return wrapper
-
-
-@contextlib.contextmanager
-def profiler_trace(log_dir: str, enabled: bool = True):
-    """JAX profiler trace (view in TensorBoard/XProf) — the Spark-UI analogue."""
-    if not enabled:
-        yield
-        return
-    with jax.profiler.trace(log_dir):
-        yield

@@ -1,0 +1,217 @@
+"""The one span mechanism (``Timer.section`` -> total + ``albedo.<name>``
+trace span), the host spans of ``ImplicitALS.fit`` and the device scopes and
+module name of the fused ALS program."""
+
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from albedo_tpu.datasets.synthetic import synthetic_stars
+from albedo_tpu.models.als import ImplicitALS
+from albedo_tpu.ops.als import als_init_fit_fused
+from albedo_tpu.utils.aot import persistent_aot_executable, reset_memory_cache
+from albedo_tpu.utils.profiling import SPAN_PREFIX, Timer
+
+MS = 1e-3
+SCOPES = {
+    "cg": ("als.init", "als.gramian", "als.gather", "als.warm_start", "als.cg",
+           "als.cg.rhs", "als.cg.precond", "als.cg.matvec", "als.cg.update", "als.landing"),
+    "cholesky": ("als.init", "als.gramian", "als.gather", "als.cholesky", "als.landing"),
+}
+
+
+@pytest.fixture(autouse=True)
+def _fresh_process():
+    """Each test has a cold export directory (conftest); give it a cold
+    in-memory executable cache to match."""
+    reset_memory_cache()
+
+
+def stars(seed=41):
+    return synthetic_stars(n_users=120, n_items=70, mean_stars=7, seed=seed)
+
+
+def host_events(trace_dir) -> dict[str, list[float]]:
+    """Seconds of every ``albedo.*`` host event of the one trace under
+    ``trace_dir``, by name."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    found: dict[str, list[float]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(SPAN_PREFIX):
+                    found.setdefault(ev.name, []).append(ev.duration_ns * 1e-9)
+    return found
+
+
+def test_section_is_a_total_a_count_and_a_trace_span(tmp_path):
+    timer = Timer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            with timer.section("stage.part", sync=jnp.ones(4) * 2):
+                pass
+        with timer.section("stage"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    snap = timer.snapshot()
+    assert snap["counts"] == {"stage.part": 2, "stage": 1}
+    assert set(snap["totals"]) == {"stage.part", "stage"} and min(snap["totals"].values()) > 0
+    events = host_events(tmp_path)
+    assert len(events["albedo.stage.part"]) == 2 and len(events["albedo.stage"]) == 1
+    # the span and the total are one clock's reading of one block
+    assert sum(events["albedo.stage.part"]) == pytest.approx(snap["totals"]["stage.part"], abs=MS)
+
+
+def test_section_without_a_profiler_and_on_an_exception_still_counts():
+    timer = Timer()
+    with pytest.raises(RuntimeError):
+        with timer.section("boom"):
+            raise RuntimeError("inside")
+    timer.add("elsewhere", 1.5)
+    timer.add("elsewhere", 0.5)
+    assert timer.counts == {"boom": 1, "elsewhere": 2}
+    assert timer.totals["elsewhere"] == 2.0
+
+
+def assert_children_within_parents(totals: dict[str, float]) -> None:
+    for name, seconds in totals.items():
+        parent = name.rpartition(".")[0]
+        if parent and name != "fit.prep.upload":  # summed over the two side threads
+            assert seconds <= totals[parent] + MS, (name, seconds, totals[parent])
+
+
+def test_cold_fit_publishes_its_spans_beside_the_keys_they_refine():
+    als = ImplicitALS(rank=4, max_iter=2, seed=3, solver="cg")
+    als.fit(stars())
+    report = als.last_fit_report
+    totals, counts = report["spans"]["totals"], report["spans"]["counts"]
+    assert report["compile_source"] == "compile"
+    assert set(totals) == {
+        "fit", "fit.admission", "fit.prep", "fit.prep.index", "fit.prep.index.csr",
+        "fit.prep.index.csc", "fit.prep.fill", "fit.prep.fill.user", "fit.prep.fill.item",
+        "fit.prep.upload", "fit.acquire", "fit.acquire.export", "fit.acquire.lower_compile",
+        "fit.acquire.serialize", "fit.acquire.probe", "fit.dispatch", "fit.wait",
+    }
+    assert counts["fit"] == counts["fit.wait"] == counts["fit.acquire.probe"] == 1
+    assert_children_within_parents(totals)
+    assert totals["fit.acquire"] == pytest.approx(report["compile_s"], abs=MS)
+    assert totals["fit.admission"] + totals["fit.prep"] == pytest.approx(report["prep_s"], abs=MS)
+    assert totals["fit.prep.upload"] == pytest.approx(report["upload_s"], abs=MS)
+    assert totals["fit.prep.index"] <= report["bucket_s"] + MS
+    whole = report["prep_s"] + report["compile_s"] + report["device_s"]
+    assert whole - MS <= totals["fit"] <= whole + 100 * MS   # + the report's own making
+
+
+def test_warm_start_in_a_fresh_process_spans_deserialize_compile_and_probe():
+    ImplicitALS(rank=4, max_iter=2, seed=3, solver="cg").fit(stars())
+    reset_memory_cache()                      # a second process: disk layer only
+    als = ImplicitALS(rank=4, max_iter=2, seed=3, solver="cg")
+    als.fit(stars())                          # a fresh matrix object: cold layout
+    report = als.last_fit_report
+    totals = report["spans"]["totals"]
+    assert report["compile_source"] == "disk"
+    acquire = {k for k in totals if k.startswith("fit.acquire.")}
+    assert acquire == {"fit.acquire.deserialize", "fit.acquire.lower_compile", "fit.acquire.probe"}
+    parts = sum(totals[k] for k in acquire)     # the branches are all but 2% of the acquisition
+    assert 0.98 * report["compile_s"] <= parts <= report["compile_s"] + MS
+    assert totals["fit.acquire"] == pytest.approx(report["compile_s"], abs=MS)
+    assert_children_within_parents(totals)
+
+
+def test_warm_fit_in_one_process_has_no_children_to_report():
+    m = stars()
+    als = ImplicitALS(rank=4, max_iter=2, seed=3, solver="cg")
+    als.fit(m)
+    als.fit(m)
+    report = als.last_fit_report
+    totals = report["spans"]["totals"]
+    assert set(totals) == {"fit", "fit.prep", "fit.acquire", "fit.dispatch", "fit.wait"}
+    assert report["compile_s"] == 0.0 and totals["fit.acquire"] < MS
+    assert totals["fit"] - totals["fit.wait"] < 50 * MS   # what fit_host_ms reads
+
+
+@pytest.mark.parametrize("kwargs", [
+    pytest.param({"chunked": True}, id="chunked"),
+    pytest.param({"sharded": "resident"}, id="sharded"),
+])
+def test_degraded_paths_publish_spans_from_the_clock_reads_they_make(kwargs):
+    if "sharded" in kwargs:
+        from albedo_tpu.parallel import make_mesh
+
+        kwargs = dict(kwargs, mesh=make_mesh(8))
+    als = ImplicitALS(rank=4, max_iter=1, seed=3, solver="cg", **kwargs)
+    als.fit(stars())
+    report = als.last_fit_report
+    totals = report["spans"]["totals"]
+    assert report["mode"] != "resident"
+    assert {"fit", "fit.prep", "fit.acquire", "fit.wait"} <= set(totals)
+    assert totals["fit.acquire"] == pytest.approx(report["compile_s"], abs=MS)
+    assert totals["fit.prep"] <= report["prep_s"] + MS
+    assert_children_within_parents(totals)
+
+
+def fused_fit_text(solver: str) -> tuple[str, str]:
+    """Compiled text of ``als_init_fit_fused`` at a tiny shape, acquired
+    through the AOT layer as ``fit`` acquires it, and the layer's source."""
+    m = stars(seed=43)
+    als = ImplicitALS(rank=4, max_iter=2, seed=3, solver=solver)
+    ug, ig, u_land, i_land = als.device_groups(m)
+    args = (jax.random.PRNGKey(0), ug, ig, jnp.float32(0.5), jnp.float32(40.0), jnp.int32(2))
+    compiled, _, source = persistent_aot_executable(
+        als_init_fit_fused, args, dict(user_landing=u_land, item_landing=i_land),
+        dict(n_users=m.n_users, n_items=m.n_items, rank=4, solver=solver, cg_steps=3,
+             gather_dtype=None),
+        key_parts=("test_tracing_spans", solver), name="als_init_fit_fused",
+    )
+    return compiled.as_text(), source
+
+
+@pytest.mark.parametrize("solver", ["cg", "cholesky"])
+@pytest.mark.parametrize("acquisition", ["fresh", "second_process"])
+def test_compiled_fit_carries_every_scope_and_a_stable_module_name(solver, acquisition):
+    text, source = fused_fit_text(solver)
+    if acquisition == "second_process":
+        reset_memory_cache()
+        text, source = fused_fit_text(solver)
+        # the CPU's Cholesky is a LAPACK custom call and never leaves memory
+        assert source == ("disk" if solver == "cg" else "compile")
+    else:
+        assert source == "compile"
+    assert re.search(r"^HloModule jit_als_init_fit_fused\b", text, re.M)
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in SCOPES[solver]:
+        assert any(f"/{scope}/" in name for name in op_names), scope
+    # sub-scopes nest inside their parent; the gather is not inside the solve
+    assert any("/als.cg/als.cg.matvec/" in n for n in op_names) or solver != "cg"
+    assert not any(re.search(r"/als\.(cg|cholesky)/.*als\.gather", n) for n in op_names)
+
+
+def test_scopes_do_not_change_what_the_fit_computes():
+    """Scopes are metadata: the half-sweep under them equals the same
+    arithmetic written without them, on the CPU's exact f32."""
+    from albedo_tpu.ops.als import bucket_cg_body, gramian
+
+    rng = np.random.default_rng(5)
+    source = jnp.asarray(rng.normal(size=(30, 4)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, 30, (6, 5)), jnp.int32)
+    mask = jnp.asarray(rng.random((6, 5)) < 0.8)
+    val = jnp.where(mask, 1.0, 0.0).astype(jnp.float32)
+    x0 = jnp.asarray(rng.normal(size=(6, 4)), jnp.float32)
+    reg, alpha = jnp.float32(0.5), jnp.float32(40.0)
+    got = bucket_cg_body(source, gramian(source), idx, val, mask, x0, reg, alpha, 64)
+
+    y, c1 = np.asarray(source, np.float64)[np.asarray(idx)], 40.0 * np.asarray(val, np.float64)
+    yty = np.asarray(source, np.float64).T @ np.asarray(source, np.float64)
+    n_b = np.asarray(mask).sum(1)
+    for b in range(6):   # the normal equations, solved exactly
+        a = yty + (y[b].T * c1[b]) @ y[b] + 0.5 * n_b[b] * np.eye(4)
+        want = np.linalg.solve(a, y[b].T @ np.where(np.asarray(mask)[b], 1.0 + c1[b], 0.0))
+        np.testing.assert_allclose(np.asarray(got)[b], want, rtol=2e-3, atol=2e-4)

@@ -7,7 +7,7 @@ import pytest
 from albedo_tpu.features.assembler import FeatureMatrix
 from albedo_tpu.models.logistic_regression import LogisticRegression
 from albedo_tpu.parallel import make_mesh
-from albedo_tpu.utils import Timer, assert_columns, equals_ignore_nullability, timed, timing
+from albedo_tpu.utils import Timer, assert_columns, equals_ignore_nullability
 
 
 def make_fm(rng, n=700):
@@ -54,21 +54,6 @@ def test_timer_sections(capsys):
     assert t.counts["a"] == 2 and t.counts["b"] == 1
     assert set(totals) == {"a", "b"}
     assert "a:" in capsys.readouterr().out
-
-
-def test_timed_and_timing_sync_jax(capsys):
-    import jax.numpy as jnp
-
-    with timed("block", sync=jnp.ones(4)):
-        out = jnp.arange(8).sum()
-
-    @timing
-    def work():
-        return jnp.ones(3) * 2
-
-    work()
-    printed = capsys.readouterr().out
-    assert "[block]" in printed and "[work]" in printed
 
 
 def test_schema_helpers():
