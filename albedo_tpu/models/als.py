@@ -33,7 +33,7 @@ from albedo_tpu.datasets.ragged import (
     grouped_bucket_rows,
 )
 from albedo_tpu.datasets.star_matrix import StarMatrix
-from albedo_tpu.ops.als import als_fit_fused, als_init_fit_fused
+from albedo_tpu.ops.als import als_fit_fused, als_init_fit_fused, cg_gram_entry_share
 from albedo_tpu.ops.topk import topk_scores
 from albedo_tpu.utils import capacity as capacity_mod
 from albedo_tpu.utils import faults
@@ -224,11 +224,12 @@ class ImplicitALS:
     max_iter: int = 26
     seed: int = 42
     # Normal-equation solver: "cholesky" = exact per-row solve, MLlib's
-    # algorithm (the parity reference); "cg" = matrix-free Jacobi-
-    # preconditioned conjugate gradient warm-started from the previous
-    # sweep's factors (``ops.als.bucket_cg_body``) — the fast path: XLA's
-    # batched small-matrix Cholesky runs at a few GF/s on TPU while the CG
-    # matvec is einsum-shaped MXU work; a few warm-started steps per
+    # algorithm (the parity reference); "cg" = Jacobi-preconditioned
+    # conjugate gradient warm-started from the previous sweep's factors
+    # (``ops.als.bucket_cg_body``: matrix-free on short rows, on the row's
+    # explicit Gramian on long ones) — the fast path: XLA's batched
+    # small-matrix Cholesky runs at a few GF/s on TPU while the CG is
+    # streaming passes and one MXU contraction; a few warm-started steps per
     # half-sweep match the exact solve's held-out ranking quality (the
     # ``implicit`` package's standard CG solver uses 3).
     solver: str = "cholesky"
@@ -474,6 +475,11 @@ class ImplicitALS:
             matrix.n_users, matrix.n_items, groups_sig,
         )
 
+    def _cg_gram_entry_share(self, shapes) -> float:
+        """``last_fit_report["cg_gram_entry_share"]`` for a fit over bucket
+        ``shapes``: what ``ops.als.cg_uses_gramian`` chose, 0 under Cholesky."""
+        return cg_gram_entry_share(shapes, self.rank) if self.solver == "cg" else 0.0
+
     # ---------------------------------------------------- capacity admission
 
     def _plan_shapes(self, matrix: StarMatrix) -> tuple[list, list]:
@@ -585,8 +591,11 @@ class ImplicitALS:
         ~0 when the per-matrix cache is warm) with its ``bucket_s``/
         ``upload_s`` parts, ``compile_s`` (AOT executable acquisition — 0 on
         an in-memory hit; ``compile_source`` says memory/disk/compile),
-        ``device_s`` (the fused training dispatch, synchronized), and
-        ``prep_cached`` (whether the layout cache was warm). ``spans`` is the
+        ``device_s`` (the fused training dispatch, synchronized),
+        ``prep_cached`` (whether the layout cache was warm) and
+        ``cg_gram_entry_share`` (the share of padded entries in buckets whose
+        CG ran on the explicit Gramian, ``ops.als.cg_uses_gramian``; 0 under
+        Cholesky). ``spans`` is the
         same call as a per-fit ``Timer`` snapshot (``{"totals", "counts"}``;
         each also an ``albedo.<name>`` host span in a profiler trace):
         ``fit`` > ``fit.admission``, ``fit.prep`` (children: see
@@ -764,6 +773,7 @@ class ImplicitALS:
             "mode": "resident",
             "capacity": None if admission is None else admission.to_dict(),
             "capacity_cross_check": cross,
+            "cg_gram_entry_share": self._cg_gram_entry_share(g[1].shape for g in (*ug, *ig)),
         }
 
         return ALSModel(user_factors=user_f, item_factors=item_f, rank=self.rank)
@@ -883,6 +893,9 @@ class ImplicitALS:
             "mode": "chunked",
             "capacity": None if admission is None else admission.to_dict(),
             "chunked_shapes": len(executables),
+            "cg_gram_entry_share": self._cg_gram_entry_share(
+                b.shape for b in (*user_buckets, *item_buckets)
+            ),
         }
         return ALSModel(user_factors=user_f, item_factors=item_f, rank=self.rank)
 
@@ -963,6 +976,9 @@ class ImplicitALS:
             "capacity": None if admission is None else admission.to_dict(),
             "streamed_buckets": stats["streamed_buckets"],
             "sharded_shapes": stats["n_shapes"],
+            "cg_gram_entry_share": self._cg_gram_entry_share(
+                b.shape for b in (*user_buckets, *item_buckets)
+            ),
             # Pipelined-dataflow accounting: upload_s accumulates inside the
             # background prefetch thread when pipelined+streamed, so it is
             # OFF the critical path there; prefetch_wait_s is the time the

@@ -13,10 +13,12 @@ reference's NDCG is reproducible — SURVEY.md section 7 hard part (b)):
 The reference executes this inside Spark MLlib as shuffled user/item blocks
 with per-block LAPACK Cholesky on executors. Here each half-sweep is a set of
 fixed-shape bucket solves: gather ``Y[idx] -> (B, L, k)``, one fused einsum for
-the Gramian correction, batched solve, land solved rows by an
-inverse-permutation gather — all on the MXU, no
-shuffle. Buckets come from ``albedo_tpu.datasets.bucket_rows``. The layout is
-the same family as ALX's TPU matrix factorization (arXiv:2112.02194 — padded
+the Gramian correction (on the MXU: the Cholesky solver's every bucket, the
+CG's long rows, ``cg_uses_gramian``; the CG's short rows stay matrix-free
+multiply-reduce passes on the vector unit), batched solve, land solved rows by
+an inverse-permutation gather — no shuffle. Buckets come from
+``albedo_tpu.datasets.bucket_rows``. The layout is the same family as ALX's
+TPU matrix factorization (arXiv:2112.02194 — padded
 dense gather blocks over sharded factor tables), and the warm-started-CG fast
 path follows the iALS speedup literature (arXiv:2110.14044; the ``implicit``
 package's CG solver).
@@ -35,14 +37,15 @@ Phases carry ``jax.named_scope`` names (HLO metadata only: the compiled code
 does not move) so a profiler trace splits the one fused program by what the
 source says and not by what kind of fusion XLA emitted: ``als.init``,
 ``als.gramian``, ``als.gather``, ``als.warm_start``, ``als.cg`` (``.rhs``,
-``.precond``, ``.matvec``, ``.update`` inside it), ``als.cholesky``,
-``als.landing``. They sit in the shared bodies, so the chunked and sharded
-paths inherit them.
+``.gram`` — long rows only —, ``.precond``, ``.matvec``, ``.update`` inside
+it), ``als.cholesky``, ``als.landing``. They sit in the shared bodies, so the
+chunked and sharded paths inherit them.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -172,6 +175,33 @@ def solve_corrected(
         return jax.scipy.linalg.cho_solve((chol, True), b_vec[..., None])[..., 0]
 
 
+# A bucket's CG runs on its explicit (B, k, k) Gramian once its padded length
+# L reaches this many ranks k. Building the Gramian is one MXU contraction
+# over the gathered (B, L, k) block, and the Gramian is k/L of the block's
+# size, so the matvecs stop streaming the block. On a v5e the explicit form
+# loses or ties at L = k and wins by 22% (rank 128) and 29% (rank 50) at
+# L = 2k, more beyond (PERF.md section 5).
+CG_GRAM_LEN_PER_RANK = 2
+
+
+def cg_uses_gramian(length: int, rank: int) -> bool:
+    """Whether a bucket of padded length ``length`` solves its CG on the
+    explicit Gramian (``bucket_cg_body``). Static shapes only: every caller of
+    the one CG body, and the fit report's counter, share this choice."""
+    return length >= CG_GRAM_LEN_PER_RANK * rank
+
+
+def cg_gram_entry_share(shapes, rank: int) -> float:
+    """Share of a CG fit's padded entries, over the bucket shapes ``(..., B, L)``
+    of both sides, in buckets whose CG takes the explicit-Gramian form."""
+    total = gram = 0
+    for shape in shapes:
+        entries = math.prod(shape)
+        total += entries
+        gram += entries * cg_uses_gramian(shape[-1], rank)
+    return gram / total if total else 0.0
+
+
 def bucket_cg_body(
     source: jax.Array,   # (n_source, k) fixed side's factors
     yty: jax.Array,      # (k, k) gramian of `source`
@@ -184,12 +214,18 @@ def bucket_cg_body(
     cg_steps: int,
     gather_dtype=None,   # None = f32 gathers; "bfloat16" halves streamed bytes
 ) -> jax.Array:
-    """Matrix-free Jacobi-preconditioned conjugate gradient on the implicit
-    normal equations — never materializes the (B, k, k) systems.
+    """Jacobi-preconditioned conjugate gradient on the implicit normal
+    equations, in the association the bucket's static shape makes cheaper.
 
-    The matvec ``A p = YtY p + Y_u^T (alpha r (.) (Y_u p)) + reg n_u p`` is two
-    gathered einsums, so each CG step costs ~4 B L k MXU FLOPs versus the
-    Cholesky path's k^3-shaped factorization, which XLA executes as ~k
+    Short rows (``L < CG_GRAM_LEN_PER_RANK * k``) stay matrix-free: the matvec
+    ``A p = YtY p + Y_u^T (alpha r (.) (Y_u p)) + reg n_u p`` is two passes
+    over the gathered block and the (B, k, k) systems are never built. Long
+    rows (``cg_uses_gramian``) build ``A`` once, one MXU contraction over the
+    block, and iterate on it: the same iterates in exact arithmetic, without
+    streaming the block from HBM for every matvec.
+
+    Either way each CG step is cheap next to the Cholesky path's k^3-shaped
+    factorization, which XLA executes as ~k
     sequential panel steps at a few GF/s on TPU (measured 6 GF/s; the einsum
     phases of the same sweep hit ~1 TF/s). Warm-starting from the previous
     sweep's factors makes a few CG steps per half-sweep converge to the same
@@ -215,24 +251,53 @@ def _cg_solve(gathered, yty, val, mask, x0, reg, alpha, cg_steps):
             "blk,bl->bk", gathered, w, preferred_element_type=jnp.float32
         )
 
-    # Jacobi preconditioner: diag(A) = diag(YtY) + sum_l c1 y_l^2 + reg n.
-    with jax.named_scope("als.cg.precond"):
-        diag = (
-            jnp.diagonal(yty)[None]
-            + _gdot("blk,bl->bk", gathered * gathered, c1)
-            + (reg * n_b)[:, None]
-        )
-        diag = jnp.maximum(diag, 1e-12)
-
-    def matvec(p):
-        with jax.named_scope("als.cg.matvec"):
-            t = c1 * _gdot("blk,bk->bl", gathered, p)
-            return (
-                p @ yty
-                + _gdot("blk,bl->bk", gathered, t)
-                + (reg * n_b)[:, None] * p
+    if cg_uses_gramian(*gathered.shape[1:]):
+        with jax.named_scope("als.cg.gram"):
+            # A = YtY + sum_l c1 y y^T + reg n I. The scaling is an
+            # elementwise producer of the contraction's operand, for XLA to
+            # fuse into it rather than write a second (B, L, k) block.
+            scaled = gathered * c1[..., None].astype(gathered.dtype)
+            a_mat = (
+                yty[None]
+                + jnp.einsum(
+                    "blk,blm->bkm", scaled, gathered,
+                    preferred_element_type=jnp.float32,
+                )
+                + (reg * n_b)[:, None, None] * jnp.eye(yty.shape[0], dtype=jnp.float32)
             )
+        with jax.named_scope("als.cg.precond"):
+            diag = jnp.maximum(jnp.diagonal(a_mat, axis1=1, axis2=2), 1e-12)
 
+        def matvec(p):
+            with jax.named_scope("als.cg.matvec"):
+                return jnp.einsum(
+                    "bkm,bm->bk", a_mat, p, preferred_element_type=jnp.float32
+                )
+    else:
+        # Jacobi preconditioner: diag(A) = diag(YtY) + sum_l c1 y_l^2 + reg n.
+        with jax.named_scope("als.cg.precond"):
+            diag = (
+                jnp.diagonal(yty)[None]
+                + _gdot("blk,bl->bk", gathered * gathered, c1)
+                + (reg * n_b)[:, None]
+            )
+            diag = jnp.maximum(diag, 1e-12)
+
+        def matvec(p):
+            with jax.named_scope("als.cg.matvec"):
+                t = c1 * _gdot("blk,bk->bl", gathered, p)
+                return (
+                    p @ yty
+                    + _gdot("blk,bl->bk", gathered, t)
+                    + (reg * n_b)[:, None] * p
+                )
+
+    return _pcg(matvec, diag, b_vec, x0, cg_steps)
+
+
+def _pcg(matvec, diag, b_vec, x0, cg_steps: int):
+    """``cg_steps`` Jacobi-preconditioned CG steps on ``A x = b`` from ``x0``:
+    the one iteration both of ``_cg_solve``'s forms of ``A`` share."""
     tiny = jnp.float32(1e-30)
     x = x0
     ax = matvec(x)
@@ -359,7 +424,7 @@ def scan_half_sweep(
     Each row appears in exactly one bucket, so scan order within a half-sweep
     is irrelevant. ``solver="cholesky"`` is the exact MLlib-parity solve
     (``bucket_solve_body``, shared with the per-bucket and shard_map paths);
-    ``solver="cg"`` is the matrix-free warm-started CG (``bucket_cg_body``).
+    ``solver="cg"`` is the warm-started CG (``bucket_cg_body``).
 
     ``landing`` (``models.als`` precomputes it on host) is the inverse
     permutation that lands solved rows by a GATHER from

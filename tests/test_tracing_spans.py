@@ -12,7 +12,7 @@ import pytest
 
 from albedo_tpu.datasets.synthetic import synthetic_stars
 from albedo_tpu.models.als import ImplicitALS
-from albedo_tpu.ops.als import als_init_fit_fused
+from albedo_tpu.ops.als import CG_GRAM_LEN_PER_RANK, als_init_fit_fused
 from albedo_tpu.utils.aot import persistent_aot_executable, reset_memory_cache
 from albedo_tpu.utils.profiling import SPAN_PREFIX, Timer
 
@@ -22,6 +22,10 @@ SCOPES = {
            "als.cg.rhs", "als.cg.precond", "als.cg.matvec", "als.cg.update", "als.landing"),
     "cholesky": ("als.init", "als.gramian", "als.gather", "als.cholesky", "als.landing"),
 }
+SCOPES["cg-long"] = SCOPES["cg"] + ("als.cg.gram",)
+# rows cut to under CG_GRAM_LEN_PER_RANK ranks never build their Gramian; whole rows do
+RANK = 4
+MAX_LEN = {"cg": CG_GRAM_LEN_PER_RANK * RANK // 2, "cholesky": None, "cg-long": None}
 
 
 @pytest.fixture(autouse=True)
@@ -158,39 +162,43 @@ def test_degraded_paths_publish_spans_from_the_clock_reads_they_make(kwargs):
     assert_children_within_parents(totals)
 
 
-def fused_fit_text(solver: str) -> tuple[str, str]:
+def fused_fit_text(case: str) -> tuple[str, str]:
     """Compiled text of ``als_init_fit_fused`` at a tiny shape, acquired
     through the AOT layer as ``fit`` acquires it, and the layer's source."""
     m = stars(seed=43)
-    als = ImplicitALS(rank=4, max_iter=2, seed=3, solver=solver)
+    solver = case.partition("-")[0]
+    als = ImplicitALS(rank=RANK, max_iter=2, seed=3, solver=solver, max_len=MAX_LEN[case])
     ug, ig, u_land, i_land = als.device_groups(m)
     args = (jax.random.PRNGKey(0), ug, ig, jnp.float32(0.5), jnp.float32(40.0), jnp.int32(2))
     compiled, _, source = persistent_aot_executable(
         als_init_fit_fused, args, dict(user_landing=u_land, item_landing=i_land),
-        dict(n_users=m.n_users, n_items=m.n_items, rank=4, solver=solver, cg_steps=3,
+        dict(n_users=m.n_users, n_items=m.n_items, rank=RANK, solver=solver, cg_steps=3,
              gather_dtype=None),
-        key_parts=("test_tracing_spans", solver), name="als_init_fit_fused",
+        key_parts=("test_tracing_spans", case), name="als_init_fit_fused",
     )
     return compiled.as_text(), source
 
 
-@pytest.mark.parametrize("solver", ["cg", "cholesky"])
+@pytest.mark.parametrize("case", ["cg", "cholesky", "cg-long"])
 @pytest.mark.parametrize("acquisition", ["fresh", "second_process"])
-def test_compiled_fit_carries_every_scope_and_a_stable_module_name(solver, acquisition):
-    text, source = fused_fit_text(solver)
+def test_compiled_fit_carries_every_scope_and_a_stable_module_name(case, acquisition):
+    text, source = fused_fit_text(case)
     if acquisition == "second_process":
         reset_memory_cache()
-        text, source = fused_fit_text(solver)
+        text, source = fused_fit_text(case)
         # the CPU's Cholesky is a LAPACK custom call and never leaves memory
-        assert source == ("disk" if solver == "cg" else "compile")
+        assert source == ("compile" if case == "cholesky" else "disk")
     else:
         assert source == "compile"
     assert re.search(r"^HloModule jit_als_init_fit_fused\b", text, re.M)
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
-    for scope in SCOPES[solver]:
+    for scope in SCOPES[case]:
         assert any(f"/{scope}/" in name for name in op_names), scope
     # sub-scopes nest inside their parent; the gather is not inside the solve
-    assert any("/als.cg/als.cg.matvec/" in n for n in op_names) or solver != "cg"
+    assert any("/als.cg/als.cg.matvec/" in n for n in op_names) or case == "cholesky"
+    # only a long row's CG builds its Gramian, and inside als.cg
+    assert any("/als.cg/als.cg.gram/" in n for n in op_names) == (case == "cg-long")
+    assert not any(re.search(r"(?<!/als\.cg)/als\.cg\.gram/", n) for n in op_names)
     assert not any(re.search(r"/als\.(cg|cholesky)/.*als\.gather", n) for n in op_names)
 
 
