@@ -45,6 +45,16 @@ from albedo_tpu.utils.profiling import Timer
 # exactly like they kill the resident path mid-checkpoint.
 _CHUNKED_FAULT = faults.site("als.chunked")
 
+# The spans a chunked (host-streamed) fit publishes in
+# ``last_fit_report["spans"]`` and, as ``albedo.<name>``, in a profiler trace
+# (``ImplicitALS._fit_chunked`` says what each is around). Tests and the
+# benchmark's ``fit_streamed`` driver import this tuple.
+CHUNKED_SPANS = (
+    "fit", "fit.admission", "fit.prep", "fit.init",
+    "fit.stream", "fit.stream.gramian", "fit.stream.upload",
+    "fit.stream.acquire", "fit.stream.dispatch", "fit.wait",
+)
+
 
 class ALSModel:
     """Trained factor matrices, indexed by dense user/item indices.
@@ -582,7 +592,9 @@ class ImplicitALS:
         fallback (:meth:`_fit_chunked`) instead of dispatching a resident
         upload that would ``RESOURCE_EXHAUSTED``. ``self.chunked`` forces
         either path; a warm groups cache implies the resident slabs already
-        fit (they are on device now).
+        fit (they are on device now), and a ``degrade`` verdict is kept with
+        the matrix's layout, so later fits of it take the chunked path under
+        that verdict without pricing the matrix again.
 
         The returned model's factors are device arrays, fully computed on
         return (``block_until_ready``) — host copies materialize lazily via
@@ -618,8 +630,18 @@ class ImplicitALS:
         if use_chunked is None:
             use_chunked = False
             if self.mesh is None and not cache_warm and capacity_mod.enabled():
-                with timer.section("fit.admission"):
-                    admission = self.admission(matrix)
+                # A degrade verdict stays with the matrix's layout, as a warm
+                # groups cache stands for a resident one: the next fit of this
+                # layout does not price the same matrix again (two bincounts
+                # over every entry).
+                verdict_key = ("degrade_verdict", self.rank, self.gather_dtype,
+                               *self._groups_cache_key())
+                admission = _matrix_cache(matrix).get(verdict_key)
+                if admission is None:
+                    with timer.section("fit.admission"):
+                        admission = self.admission(matrix)
+                    if admission.verdict == "degrade":
+                        _matrix_cache(matrix)[verdict_key] = admission
                 use_chunked = admission.verdict == "degrade"
         if use_chunked:
             return self._fit_chunked(matrix, callback, admission, t0, timer)
@@ -778,6 +800,18 @@ class ImplicitALS:
 
         return ALSModel(user_factors=user_f, item_factors=item_f, rank=self.rank)
 
+    def _chunked_executables(self, matrix: StarMatrix) -> dict:
+        """The chunked path's per-shape executables, kept with the matrix's
+        layout (``_matrix_cache``) under this estimator's compile-time
+        settings and keyed by ``(n_source, n_target, bucket shape)``: a
+        second fit of the same estimator and matrix dispatches them as they
+        are, as the resident path finds its one program."""
+        key = (
+            "chunked_executables", self.solver, self.cg_steps, self.gather_dtype,
+            self.rank, jax.default_backend(),
+        )
+        return _matrix_cache(matrix).setdefault(key, {})
+
     def _fit_chunked(
         self,
         matrix: StarMatrix,
@@ -793,12 +827,35 @@ class ImplicitALS:
         the fused path (``ops.als.chunked_bucket_update`` wraps
         ``bucket_solve_body``/``bucket_cg_body``), so the result is
         numerics-parity with the resident path (pinned by
-        ``tests/test_als_chunked.py``) at a host-bandwidth-bound pace —
-        slower, never dead. Per-shape executables are acquired through the
+        ``tests/test_als_chunked.py``) — slower, never dead. Measured on one
+        v5e at 10M x 1M x 100M stars, rank 128 (``gh10m-r128.fit-streamed``,
+        PERF.md section 5, PR 29): 4,320 ms a sweep over 1,455 buckets
+        against a resident plan that does not fit, the chip idle 3.1% of a
+        fit — at that size the device sets the pace (gather 51%, CG 27%, the
+        per-bucket landing scatter 19%) and the host's uploads (1.1 ms a
+        bucket) and dispatches hide behind it. Per-shape executables are
+        acquired through the
         persistent AOT layer, NOT bare jit: chunked fits run in exactly the
         kill-resume chaos that exposed the PR 4 XLA-cache custom-call
         corruption, so their cross-process executable reuse must stay
-        fingerprint-verified too.
+        fingerprint-verified too. Every shape of the layout is acquired
+        ahead of the first sweep, side by side on a thread pool (abstract
+        arguments: no table exists yet, so a probe's tables are the only
+        ones on the device), and kept with the matrix for the estimator's
+        later fits (:meth:`_chunked_executables`).
+
+        Spans (``CHUNKED_SPANS``): ``fit.prep`` (host bucketing),
+        ``fit.init`` (the seeded tables), one ``fit.stream`` a half-sweep
+        with one ``fit.stream.gramian`` and, a bucket, ``fit.stream.upload``
+        (the slab's four arrays), ``fit.stream.acquire`` (the executable's
+        look-up) and ``fit.stream.dispatch`` (the compiled call);
+        ``fit.wait`` is the health read that ends the fit. On a cold
+        estimator one more ``fit.stream`` comes first, holding one
+        ``fit.stream.acquire`` around the acquisition of every shape
+        (``fit.acquire`` = ``compile_s`` repeats its wall-clock; the AOT
+        layer's branches under it are thread-seconds). The host runs ahead
+        of the device: a span is the host's time in the call, and what the
+        device still owes is in ``fit.wait``.
         """
         from albedo_tpu.ops.als import chunked_bucket_update, gramian
 
@@ -808,62 +865,88 @@ class ImplicitALS:
             user_buckets, item_buckets = self._host_buckets(matrix)
         t1 = time.perf_counter()
 
-        if self.init_factors is not None:
-            user_f = jnp.asarray(self.init_factors[0], jnp.float32)
-            item_f = jnp.asarray(self.init_factors[1], jnp.float32)
-        else:
-            # Eager seeded init: same traced PRNG ops + key as the fused
-            # init, so the values are identical (see als_init_fit_fused).
-            key = jax.random.PRNGKey(self.seed)
-            ukey, ikey = jax.random.split(key)
-            scale = 1.0 / np.sqrt(self.rank)
-            user_f = jax.random.normal(ukey, (matrix.n_users, self.rank), jnp.float32) * scale
-            item_f = jax.random.normal(ikey, (matrix.n_items, self.rank), jnp.float32) * scale
-
-        reg = jnp.float32(self.reg_param)
-        alpha = jnp.float32(self.alpha)
         statics = dict(
             solver=self.solver, cg_steps=self.cg_steps,
             gather_dtype=self.gather_dtype,
         )
-        executables: dict[tuple, Any] = {}
-        compile_s = 0.0
+        executables = self._chunked_executables(matrix)
         compile_sources: set[str] = set()
+        dev = jax.devices()[0]
 
-        def run_bucket(source, yty, target, b: Bucket):
-            nonlocal compile_s
+        def acquire(n_source: int, n_target: int, shape: tuple):
+            """One shape's executable and where it came from, from abstract
+            arguments."""
+            f32, i32 = jnp.float32, jnp.int32
+            sds = jax.ShapeDtypeStruct
             args = (
-                source, yty, target,
-                jnp.asarray(b.row_ids), jnp.asarray(b.idx),
-                jnp.asarray(b.val), jnp.asarray(b.mask), reg, alpha,
+                sds((n_source, self.rank), f32), sds((self.rank, self.rank), f32),
+                sds((n_target, self.rank), f32), sds(shape[:1], i32),
+                sds(shape, i32), sds(shape, f32), sds(shape, jnp.bool_),
+                sds((), f32), sds((), f32),
             )
-            key2 = (source.shape[0], target.shape[0], b.shape)
-            compiled = executables.get(key2)
-            if compiled is None:
-                dev = jax.devices()[0]
-                compiled, c_s, source_tag = persistent_aot_executable(
-                    chunked_bucket_update, args, None, statics,
-                    key_parts=(
-                        "als_chunked", jax.__version__, jax.default_backend(),
-                        getattr(dev, "device_kind", "?"),
-                        self.solver, self.cg_steps, self.gather_dtype,
-                        self.rank, source.shape[0], target.shape[0], b.shape,
-                    ),
-                    name="als_chunked",
-                )
-                executables[key2] = compiled
-                compile_s += c_s
-                compile_sources.add(source_tag)
-            return compiled(*args)
+            compiled, _, source_tag = persistent_aot_executable(
+                chunked_bucket_update, args, None, statics,
+                key_parts=(
+                    "als_chunked", jax.__version__, jax.default_backend(),
+                    getattr(dev, "device_kind", "?"),
+                    self.solver, self.cg_steps, self.gather_dtype,
+                    self.rank, n_source, n_target, shape,
+                ),
+                name="als_chunked", timer=timer, span="fit.stream.acquire",
+                donate_argnums=(2,),  # target, as chunked_bucket_update's own
+            )
+            return compiled, source_tag
+
+        sides = (
+            (matrix.n_users, matrix.n_items, item_buckets),
+            (matrix.n_items, matrix.n_users, user_buckets),
+        )
+        missing = list(dict.fromkeys(
+            key for n_source, n_target, buckets in sides for b in buckets
+            if (key := (n_source, n_target, b.shape)) not in executables
+        ))
+        compile_s = 0.0
+        if missing:
+            with timer.section("fit.stream"), timer.section("fit.stream.acquire"), \
+                    ThreadPoolExecutor(max_workers=_bucket_workers() or 1) as pool:
+                for key, (compiled, source_tag) in zip(
+                        missing, pool.map(lambda k: acquire(*k), missing)):
+                    executables[key] = compiled
+                    compile_sources.add(source_tag)
+            compile_s = time.perf_counter() - t1
+        timer.add("fit.acquire", compile_s)
+
+        with timer.section("fit.init"):
+            if self.init_factors is not None:
+                user_f = jnp.asarray(self.init_factors[0], jnp.float32)
+                item_f = jnp.asarray(self.init_factors[1], jnp.float32)
+            else:
+                # Eager seeded init: same traced PRNG ops + key as the fused
+                # init, so the values are identical (see als_init_fit_fused).
+                key = jax.random.PRNGKey(self.seed)
+                ukey, ikey = jax.random.split(key)
+                scale = 1.0 / np.sqrt(self.rank)
+                user_f = jax.random.normal(ukey, (matrix.n_users, self.rank), jnp.float32) * scale
+                item_f = jax.random.normal(ikey, (matrix.n_items, self.rank), jnp.float32) * scale
+            reg = jnp.float32(self.reg_param)
+            alpha = jnp.float32(self.alpha)
 
         def half_sweep(source, target, buckets):
             # The chaos hook: an armed kill dies genuinely mid-stream; an
             # armed error/oom surfaces as a failed fit for the pipeline's
             # fail-fast (not retried: is_resource_exhausted) handling.
             _CHUNKED_FAULT.hit()
-            yty = gramian(source)
-            for b in buckets:
-                target = run_bucket(source, yty, target, b)
+            with timer.section("fit.stream"):
+                with timer.section("fit.stream.gramian"):
+                    yty = gramian(source)
+                for b in buckets:
+                    with timer.section("fit.stream.upload"):
+                        slab = (jnp.asarray(b.row_ids), jnp.asarray(b.idx),
+                                jnp.asarray(b.val), jnp.asarray(b.mask))
+                    with timer.section("fit.stream.acquire"):
+                        compiled = executables[source.shape[0], target.shape[0], b.shape]
+                    with timer.section("fit.stream.dispatch"):
+                        target = compiled(source, yty, target, *slab, reg, alpha)
             return target
 
         for it in range(self.max_iter):
@@ -880,11 +963,12 @@ class ImplicitALS:
         with timer.section("fit.wait"):
             health = health_dict(factor_health(user_f, item_f))
         t2 = time.perf_counter()
-        timer.add("fit.acquire", compile_s)
+        n_buckets = {"user": len(user_buckets), "item": len(item_buckets)}
         self.last_fit_report = {
             "prep_s": round(t1 - t0, 4),
             "bucket_s": round(t1 - t0, 4),
-            "upload_s": 0.0,  # uploads are streamed per bucket, inside device_s
+            # Host seconds in the per-bucket uploads (inside device_s).
+            "upload_s": round(timer.totals.get("fit.stream.upload", 0.0), 4),
             "compile_s": round(compile_s, 4),
             "compile_source": "+".join(sorted(compile_sources)) or None,
             "device_s": round(t2 - t1 - compile_s, 4),
@@ -893,6 +977,12 @@ class ImplicitALS:
             "mode": "chunked",
             "capacity": None if admission is None else admission.to_dict(),
             "chunked_shapes": len(executables),
+            "dispatches": self.max_iter * sum(n_buckets.values()),
+            "buckets": n_buckets,
+            "streamed_bytes_per_sweep": sum(
+                capacity_mod.bucket_slab_bytes(*b.shape)
+                for b in (*user_buckets, *item_buckets)
+            ),
             "cg_gram_entry_share": self._cg_gram_entry_share(
                 b.shape for b in (*user_buckets, *item_buckets)
             ),

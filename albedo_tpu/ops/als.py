@@ -39,7 +39,9 @@ source says and not by what kind of fusion XLA emitted: ``als.init``,
 ``als.gramian``, ``als.gather``, ``als.warm_start``, ``als.cg`` (``.rhs``,
 ``.gram`` — long rows only —, ``.precond``, ``.matvec``, ``.update`` inside
 it), ``als.cholesky``, ``als.landing``. They sit in the shared bodies, so the
-chunked and sharded paths inherit them.
+chunked and sharded paths inherit them; the chunked path's per-bucket landing
+scatter into the donated table is ``als.chunk.scatter`` (around its
+``als.landing``).
 """
 
 from __future__ import annotations
@@ -379,7 +381,8 @@ def chunked_bucket_update(
         solved = bucket_solve_body(
             source, yty, idx, val, mask, reg, alpha, gather_dtype=gather_dtype,
         )
-    return scatter_solved(target, row_ids, solved)
+    with jax.named_scope("als.chunk.scatter"):
+        return scatter_solved(target, row_ids, solved)
 
 
 def als_half_sweep(

@@ -37,7 +37,14 @@ verification can make that reuse safe). Three scoped defenses:
    executables exhibit) and a SHA-256 of its output bytes lands in a
    ``.fp`` sidecar; a deserializing process replays the probe and, on
    mismatch, deletes the export and recompiles
-   (``albedo_aot_fingerprint_mismatches_total{name=}``).
+   (``albedo_aot_fingerprint_mismatches_total{name=}``). The probe's cost
+   follows the program's size: an argument or output of more than
+   ``_PROBE_HOST_ELEMS`` elements is made, and digested, ON THE DEVICE
+   (a factor table of a chunked fit is 1.28e9 elements: built in numpy and
+   hashed on the host it was ~10 GB and tens of seconds a probe), probes
+   run one at a time, and the sidecar carries the digest's version so that
+   one written under another scheme reads as "recompile once", never as
+   corruption.
 3. **Export-failed programs** (custom-call status unknown) get the same
    probe fingerprint across the XLA-cache boundary: mismatch recompiles
    with the cache bypassed.
@@ -102,6 +109,15 @@ class LRUCache:
 _EXECUTABLES = LRUCache(maxsize=int(os.environ.get("ALBEDO_AOT_MEMORY_SLOTS", "8")))
 # Serializes the XLA-cache bypass toggle (see _compile_bypassing_xla_cache).
 _BYPASS_LOCK = named_lock("utils.aot.bypass")
+# One probe on the device at a time: concurrent acquisitions (the chunked
+# fit warms its ~130 shapes from a thread pool) compile side by side, but a
+# probe may hold a second copy of both factor tables.
+_PROBE_LOCK = named_lock("utils.aot.probe")
+# Leaves of up to this many elements are probed as before, numpy on the host
+# and SHA-256 over the downloaded bytes; larger ones never visit the host.
+_PROBE_HOST_ELEMS = 1 << 24
+# Version of the probe + digest scheme, recorded in every ``.fp`` sidecar.
+_FP_VERSION = 2
 
 
 def reset_memory_cache() -> None:
@@ -176,7 +192,8 @@ def _probe_leaf(leaf):
     shows in the output bytes, and scalar hyperparameters (regularization,
     confidence) stay in well-posed territory so solver probes exercise the
     real numeric path rather than a NaN fill. Only shape/dtype are read (no
-    device download)."""
+    device download). A leaf of more than ``_PROBE_HOST_ELEMS`` elements is
+    made on the device (``_device_probe_maker``), the same patterns."""
     import numpy as np
 
     shape = getattr(leaf, "shape", None)
@@ -185,6 +202,8 @@ def _probe_leaf(leaf):
         return leaf  # python scalar static-alike: already deterministic
     dtype = np.dtype(dtype)
     size = int(np.prod(shape)) if shape else 1
+    if size > _PROBE_HOST_ELEMS:
+        return _device_probe_maker(tuple(shape), dtype.name, getattr(leaf, "sharding", None))()
     if dtype.kind == "b":
         return np.zeros(shape, dtype)
     if dtype.kind in "iu":
@@ -196,6 +215,73 @@ def _probe_leaf(leaf):
         return (np.arange(max(size, 1))[:size] % 7).reshape(shape).astype(dtype)
     ramp = (np.arange(max(size, 1)) % 61).astype(np.float64) / 122.0 + 0.25
     return ramp[:size].reshape(shape).astype(dtype)
+
+
+@functools.lru_cache(maxsize=64)
+def _device_probe_maker(shape: tuple, dtype_name: str, sharding):
+    """The jitted maker of one large probe leaf, laid out as the argument it
+    stands in for where that has a sharding. The flat position of element
+    ``(row, col)`` is ``row * cols + col``; its residues come from the row's
+    and the column's, so no position ever leaves 32 bits."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    dtype = np.dtype(dtype_name)
+    cols = shape[-1]
+    rows = int(np.prod(shape[:-1]))
+
+    def residues(mod: int):
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) % mod
+        c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1) % mod
+        return (r * (cols % mod) + c) % mod
+
+    def make():
+        if dtype.kind == "b":
+            out = jnp.zeros((rows, cols), dtype)
+        elif dtype.kind in "iu":
+            out = residues(7).astype(dtype)
+        else:
+            out = (residues(61).astype(jnp.float32) / 122.0 + 0.25).astype(dtype)
+        return out.reshape(shape)
+
+    # The AOT layer's own helper: a few elementwise ops with nothing to reuse
+    # across processes but what the XLA cache already keeps.
+    return jax.jit(make, out_shardings=sharding)
+
+
+@functools.lru_cache(maxsize=64)
+def _device_digest_fn(shape: tuple, dtype_name: str):
+    """The jitted digest of one large output leaf: two position-weighted sums
+    of its bit patterns, modulo 2**32 (whole-number sums, so the order the
+    device adds them in cannot change them). Any element that drifts by one
+    bit moves both."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    dtype = np.dtype(dtype_name)
+    cols = shape[-1]
+    rows = int(np.prod(shape[:-1]))
+
+    def digest(x):
+        x = x.reshape(rows, cols)
+        if dtype.kind == "b":
+            bits = x.astype(jnp.uint32)
+        else:
+            bits = jax.lax.bitcast_convert_type(
+                x, {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[dtype.itemsize]
+            ).astype(jnp.uint32)
+        r = jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 0)
+        c = jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 1)
+        pos = r * jnp.uint32(cols % (1 << 32)) + c  # wraps, by design
+        odd = pos * jnp.uint32(2) + jnp.uint32(1)
+        return jnp.stack([
+            jnp.sum(bits * odd, dtype=jnp.uint32),
+            jnp.sum((bits ^ (pos * jnp.uint32(0x9E3779B1))) * odd, dtype=jnp.uint32),
+        ])
+
+    return jax.jit(digest)
 
 
 def _xla_persistent_cache_engaged() -> bool:
@@ -238,24 +324,46 @@ def _compile_bypassing_xla_cache(jitted, args, dyn_kwargs, static_kwargs, sectio
 
 
 def _output_fingerprint(compiled, args: tuple, dyn_kwargs: dict, section) -> str:
-    """Run ``compiled`` on the deterministic probe and hash the raw output
-    bytes (shape + dtype + buffer; NaNs compare by representation). Every
-    probe run is one ``probe`` span of the caller's ``section``."""
+    """Run ``compiled`` on the deterministic probe and hash the output
+    (shape + dtype + the raw bytes, NaNs by representation; for a leaf of
+    more than ``_PROBE_HOST_ELEMS`` elements the eight bytes of its
+    on-device digest instead). Every probe run is one ``probe`` span of the
+    caller's ``section``; probes of concurrent acquisitions take turns."""
     import jax
     import numpy as np
 
-    with section("probe"):
+    with _PROBE_LOCK, section("probe"):
         probe_args, probe_kwargs = jax.tree_util.tree_map(
             _probe_leaf, (tuple(args), dict(dyn_kwargs))
         )
         out = compiled(*probe_args, **probe_kwargs)
+        del probe_args, probe_kwargs
         h = hashlib.sha256()
         for leaf in jax.tree_util.tree_leaves(out):
-            arr = np.asarray(leaf)
-            h.update(str(arr.shape).encode())
-            h.update(str(arr.dtype).encode())
-            h.update(arr.tobytes())
+            h.update(str(tuple(leaf.shape)).encode())
+            h.update(str(np.dtype(leaf.dtype)).encode())
+            if leaf.size > _PROBE_HOST_ELEMS:
+                leaf = _device_digest_fn(tuple(leaf.shape), np.dtype(leaf.dtype).name)(leaf)
+            h.update(np.asarray(leaf).tobytes())
         return h.hexdigest()
+
+
+def _write_fingerprint(fp_path: Path, sha256: str) -> None:
+    fp_tmp = fp_path.with_name(fp_path.name + f".tmp{os.getpid()}")
+    fp_tmp.write_text(json.dumps({"sha256": sha256, "v": _FP_VERSION}))
+    os.replace(fp_tmp, fp_path)
+
+
+def _read_fingerprint(fp_path: Path) -> str | None:
+    """The recorded digest, or nothing where the sidecar names another
+    version of the probe: that is an export this build cannot verify
+    (recompile once), not a divergent executable. (A sidecar from before
+    the version was written sits beside an export of another code
+    fingerprint, which no look-up of this build finds.)"""
+    recorded = json.loads(fp_path.read_text())
+    if recorded.get("v", _FP_VERSION) != _FP_VERSION:
+        return None
+    return recorded.get("sha256")
 
 
 def _named_call(call, name: str):
@@ -336,6 +444,7 @@ def persistent_aot_executable(
     name: str = "fn",
     timer: Any | None = None,
     span: str = "acquire",
+    donate_argnums: tuple[int, ...] = (),
 ) -> tuple[Any, float, str]:
     """Resolve the cached executable WITHOUT calling it.
 
@@ -349,6 +458,10 @@ def persistent_aot_executable(
     (``utils.profiling.Timer``; a throwaway one when none is given, so the
     trace spans exist either way) named ``<span>.deserialize``,
     ``.lower_compile``, ``.export``, ``.serialize`` and ``.probe``.
+
+    ``donate_argnums`` repeats ``jitted``'s own donated positions: an
+    export does not carry donation, so the program compiled from one (fresh
+    or deserialized) donates only what is named here.
     """
     t0 = time.perf_counter()
     import jax
@@ -395,7 +508,9 @@ def persistent_aot_executable(
                 if _custom_call_targets(restored):
                     raise ValueError("serialized module contains custom calls")
             with section("lower_compile"):
-                compiled = jax.jit(_named_call(restored.call, name)).lower(
+                compiled = jax.jit(
+                    _named_call(restored.call, name), donate_argnums=donate_argnums
+                ).lower(
                     *args, **dyn_kwargs
                 ).compile()
             # Self-check: the deserialized executable must reproduce the
@@ -404,27 +519,33 @@ def persistent_aot_executable(
             # export and recompile rather than serve drifted numerics.
             fp_path = _fingerprint_path(path)
             if fingerprint_enabled() and fp_path.exists():
-                expected = json.loads(fp_path.read_text()).get("sha256")
-                got = _output_fingerprint(compiled, args, dyn_kwargs, section)
-                if got != expected:
-                    from albedo_tpu.utils import events
+                expected = _read_fingerprint(fp_path)
+                if expected is None:
+                    log.info("AOT export %s carries another probe version; "
+                             "recompiling once", path.name)
+                    branch.append("disk-fingerprint-version")
+                else:
+                    got = _output_fingerprint(compiled, args, dyn_kwargs, section)
+                    if got == expected:
+                        source = "disk"
+                        branch.append("disk-verified")
+                    else:
+                        from albedo_tpu.utils import events
 
-                    events.aot_fingerprint_mismatches.inc(name=name)
-                    log.warning(
-                        "AOT export %s output fingerprint mismatch "
-                        "(%s != %s); discarding and recompiling",
-                        path.name, got[:12], str(expected)[:12],
-                    )
+                        events.aot_fingerprint_mismatches.inc(name=name)
+                        log.warning(
+                            "AOT export %s output fingerprint mismatch "
+                            "(%s != %s); discarding and recompiling",
+                            path.name, got[:12], str(expected)[:12],
+                        )
+                        branch.append("disk-fingerprint-mismatch")
+                if source != "disk":
                     for stale in (path, fp_path):
                         try:
                             stale.unlink()
                         except OSError:
                             pass
                     compiled = None
-                    branch.append("disk-fingerprint-mismatch")
-                else:
-                    source = "disk"
-                    branch.append("disk-verified")
             else:
                 source = "disk"
                 branch.append("disk-unverified")
@@ -460,7 +581,10 @@ def persistent_aot_executable(
                     # with arguments not yet laid out on its mesh cannot
                     # lower this way — that is an export failure too.)
                     with section("lower_compile"):
-                        compiled = jax.jit(_named_call(exported.call, name)).lower(
+                        compiled = jax.jit(
+                            _named_call(exported.call, name),
+                            donate_argnums=donate_argnums,
+                        ).lower(
                             *args, **dyn_kwargs
                         ).compile()
             except Exception as e:  # noqa: BLE001
@@ -502,10 +626,7 @@ def persistent_aot_executable(
                 # for later processes to trust unverified.
                 try:
                     fp = _output_fingerprint(compiled, args, dyn_kwargs, section)
-                    fp_path = _fingerprint_path(path)
-                    fp_tmp = fp_path.with_name(fp_path.name + f".tmp{os.getpid()}")
-                    fp_tmp.write_text(json.dumps({"sha256": fp}))
-                    os.replace(fp_tmp, fp_path)
+                    _write_fingerprint(_fingerprint_path(path), fp)
                     branch.append("fingerprinted")
                 except Exception as e:  # noqa: BLE001
                     branch.append(f"probe-failed:{type(e).__name__}")
@@ -565,8 +686,9 @@ def persistent_aot_executable(
                 try:
                     if got is None:
                         pass
-                    elif fp_path.exists():
-                        expected = json.loads(fp_path.read_text()).get("sha256")
+                    elif fp_path.exists() and (
+                        expected := _read_fingerprint(fp_path)
+                    ) is not None:
                         if got != expected:
                             from albedo_tpu.utils import events
 
@@ -608,11 +730,7 @@ def persistent_aot_executable(
                             )
                         else:
                             fp_path.parent.mkdir(parents=True, exist_ok=True)
-                            fp_tmp = fp_path.with_name(
-                                fp_path.name + f".tmp{os.getpid()}"
-                            )
-                            fp_tmp.write_text(json.dumps({"sha256": baseline}))
-                            os.replace(fp_tmp, fp_path)
+                            _write_fingerprint(fp_path, baseline)
                             branch.append("baseline-second-compile")
                             if got != baseline:
                                 branch.append("xla-cache-fingerprint-mismatch")

@@ -345,6 +345,12 @@ def _dtype_bytes(gather_dtype: str | None) -> int:
     return 2 if gather_dtype == "bfloat16" else 4
 
 
+def bucket_slab_bytes(b: int, ln: int) -> int:
+    """Bytes of one padded ``(b, ln)`` bucket's slab: int32 row ids, int32
+    indices, float32 values, one-byte mask."""
+    return b * 4 + b * ln * (4 + 4 + 1)
+
+
 def plan_fit(
     bucket_shapes_user: list[tuple[int, int]],
     bucket_shapes_item: list[tuple[int, int]],
@@ -377,7 +383,7 @@ def plan_fit(
     transient = 0
     for shapes, side in ((bucket_shapes_user, "u"), (bucket_shapes_item, "i")):
         for b, ln in shapes:
-            slabs += b * 4 + b * ln * (4 + 4 + 1)
+            slabs += bucket_slab_bytes(b, ln)
             if side == "u":
                 slots_u += b
             else:
@@ -504,7 +510,7 @@ def plan_fit_chunked(
         for b, ln in shapes:
             worst = max(
                 worst,
-                b * 4 + b * ln * (4 + 4 + 1)
+                bucket_slab_bytes(b, ln)
                 + b * ln * (rank * gb + gb) + b * rank * rank * 4
                 + b * rank * 4,
             )

@@ -14,6 +14,13 @@ from albedo_tpu.utils import capacity, faults  # noqa: E402
 KW = dict(rank=8, max_iter=3, seed=0, batch_size=16)
 
 
+@pytest.fixture(autouse=True)
+def _two_compile_threads(monkeypatch):
+    """A chunked fit acquires its shapes on as many threads as the box has
+    cores; under the suite's parallel workers two are load enough."""
+    monkeypatch.setenv("ALBEDO_BUCKET_WORKERS", "2")
+
+
 def _matrix(seed=1):
     return synthetic_stars(n_users=70, n_items=45, mean_stars=6, seed=seed)
 
@@ -152,3 +159,133 @@ class TestAdmissionWiring:
         model = est.fit(m)
         assert np.isfinite(model.user_factors).all()
         assert est.last_fit_report["mode"] in ("sharded", "sharded_streamed")
+
+
+def _degraded(monkeypatch, matrix_seed, **overrides):
+    """An estimator and a FRESH matrix under a device budget between the
+    resident and the chunked plan: admission's own degrade, nothing forced."""
+    monkeypatch.setenv("ALBEDO_DEVICE_MEM_BYTES", "4g")
+    est = ImplicitALS(**dict(KW, solver="cg", **overrides))
+    probe = _matrix(seed=matrix_seed)
+    mid = (est.capacity_plan(probe).required_bytes
+           + est.capacity_plan(probe, chunked=True).required_bytes) // 2
+    monkeypatch.setenv("ALBEDO_DEVICE_MEM_BYTES", str(int(mid / capacity.headroom())))
+    return est, _matrix(seed=matrix_seed)
+
+
+class TestStreamObservability:
+    def test_degraded_fit_publishes_every_span_and_counter(self, monkeypatch):
+        from albedo_tpu.models.als import CHUNKED_SPANS
+
+        est, m = _degraded(monkeypatch, 8)
+        assert est.chunked is None
+        est.fit(m)
+        report = est.last_fit_report
+        assert report["mode"] == "chunked" and report["capacity"]["verdict"] == "degrade"
+        totals, counts = report["spans"]["totals"], report["spans"]["counts"]
+        assert set(CHUNKED_SPANS) <= set(totals)
+        user_buckets, item_buckets = est._host_buckets(m)
+        n_user, n_item = len(user_buckets), len(item_buckets)
+        assert report["buckets"] == {"user": n_user, "item": n_item}
+        assert report["dispatches"] == KW["max_iter"] * (n_user + n_item)
+        assert counts["fit.stream.upload"] == counts["fit.stream.dispatch"] == report["dispatches"]
+        # a cold estimator: one more fit.stream holding the one acquisition of every shape
+        assert counts["fit.stream"] == 2 * KW["max_iter"] + 1
+        assert counts["fit.stream.acquire"] == report["dispatches"] + 1
+        assert counts["fit.stream.gramian"] == 2 * KW["max_iter"]
+        assert counts["fit.stream.acquire.lower_compile"] == report["chunked_shapes"]
+        assert counts["fit.admission"] == counts["fit.init"] == counts["fit.wait"] == 1
+        # by hand: int32 row ids, int32 indices, float32 values, one-byte mask
+        by_hand = sum(
+            b.row_ids.shape[0] * 4 + b.idx.size * 4 + b.val.size * 4 + b.mask.size * 1
+            for b in (*user_buckets, *item_buckets)
+        )
+        assert by_hand == sum(
+            a.nbytes for b in (*user_buckets, *item_buckets)
+            for a in (b.row_ids, b.idx, b.val, b.mask)
+        )
+        assert report["streamed_bytes_per_sweep"] == by_hand
+        assert report["upload_s"] == pytest.approx(totals["fit.stream.upload"], abs=1e-3)
+        assert report["compile_s"] == pytest.approx(totals["fit.acquire"], abs=1e-3)
+        children = sum(totals[f"fit.stream.{c}"] for c in ("gramian", "upload", "acquire", "dispatch"))
+        assert children <= totals["fit.stream"] + 1e-3
+
+    def test_executables_outlive_the_fit(self, monkeypatch):
+        """The second fit of an estimator on its matrix acquires nothing:
+        not from memory (the layer's LRU holds 8 of these shapes), not from
+        disk."""
+        from albedo_tpu.utils import aot
+
+        est, m = _degraded(monkeypatch, 9)
+        aot.reset_memory_cache()
+        first = est.fit(m)
+        shapes = est.last_fit_report["chunked_shapes"]
+        assert shapes > 8 and est.last_fit_report["compile_source"] == "compile"
+        before = len(aot.branch_log())
+        aot.reset_memory_cache()
+        second = est.fit(m)
+        report = est.last_fit_report
+        assert len(aot.branch_log()) == before
+        assert report["mode"] == "chunked" and report["capacity"]["verdict"] == "degrade"
+        assert report["compile_s"] == 0.0 and report["compile_source"] is None
+        assert report["chunked_shapes"] == shapes
+        counts = report["spans"]["counts"]
+        assert counts["fit.stream"] == 2 * KW["max_iter"]
+        assert counts["fit.stream.acquire"] == report["dispatches"]
+        assert not any(k.startswith("fit.stream.acquire.") for k in counts)
+        # nor is the matrix priced again: the verdict stays with its layout
+        assert "fit.admission" not in counts
+        np.testing.assert_array_equal(first.user_factors, second.user_factors)
+
+    def test_the_table_is_donated_through_the_export(self, monkeypatch):
+        """An export carries no donation: the AOT layer repeats it, or every
+        bucket's dispatch would copy the whole target table."""
+        import jax.numpy as jnp
+
+        est, m = _degraded(monkeypatch, 10)
+        est.fit(m)
+        (key, compiled), *_ = est._chunked_executables(m).items()
+        n_source, n_target, (b, l) = key
+        target = jnp.ones((n_target, 8), jnp.float32)
+        compiled(
+            jnp.ones((n_source, 8), jnp.float32), jnp.eye(8, dtype=jnp.float32), target,
+            jnp.full((b,), -1, jnp.int32), jnp.zeros((b, l), jnp.int32),
+            jnp.zeros((b, l), jnp.float32), jnp.zeros((b, l), bool),
+            jnp.float32(0.5), jnp.float32(40.0),
+        )
+        assert target.is_deleted()
+
+
+def test_chunked_fit_against_the_plain_reference(monkeypatch):
+    """The system's chunked fit, chosen by admission, against the benchmark's
+    plain reference (``benchmark/reference/als_cg.py``: its own init, blocks
+    and float32 CG at highest precision, nothing of the program) after two
+    sweeps from the same seed. Tolerance 2e-4 of the larger of a row's norm
+    and the median row's: on the CPU both sides are exact float32 and differ
+    in summation order alone (padded widths 8/16/32... against the program's
+    tiers; the long rows' CG on the explicit Gramian against the
+    matrix-free form), which three CG steps on systems conditioned like
+    1 + 40 n amplify to ~1e-5; a dropped bucket, a stale warm start or a
+    wrong landing reads 1e-1 or more."""
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "benchmark" / "reference" / "als_cg.py"
+    spec = importlib.util.spec_from_file_location("plain_als_cg", path)
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+
+    est, m = _degraded(monkeypatch, 11, max_iter=2, seed=13)
+    model = est.fit(m)
+    assert est.last_fit_report["mode"] == "chunked"
+    stars = {"rows": m.rows, "cols": m.cols, "vals": m.vals,
+             "n_users": m.n_users, "n_items": m.n_items}
+    config = {"rank": est.rank, "reg_param": est.reg_param, "alpha": est.alpha,
+              "cg_steps": est.cg_steps}
+    want_user, want_item = reference.fit(stars, config, 13, 2)
+    for got, want in ((model.user_factors, want_user), (model.item_factors, want_item)):
+        norms = np.linalg.norm(want, axis=1)
+        err = np.linalg.norm(got - want, axis=1) / np.maximum(norms, np.median(norms))
+        assert err.max() < 2e-4, err.max()
+    init = reference.init_factors(13, m.n_users, m.n_items, est.rank)
+    assert np.abs(model.user_factors - np.asarray(init[0])).max() > 0.05   # it moved

@@ -197,3 +197,89 @@ def test_matrix_cache_released_with_matrix():
     del m
     gc.collect()
     assert key not in _LAYOUT_CACHES
+
+
+def test_a_corrupt_executable_still_fails_when_the_probe_runs_on_the_device(monkeypatch):
+    """PR 29: leaves too large for the host are made and digested on the
+    device. With the threshold lowered so that this fit's slabs and tables
+    count as large, the drill above still ends the same way: a recorded
+    digest the executable does not reproduce discards the export; the fresh
+    compile is trusted again."""
+    import json as _json
+
+    from albedo_tpu.utils import aot, events
+
+    monkeypatch.setattr(aot, "_PROBE_HOST_ELEMS", 32)
+    m = synthetic_stars(n_users=90, n_items=60, mean_stars=6, seed=29)
+    als = ImplicitALS(rank=4, max_iter=3, seed=7, solver="cg")
+    first = als.fit(m)
+    (sidecar,) = aot.export_dir().glob("als_init_fit_fused-*.jaxexport.fp")
+    recorded = _json.loads(sidecar.read_text())
+    assert recorded["v"] == aot._FP_VERSION
+
+    reset_memory_cache()
+    again = ImplicitALS(rank=4, max_iter=3, seed=7, solver="cg")
+    again.fit(m)
+    assert again.last_fit_report["compile_source"] == "disk"   # the digest is reproducible
+
+    before = events.aot_fingerprint_mismatches.total()
+    sidecar.write_text(_json.dumps({"sha256": "0" * 64, "v": aot._FP_VERSION}))
+    reset_memory_cache()
+    als2 = ImplicitALS(rank=4, max_iter=3, seed=7, solver="cg")
+    second = als2.fit(m)
+    assert als2.last_fit_report["compile_source"] == "compile"
+    assert events.aot_fingerprint_mismatches.total() == before + 1
+    np.testing.assert_array_equal(first.user_factors, second.user_factors)
+    assert _json.loads(sidecar.read_text())["sha256"] == recorded["sha256"]
+
+
+def test_a_sidecar_of_another_probe_version_recompiles_once_and_counts_no_mismatch():
+    import json as _json
+
+    from albedo_tpu.utils import aot, events
+
+    m = synthetic_stars(n_users=90, n_items=60, mean_stars=6, seed=31)
+    ImplicitALS(rank=4, max_iter=2, seed=7, solver="cg").fit(m)
+    (sidecar,) = aot.export_dir().glob("als_init_fit_fused-*.jaxexport.fp")
+    good = _json.loads(sidecar.read_text())
+    sidecar.write_text(_json.dumps({"sha256": good["sha256"], "v": aot._FP_VERSION - 1}))
+    before = events.aot_fingerprint_mismatches.total()
+    reset_memory_cache()
+    als = ImplicitALS(rank=4, max_iter=2, seed=7, solver="cg")
+    als.fit(m)
+    assert als.last_fit_report["compile_source"] == "compile"
+    assert events.aot_fingerprint_mismatches.total() == before
+    assert aot.branch_log()[-1]["branch"].startswith("disk-fingerprint-version")
+    assert _json.loads(sidecar.read_text()) == good
+    reset_memory_cache()
+    als.fit(synthetic_stars(n_users=90, n_items=60, mean_stars=6, seed=31))
+    assert als.last_fit_report["compile_source"] == "disk"
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int32", "bool", "bfloat16"])
+def test_device_made_probe_leaves_and_their_digest(monkeypatch, dtype):
+    """A large leaf made on the device holds the host pattern (whole numbers
+    exactly, floats to an ulp), and its digest moves with any one element."""
+    import jax
+    import jax.numpy as jnp
+
+    from albedo_tpu.utils import aot
+
+    spec = jax.ShapeDtypeStruct((3, 5, 70), jnp.dtype(dtype))
+    on_host = aot._probe_leaf(spec)
+    monkeypatch.setattr(aot, "_PROBE_HOST_ELEMS", 8)
+    on_device = aot._probe_leaf(spec)
+    assert isinstance(on_device, jax.Array) and on_device.dtype == jnp.dtype(dtype)
+    np.testing.assert_allclose(np.asarray(on_device, np.float64), np.asarray(on_host, np.float64),
+                               rtol=1e-2 if dtype == "bfloat16" else 1e-6)
+    digest = aot._device_digest_fn(spec.shape, jnp.dtype(dtype).name)
+    base = np.asarray(digest(on_device))
+    assert base.shape == (2,) and (np.asarray(digest(on_device)) == base).all()
+    flipped = np.asarray(on_device).copy()
+    flipped[2, 4, 69] = not flipped[2, 4, 69] if dtype == "bool" else flipped[2, 4, 69] + 1
+    moved = np.asarray(digest(jnp.asarray(flipped)))
+    assert (moved != base).all()
+    swapped = np.asarray(on_device).copy()
+    swapped[0, 0, [1, 2]] = swapped[0, 0, [2, 1]]       # the same values elsewhere
+    if dtype != "bool":
+        assert (np.asarray(digest(jnp.asarray(swapped))) != base).all()
