@@ -33,7 +33,12 @@ from albedo_tpu.datasets.ragged import (
     grouped_bucket_rows,
 )
 from albedo_tpu.datasets.star_matrix import StarMatrix
-from albedo_tpu.ops.als import als_fit_fused, als_init_fit_fused, cg_gram_entry_share
+from albedo_tpu.ops.als import (
+    als_fit_fused,
+    als_init_fit_fused,
+    cg_gram_entry_share,
+    gather_reformed_entry_share,
+)
 from albedo_tpu.ops.topk import topk_scores
 from albedo_tpu.utils import capacity as capacity_mod
 from albedo_tpu.utils import faults
@@ -607,7 +612,8 @@ class ImplicitALS:
         ``prep_cached`` (whether the layout cache was warm) and
         ``cg_gram_entry_share`` (the share of padded entries in buckets whose
         CG ran on the explicit Gramian, ``ops.als.cg_uses_gramian``; 0 under
-        Cholesky). ``spans`` is the
+        Cholesky) and ``gather_reformed_entry_share`` (the share in buckets
+        gathered at a grown slot count, ``ops.als.gather_slots``). ``spans`` is the
         same call as a per-fit ``Timer`` snapshot (``{"totals", "counts"}``;
         each also an ``albedo.<name>`` host span in a profiler trace):
         ``fit`` > ``fit.admission``, ``fit.prep`` (children: see
@@ -796,6 +802,9 @@ class ImplicitALS:
             "capacity": None if admission is None else admission.to_dict(),
             "capacity_cross_check": cross,
             "cg_gram_entry_share": self._cg_gram_entry_share(g[1].shape for g in (*ug, *ig)),
+            "gather_reformed_entry_share": gather_reformed_entry_share(
+                g[1].shape for g in (*ug, *ig)
+            ),
         }
 
         return ALSModel(user_factors=user_f, item_factors=item_f, rank=self.rank)
@@ -986,6 +995,9 @@ class ImplicitALS:
             "cg_gram_entry_share": self._cg_gram_entry_share(
                 b.shape for b in (*user_buckets, *item_buckets)
             ),
+            "gather_reformed_entry_share": gather_reformed_entry_share(
+                b.shape for b in (*user_buckets, *item_buckets)
+            ),
         }
         return ALSModel(user_factors=user_f, item_factors=item_f, rank=self.rank)
 
@@ -1068,6 +1080,15 @@ class ImplicitALS:
             "sharded_shapes": stats["n_shapes"],
             "cg_gram_entry_share": self._cg_gram_entry_share(
                 b.shape for b in (*user_buckets, *item_buckets)
+            ),
+            # Each device gathers its own slots of a bucket (padded to a
+            # multiple of the shards); the ring mode gathers phase by phase
+            # from a table shard, not through ``ops.als._gather``.
+            "gather_reformed_entry_share": 0.0 if self.shard_mode == "ring" else (
+                gather_reformed_entry_share(
+                    (-(-b.shape[0] // engine.n_shards), b.shape[1])
+                    for b in (*user_buckets, *item_buckets)
+                )
             ),
             # Pipelined-dataflow accounting: upload_s accumulates inside the
             # background prefetch thread when pipelined+streamed, so it is

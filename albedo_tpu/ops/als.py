@@ -23,15 +23,18 @@ dense gather blocks over sharded factor tables), and the warm-started-CG fast
 path follows the iALS speedup literature (arXiv:2110.14044; the ``implicit``
 package's CG solver).
 
-Why XLA HLO and not a hand-written Pallas kernel: the op mix here is exactly
-what XLA fuses well — a row gather feeding a batched contraction with static
-shapes. A Pallas version would have to issue one small DMA per gathered row
-(arbitrary-index row gathers don't tile; ~k*4 bytes per transfer, latency-
-bound), and the k=50 factor width sits far off the 128-lane VMEM tile, so a
-custom kernel loses to the compiler's gather+einsum fusion. Pallas pays off
-when fusion FAILS (e.g. data-dependent inner structure); everything in this
-sweep is fusion-friendly by construction — that is what the tier-packed
-fixed-shape bucket layout is for.
+Why XLA HLO and not a hand-written Pallas kernel: the sweep is a row gather
+feeding batched contractions with static shapes, and on a v5e the gather is
+bound by ROWS, not bytes (PERF.md section 5): a factor row is 512 B in the
+(8, 128) tiling at rank 50 and rank 128 alike, and XLA's gather takes 1.6-2.4
+ns a row from a table it can keep in VMEM (under ~115 MB), 4.3-5.8 ns a row
+from a table in HBM when it stages 256 rows a step, and 10.5-11.9 ns when it
+stages 128. A Pallas gather issues one DMA descriptor a row and has the same
+bound, so the way to a faster gather is to be handed the compiler's fast form.
+Which form it takes follows from the flat row count ``B * L`` alone - XLA
+flattens any index array to one vector, so its blocking changes nothing
+(measured: within 3.4%) and the order of its entries next to nothing - and
+``gather_slots`` picks the slot count that gets the 256-row form.
 
 Phases carry ``jax.named_scope`` names (HLO metadata only: the compiled code
 does not move) so a profiler trace splits the one fused program by what the
@@ -81,9 +84,70 @@ def warm_start(target: jax.Array, row_ids: jax.Array) -> jax.Array:
         return target[jnp.where(row_ids < 0, 0, row_ids)]
 
 
+# XLA's TPU row gather flattens its index array to one (B * L,) vector, tiles
+# that by GATHER_INDEX_TILE entries, and sizes its staging step by the padding
+# the tiling leaves: 256 rows a step when the count lies GATHER_MIN_PAD or more
+# short of a tile boundary, 128 rows otherwise. From a table in HBM the
+# 128-row form takes 10.5-11.9 ns a row and the 256-row form 4.3-5.8; from a
+# table in VMEM 2.1-2.8 against 1.6-2.4 (one v5e, ranks 50 and 128, every
+# bucket shape and index order tried: PERF.md section 5). The planner's slot
+# tiers (powers of two, 1024-multiples) and length tiers (multiples of 8) make
+# B * L a multiple of 1024 for most buckets, so most gathers took the slow form.
+GATHER_INDEX_TILE = 1024
+GATHER_MIN_PAD = 128
+# A bucket grows by at most one slot row in GATHER_MAX_GROWTH: the fast form
+# gains 2.2x on the gather, and an empty slot row costs its share of gather
+# and solve alike.
+GATHER_MAX_GROWTH = 8
+
+
+def gather_slots(n_slots: int, length: int) -> int:
+    """The slot count at which a ``(n_slots, length)`` bucket is gathered and
+    solved: the least count from ``n_slots`` up whose flat row count gets the
+    gather's 256-row form, or ``n_slots`` itself where none within an eighth
+    more does (a ``length`` that is a multiple of 1024; buckets of a few
+    slots). Static shapes only: the kernel and the fit report's counter share
+    this choice."""
+    # (the flat count modulo the tile repeats within GATHER_INDEX_TILE slots)
+    most = n_slots + min(n_slots // GATHER_MAX_GROWTH, GATHER_INDEX_TILE)
+    for slots in range(n_slots, most + 1):
+        if -(slots * length) % GATHER_INDEX_TILE >= GATHER_MIN_PAD:
+            return slots
+    return n_slots
+
+
+def gather_reformed_entry_share(shapes) -> float:
+    """Share of a fit's padded entries, over the bucket shapes ``(..., B, L)``
+    of both sides, in buckets that ``_gather`` grows to another slot count."""
+    total = reformed = 0
+    for shape in shapes:
+        entries = math.prod(shape)
+        total += entries
+        reformed += entries * (gather_slots(*shape[-2:]) != shape[-2])
+    return reformed / total if total else 0.0
+
+
+def _with_slots(a: jax.Array, n_slots: int) -> jax.Array:
+    """``a`` with empty slot rows (zeros: no entry, no weight, a zero
+    iterate) appended up to ``n_slots``."""
+    if a.shape[0] == n_slots:
+        return a
+    return jnp.pad(a, ((0, n_slots - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
+
+
 def _gather(source: jax.Array, idx: jax.Array, gather_dtype) -> jax.Array:
     """Row-gather the fixed side's factors, optionally through a reduced-
-    precision copy of the table.
+    precision copy of the table, at the slot count XLA's gather runs fastest
+    on.
+
+    The block returned is ``(gather_slots(B, L), L, k)``: its first ``B`` slot
+    rows are ``source[idx]``, and the few beyond them are empty slots like the
+    ones every bucket's slot tier already carries (index 0, to be given no
+    weight). Growing the bucket is the one handle there is - a reshape of
+    ``idx`` reaches the same flat gather - and it is all but free: the index
+    padding fuses into the gather's own index clamp, the callers' padding of
+    ``val``/``mask`` into the elementwise passes that read them, and the block
+    is written once, where it is read (no slice of it, no copy).
 
     With ``gather_dtype="bfloat16"`` the (tiny) factor table is cast once and
     the (huge) gathered ``(B, L, k)`` blocks live in bf16 in HBM — halving the
@@ -91,6 +155,7 @@ def _gather(source: jax.Array, idx: jax.Array, gather_dtype) -> jax.Array:
     gathered blocks accumulate in float32 (``preferred_element_type``), the
     MXU's native bf16-in/f32-out mode."""
     with jax.named_scope("als.gather"):
+        idx = _with_slots(idx, gather_slots(*idx.shape))
         if gather_dtype is None:
             return source[idx]
         return source.astype(jnp.dtype(gather_dtype))[idx]
@@ -119,13 +184,15 @@ def bucket_solve_body(
     """The normal-equation solve for a padded bucket: gather → fused Gramian
     correction → batched Cholesky. Shared by the single-device and shard_map'd
     paths (``parallel.als``), so a parity fix lands in both."""
-    gathered = _gather(source, idx, gather_dtype)  # (B, L, k)
-    c1 = alpha * val                            # (B, L); 0 on padding
+    n_slots = idx.shape[0]
+    gathered = _gather(source, idx, gather_dtype)  # (B', L, k), B' >= B
+    val, mask = (_with_slots(a, gathered.shape[0]) for a in (val, mask))
+    c1 = alpha * val                            # (B', L); 0 on padding
     w = jnp.where(mask, 1.0 + c1, 0.0)          # b-vector weights
 
     corr, b_vec = bucket_partial_terms(gathered, c1, w)
     n_b = mask.sum(axis=1).astype(jnp.float32)
-    return solve_corrected(yty, corr, b_vec, n_b, reg)
+    return solve_corrected(yty, corr, b_vec, n_b, reg)[:n_slots]
 
 
 def bucket_partial_terms(
@@ -236,9 +303,11 @@ def bucket_cg_body(
     MLlib's exact per-block Cholesky (what ``bucket_solve_body`` mirrors)
     remains the parity reference.
     """
-    gathered = _gather(source, idx, gather_dtype)  # (B, L, k)
+    n_slots = idx.shape[0]
+    gathered = _gather(source, idx, gather_dtype)  # (B', L, k), B' >= B
     with jax.named_scope("als.cg"):
-        return _cg_solve(gathered, yty, val, mask, x0, reg, alpha, cg_steps)
+        val, mask, x0 = (_with_slots(a, gathered.shape[0]) for a in (val, mask, x0))
+        return _cg_solve(gathered, yty, val, mask, x0, reg, alpha, cg_steps)[:n_slots]
 
 
 def _cg_solve(gathered, yty, val, mask, x0, reg, alpha, cg_steps):

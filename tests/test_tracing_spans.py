@@ -233,6 +233,37 @@ def test_compiled_chunked_update_carries_the_scatter_scope_beside_the_shared_one
     assert not any(re.search(r"/als\.chunk\.scatter/.*als\.(gather|cg)", n) for n in op_names)
 
 
+@pytest.mark.parametrize("program", ["fused", "chunked"])
+def test_grown_gather_keeps_its_scope_and_stays_outside_the_solve(program):
+    """A bucket gathered at a grown slot count (``ops.als.gather_slots``):
+    the index padding belongs to ``als.gather``, the padding of what the solve
+    reads and the cut back to the bucket's own slots to ``als.cg``."""
+    from albedo_tpu.ops.als import chunked_bucket_update, gather_slots
+
+    if program == "fused":
+        m = stars(seed=43)
+        ug, ig, _, _ = ImplicitALS(rank=RANK, solver="cg").device_groups(m)
+        assert any(gather_slots(*g[1].shape[-2:]) != g[1].shape[-2] for g in (*ug, *ig))
+        text, _ = fused_fit_text("cg-long")      # whole rows, as device_groups above
+    else:
+        sds = jax.ShapeDtypeStruct
+        assert gather_slots(128, 8) == 129
+        args = (sds((30, RANK), jnp.float32), sds((RANK, RANK), jnp.float32), sds((200, RANK), jnp.float32),
+                sds((128,), jnp.int32), sds((128, 8), jnp.int32), sds((128, 8), jnp.float32),
+                sds((128, 8), jnp.bool_), sds((), jnp.float32), sds((), jnp.float32))
+        compiled, _, _ = persistent_aot_executable(
+            chunked_bucket_update, args, None, dict(solver="cg", cg_steps=3, gather_dtype=None),
+            key_parts=("test_tracing_spans", "chunked-grown"), name="als_chunked", donate_argnums=(2,),
+        )
+        text = compiled.as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any(re.search(r"/als\.gather/.*pad", n) for n in op_names)       # idx grows under the gather
+    assert any(re.search(r"/als\.gather/gather", n) for n in op_names)
+    assert any(re.search(r"/als\.cg/.*pad", n) for n in op_names)           # val, mask, x0 under the solve
+    assert not any(re.search(r"/als\.(cg|cholesky)/.*als\.gather", n) for n in op_names)
+    assert not any(re.search(r"/als\.gather/.*als\.cg", n) for n in op_names)
+
+
 def test_scopes_do_not_change_what_the_fit_computes():
     """Scopes are metadata: the half-sweep under them equals the same
     arithmetic written without them, on the CPU's exact f32."""
