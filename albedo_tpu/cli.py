@@ -114,7 +114,7 @@ def build_parser() -> argparse.ArgumentParser:
         "tables with device-resident buckets, streamed = additionally "
         "stream interaction buckets from the host per half-sweep (the "
         "PIPELINED dataflow — double-buffered prefetch, overlapped ring "
-        "phases, fused landing; ALBEDO_PIPELINE=off reverts every stage), "
+        "phases, fused landing), "
         "streamed_sync = pin the synchronous single-slab streamed dataflow "
         "(the cheapest admission rung and the A/B triage path). With "
         "--checkpoint-every the fit runs the ELASTIC driver "

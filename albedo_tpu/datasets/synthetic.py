@@ -150,12 +150,12 @@ class ScaleDataset:
     def iter_buckets(
         self,
         side: str,
-        readahead: bool | None = None,
+        readahead: bool = True,
         coalesce: bool = False,
     ):
         """Yield the stored padded buckets for one half-sweep, file by file.
 
-        With ``readahead`` (default: the ``ALBEDO_PIPELINE`` switch) the
+        With ``readahead`` (the default) the
         NEXT file is read and parsed on a background thread while the
         current file's buckets are consumed — the disk I/O side of the
         pipelined sharded dataflow, feeding the device-side bucket
@@ -170,13 +170,8 @@ class ScaleDataset:
         chunked generation fragments every tier once per chunk file, so an
         n-chunk dataset otherwise dispatches ~n buckets where one would
         do. Raw (False) is the stored layout — what the meta shapes
-        describe; :meth:`provider` turns coalescing on for fits under the
-        pipeline switch.
+        describe; :meth:`provider` turns coalescing on for fits.
         """
-        if readahead is None:
-            from albedo_tpu.utils.dataflow import pipeline_enabled
-
-            readahead = pipeline_enabled()
         if coalesce:
             from albedo_tpu.datasets.ragged import coalesce_buckets
 
@@ -208,18 +203,14 @@ class ScaleDataset:
     def provider(
         self,
         side: str,
-        readahead: bool | None = None,
-        coalesce: bool | None = None,
+        readahead: bool = True,
+        coalesce: bool = True,
     ):
         """A re-callable bucket provider for ``ShardedALSFit.fit`` — each
-        half-sweep re-streams the side's buckets from disk. Defaults follow
-        the ``ALBEDO_PIPELINE`` switch: file readahead on a background
+        half-sweep re-streams the side's buckets from disk. By default with
+        file readahead on a background
         thread AND per-tier bucket coalescing (see :meth:`iter_buckets`) —
         the host half of the pipelined sharded dataflow."""
-        if coalesce is None:
-            from albedo_tpu.utils.dataflow import pipeline_enabled
-
-            coalesce = pipeline_enabled()
         return lambda: self.iter_buckets(
             side, readahead=readahead, coalesce=coalesce
         )

@@ -37,7 +37,11 @@ from albedo_tpu.ops.als import (
     als_fit_fused,
     als_init_fit_fused,
     cg_gram_entry_share,
+    check_solver,
+    chunked_bucket_update,
     gather_reformed_entry_share,
+    gramian,
+    seeded_factors,
 )
 from albedo_tpu.ops.topk import topk_scores
 from albedo_tpu.utils import capacity as capacity_mod
@@ -274,7 +278,7 @@ class ImplicitALS:
     # "resident"/True force row-sharded tables with resident buckets;
     # "streamed" additionally streams interaction buckets from the host per
     # half-sweep (the star matrix is never device-resident whole) through
-    # the PIPELINED dataflow (ALBEDO_PIPELINE governs); "streamed_sync"
+    # the PIPELINED dataflow; "streamed_sync"
     # pins the synchronous streamed dataflow — the cheaper admission rung
     # and the A/B triage path. Checkpointed mesh fits run the ELASTIC
     # driver (parallel/elastic.py): mesh-portable sweep-boundary
@@ -490,11 +494,6 @@ class ImplicitALS:
             matrix.n_users, matrix.n_items, groups_sig,
         )
 
-    def _cg_gram_entry_share(self, shapes) -> float:
-        """``last_fit_report["cg_gram_entry_share"]`` for a fit over bucket
-        ``shapes``: what ``ops.als.cg_uses_gramian`` chose, 0 under Cholesky."""
-        return cg_gram_entry_share(shapes, self.rank) if self.solver == "cg" else 0.0
-
     # ---------------------------------------------------- capacity admission
 
     def _plan_shapes(self, matrix: StarMatrix) -> tuple[list, list]:
@@ -552,11 +551,8 @@ class ImplicitALS:
         (``verdict.chosen``). When even the synchronous streamed rung busts
         the budget, raises :class:`~albedo_tpu.utils.capacity.
         CapacityExceeded` — that matrix needs more chips, not more spilling.
-        With ``ALBEDO_PIPELINE=off`` the streamed rung prices (and runs)
-        the single-slab synchronous dataflow directly.
         """
         from albedo_tpu.parallel.mesh import DATA_AXIS
-        from albedo_tpu.utils.dataflow import pipeline_enabled
 
         n_dev = int(self.mesh.shape[DATA_AXIS])
         shapes_u, shapes_i = self._plan_shapes(matrix)
@@ -565,21 +561,18 @@ class ImplicitALS:
             gather_dtype=self.gather_dtype, mode=self.shard_mode,
             solver=self.solver,
         )
-        pipelined = pipeline_enabled()
-        plans = [
+        verdict = capacity_mod.admit_ladder([
             capacity_mod.plan_fit(
                 *args, gather_dtype=self.gather_dtype, n_devices=n_dev
             ),
             capacity_mod.plan_fit_sharded(*args, n_dev, streamed=False, **shard_kw),
             capacity_mod.plan_fit_sharded(
-                *args, n_dev, streamed=True, pipelined=pipelined, **shard_kw
+                *args, n_dev, streamed=True, pipelined=True, **shard_kw
             ),
-        ]
-        if pipelined:
-            plans.append(capacity_mod.plan_fit_sharded(
+            capacity_mod.plan_fit_sharded(
                 *args, n_dev, streamed=True, pipelined=False, **shard_kw
-            ))
-        verdict = capacity_mod.admit_ladder(plans)
+            ),
+        ])
         if verdict.verdict == "refuse":
             raise capacity_mod.CapacityExceeded(verdict)
         return verdict
@@ -592,19 +585,20 @@ class ImplicitALS:
         ``callback(iteration, user_factors, item_factors)`` if given is invoked
         after each full sweep (host arrays; for monitoring/tests).
 
-        Memory-budget admission runs first (single-device paths, cold layout
-        cache): a ``degrade`` verdict reroutes to the chunked host-streamed
-        fallback (:meth:`_fit_chunked`) instead of dispatching a resident
-        upload that would ``RESOURCE_EXHAUSTED``. ``self.chunked`` forces
-        either path; a warm groups cache implies the resident slabs already
-        fit (they are on device now), and a ``degrade`` verdict is kept with
-        the matrix's layout, so later fits of it take the chunked path under
-        that verdict without pricing the matrix again.
+        Memory-budget admission runs first (:meth:`_choose_path`; cold layout
+        cache only): a ``degrade`` verdict reroutes to the chunked
+        host-streamed fallback (:meth:`_fit_chunked`) instead of dispatching a
+        resident upload that would ``RESOURCE_EXHAUSTED``. ``self.chunked``
+        forces either path; a warm groups cache implies the resident slabs
+        already fit (they are on device now), and a ``degrade`` verdict is
+        kept with the matrix's layout, so later fits of it take the chunked
+        path under that verdict without pricing the matrix again.
 
         The returned model's factors are device arrays, fully computed on
         return (``block_until_ready``) — host copies materialize lazily via
         the ``ALSModel`` properties. ``self.last_fit_report`` records the
-        wall-clock split: ``prep_s`` (bucket layout + one-time device upload;
+        wall-clock split (:meth:`_finish` builds it for every path):
+        ``prep_s`` (bucket layout + one-time device upload;
         ~0 when the per-matrix cache is warm) with its ``bucket_s``/
         ``upload_s`` parts, ``compile_s`` (AOT executable acquisition — 0 on
         an in-memory hit; ``compile_source`` says memory/disk/compile),
@@ -622,60 +616,122 @@ class ImplicitALS:
         key and the compiled call until it returns) and ``fit.wait`` (the
         health read that is the completion barrier).
         """
+        # Before admission, bucketing and the upload are paid for a fit that
+        # no kernel can run.
+        check_solver(self.solver)
         timer = Timer()
         with timer.section("fit"):
-            model = self._fit(matrix, callback, timer)
-        self.last_fit_report["spans"] = timer.snapshot()
-        return model
-
-    def _fit(self, matrix: StarMatrix, callback: Any | None, timer: Timer) -> ALSModel:
-        t0 = time.perf_counter()
-        cache_warm = self._groups_cache_key() in _matrix_cache(matrix)
-        admission = None
-        use_chunked = self.chunked
-        if use_chunked is None:
-            use_chunked = False
-            if self.mesh is None and not cache_warm and capacity_mod.enabled():
-                # A degrade verdict stays with the matrix's layout, as a warm
-                # groups cache stands for a resident one: the next fit of this
-                # layout does not price the same matrix again (two bincounts
-                # over every entry).
-                verdict_key = ("degrade_verdict", self.rank, self.gather_dtype,
-                               *self._groups_cache_key())
-                admission = _matrix_cache(matrix).get(verdict_key)
-                if admission is None:
-                    with timer.section("fit.admission"):
-                        admission = self.admission(matrix)
-                    if admission.verdict == "degrade":
-                        _matrix_cache(matrix)[verdict_key] = admission
-                use_chunked = admission.verdict == "degrade"
-        if use_chunked:
-            return self._fit_chunked(matrix, callback, admission, t0, timer)
-        if self.mesh is not None:
-            # The mesh path is no longer capacity-exempt: the admission
-            # LADDER picks replicated-resident -> sharded -> sharded +
-            # streamed (or raises), unless self.sharded forces a mode.
-            sharded = self.sharded
-            if sharded is None:
-                sharded = False
-                if not cache_warm and capacity_mod.enabled():
-                    with timer.section("fit.admission"):
-                        admission = self.admission_mesh(matrix)
-                    sharded = {
-                        "als_fit": False,
-                        "als_fit_sharded": "resident",
-                        "als_fit_sharded_streamed": "streamed",
-                        "als_fit_sharded_streamed_sync": "streamed_sync",
-                    }[admission.chosen]
-            if sharded:
-                return self._fit_sharded(
-                    matrix, callback, admission, t0, timer,
-                    streamed=(sharded in ("streamed", "streamed_sync")),
-                    # "streamed_sync" is the admission ladder's single-slab
-                    # rung (or forced triage): the synchronous dataflow.
-                    # Everything else defers to the ALBEDO_PIPELINE switch.
-                    pipelined=False if sharded == "streamed_sync" else None,
+            t0 = time.perf_counter()
+            path, admission = self._choose_path(matrix, timer)
+            if path == "chunked":
+                run = self._fit_chunked(matrix, callback, timer)
+            elif path == "resident":
+                run = self._fit_resident(matrix, callback, timer, admission)
+            else:
+                run = self._fit_sharded(
+                    matrix, callback, timer,
+                    streamed=path != "sharded",
+                    pipelined=path != "sharded_streamed_sync",
                 )
+            self.last_fit_report = self._finish(run, path, admission, t0, timer)
+        self.last_fit_report["spans"] = timer.snapshot()
+        return ALSModel(user_factors=run.user_f, item_factors=run.item_f, rank=self.rank)
+
+    def _choose_path(self, matrix: StarMatrix, timer: Timer) -> tuple[str, Any]:
+        """Which fit runs, and the admission verdict that said so (``None``
+        where a field forced the path or nothing was priced): ``"chunked"``
+        (:meth:`_fit_chunked`), ``"resident"`` (:meth:`_fit_resident`, on one
+        device or replicated over the mesh), or ``"sharded"`` /
+        ``"sharded_streamed"`` / ``"sharded_streamed_sync"``
+        (:meth:`_fit_sharded`: resident buckets, host-streamed buckets under
+        the pipelined dataflow, the same under the synchronous one). From the
+        estimator's fields (``chunked``, ``mesh``, ``sharded``) and from what
+        admission observes, nothing else."""
+        if self.chunked:
+            return "chunked", None
+        cache, groups_key = _matrix_cache(matrix), self._groups_cache_key()
+        # A warm groups cache stands for a resident layout that fits: its
+        # slabs are on the device now.
+        priced = groups_key not in cache and capacity_mod.enabled()
+        if self.mesh is None:
+            if self.chunked is False or not priced:
+                return "resident", None
+            # A degrade verdict stays with the matrix's layout, as a warm
+            # groups cache stands for a resident one: the next fit of this
+            # layout does not price the same matrix again (two bincounts
+            # over every entry).
+            verdict_key = ("degrade_verdict", self.rank, self.gather_dtype, *groups_key)
+            admission = cache.get(verdict_key)
+            if admission is None:
+                with timer.section("fit.admission"):
+                    admission = self.admission(matrix)
+                if admission.verdict == "degrade":
+                    cache[verdict_key] = admission
+            return ("chunked" if admission.verdict == "degrade" else "resident"), admission
+        # The mesh path is not capacity-exempt: the admission LADDER picks
+        # replicated-resident -> sharded -> sharded + streamed -> the same
+        # with one slab in flight (or raises), unless self.sharded forces one.
+        if self.sharded is None:
+            if not priced:
+                return "resident", None
+            with timer.section("fit.admission"):
+                admission = self.admission_mesh(matrix)
+            return {
+                "als_fit": "resident",
+                "als_fit_sharded": "sharded",
+                "als_fit_sharded_streamed": "sharded_streamed",
+                "als_fit_sharded_streamed_sync": "sharded_streamed_sync",
+            }[admission.chosen], admission
+        if not self.sharded:
+            return "resident", None
+        if self.sharded in ("streamed", "streamed_sync"):
+            return f"sharded_{self.sharded}", None
+        return "sharded", None
+
+    def _finish(self, run: "_PathRun", path: str, admission, t0: float, timer: Timer) -> dict:
+        """The completion barrier and ``last_fit_report`` (less ``spans``) of
+        every path: the keys the benchmark's readers take from whichever path
+        ran, then the keys that are the path's own (``run.own``)."""
+        # Completion barrier: one ~12-byte device->host read of the
+        # divergence watchdog's on-device health vector (nonfinite count /
+        # max-abs / RMS over BOTH factor tables, utils.watchdog). It depends
+        # on every factor element, so it orders after the whole fit exactly
+        # as block_until_ready does (timed equal on a v5e, CHANGES.md PR 21)
+        # AND surfaces per-fit solve sanity with zero added host syncs on
+        # the happy path.
+        from albedo_tpu.utils.watchdog import factor_health, health_dict
+
+        with timer.section("fit.wait"):
+            health = health_dict(factor_health(run.user_f, run.item_f))
+        t2 = time.perf_counter()
+        prep_s = round(run.t1 - t0, 4)
+        return {
+            "prep_s": prep_s,
+            "bucket_s": prep_s if run.bucket_s is None else run.bucket_s,
+            "upload_s": run.upload_s,
+            "compile_s": round(run.compile_s, 4),
+            "compile_source": run.compile_source,
+            "device_s": round(t2 - run.t1 - run.compile_s, 4),
+            "prep_cached": run.prep_cached,
+            "health": health,
+            # the synchronous dataflow is the streamed mode's, told apart by
+            # the report's own ``pipelined``
+            "mode": "sharded_streamed" if path == "sharded_streamed_sync" else path,
+            "capacity": None if admission is None else admission.to_dict(),
+            "cg_gram_entry_share": (
+                cg_gram_entry_share(run.shapes, self.rank) if self.solver == "cg" else 0.0
+            ),
+            "gather_reformed_entry_share": gather_reformed_entry_share(run.shapes),
+            **run.own,
+        }
+
+    def _fit_resident(
+        self, matrix: StarMatrix, callback: Any | None, timer: Timer, admission
+    ) -> "_PathRun":
+        """The resident fit: every bucket group uploaded once
+        (:meth:`device_groups`), the whole fit one device program — on one
+        device, or with ``self.mesh`` under XLA's SPMD partitioner."""
+        prep_cached = self._groups_cache_key() in _matrix_cache(matrix)
         with timer.section("fit.prep"):
             ug, ig, u_land, i_land = self.device_groups(matrix, timer)
         prep_split = dict(getattr(self, "last_prep_timings", {}))
@@ -689,9 +745,10 @@ class ImplicitALS:
                     name=name, timer=timer, span="fit.acquire",
                 )
 
-        compile_s = 0.0
-        compile_source = None
-        compiled_handle = None  # for the capacity cross-check, when held
+        statics = dict(solver=self.solver, cg_steps=self.cg_steps,
+                       gather_dtype=self.gather_dtype)
+        landings = dict(user_landing=u_land, item_landing=i_land)
+        cross = None
         with timer.section("fit.dispatch"):
             reg = jnp.float32(self.reg_param)
             alpha = jnp.float32(self.alpha)
@@ -703,111 +760,72 @@ class ImplicitALS:
             with timer.section("fit.dispatch"):
                 fused_args = (jax.random.PRNGKey(self.seed), ug, ig, reg, alpha,
                               jnp.int32(self.max_iter))
-                fused_kwargs = dict(user_landing=u_land, item_landing=i_land)
-            compiled_handle, compile_s, compile_source = acquire(
-                als_init_fit_fused,
-                fused_args,
-                fused_kwargs,
-                dict(
-                    n_users=matrix.n_users, n_items=matrix.n_items,
-                    rank=self.rank, solver=self.solver, cg_steps=self.cg_steps,
-                    gather_dtype=self.gather_dtype,
-                ),
+            compiled, compile_s, compile_source = acquire(
+                als_init_fit_fused, fused_args, landings,
+                dict(n_users=matrix.n_users, n_items=matrix.n_items,
+                     rank=self.rank, **statics),
                 "als_init_fit_fused",
             )
             with timer.section("fit.dispatch"):
-                user_f, item_f = compiled_handle(*fused_args, **fused_kwargs)
+                user_f, item_f = compiled(*fused_args, **landings)
+            # Cross-check the static cost model against the compiler's own
+            # memory analysis where a verdict and this program's handle are
+            # both held — advisory (logged loudly on a >2x underestimate), so
+            # a stale model surfaces before it mis-admits a real workload.
+            if admission is not None:
+                cross = capacity_mod.cross_check(admission.plan, compiled)
         else:
-            if self.init_factors is not None:
-                user_f = jnp.asarray(self.init_factors[0], jnp.float32)
-                item_f = jnp.asarray(self.init_factors[1], jnp.float32)
-            else:
-                key = jax.random.PRNGKey(self.seed)
-                ukey, ikey = jax.random.split(key)
-                scale = 1.0 / np.sqrt(self.rank)
-                user_f = jax.random.normal(ukey, (matrix.n_users, self.rank), jnp.float32) * scale
-                item_f = jax.random.normal(ikey, (matrix.n_items, self.rank), jnp.float32) * scale
+            user_f, item_f = self._initial_factors(matrix)
             if self.mesh is not None:
                 from albedo_tpu.parallel.mesh import replicated
 
                 user_f = jax.device_put(user_f, replicated(self.mesh))
                 item_f = jax.device_put(item_f, replicated(self.mesh))
-            statics = dict(solver=self.solver, cg_steps=self.cg_steps,
-                           gather_dtype=self.gather_dtype)
-            step_kwargs = dict(user_landing=u_land, item_landing=i_land)
-            if callback is None:
-                fit_args = (user_f, item_f, ug, ig, reg, alpha,
-                            jnp.int32(self.max_iter))
-                compiled_fit, compile_s, compile_source = acquire(
-                    als_fit_fused, fit_args, step_kwargs, statics, "als_fit_fused"
-                )
+            # Without a callback one dispatch runs every sweep; with one, one
+            # dispatch a sweep (same program: n_iter is traced), surfacing
+            # factors to the host in between. The per-sweep executable is
+            # acquired through the AOT layer under a name of its own: the
+            # checkpointed chunks this serves are exactly what kill-resume
+            # drills re-run in a fresh process, so their cross-process
+            # executable reuse must be output-fingerprint verified too (a
+            # plain jit call here rode the persistent XLA cache unguarded —
+            # the source of the PR 3 drift).
+            n_iter = jnp.int32(self.max_iter if callback is None else 1)
+            compiled, compile_s, compile_source = acquire(
+                als_fit_fused, (user_f, item_f, ug, ig, reg, alpha, n_iter),
+                landings, statics,
+                "als_fit_fused" if callback is None else "als_fit_step",
+            )
+            for it in range(1 if callback is None else self.max_iter):
                 with timer.section("fit.dispatch"):
-                    user_f, item_f = compiled_fit(*fit_args, **step_kwargs)
-            else:
-                # One fused dispatch per iteration (same executable: n_iter
-                # is traced), surfacing factors to the host for the callback.
-                # Acquired through the AOT layer like the single-dispatch
-                # path: the checkpointed chunks this serves are exactly what
-                # kill-resume drills re-run in a fresh process, so their
-                # cross-process executable reuse must be output-fingerprint
-                # verified too (a plain jit call here rode the persistent
-                # XLA cache unguarded — the source of the PR 3 drift).
-                one = jnp.int32(1)
-                compiled_step, compile_s, compile_source = acquire(
-                    als_fit_fused,
-                    (user_f, item_f, ug, ig, reg, alpha, one),
-                    step_kwargs, statics, "als_fit_step",
-                )
-                for it in range(self.max_iter):
-                    with timer.section("fit.dispatch"):
-                        user_f, item_f = compiled_step(
-                            user_f, item_f, ug, ig, reg, alpha, one, **step_kwargs
-                        )
+                    user_f, item_f = compiled(
+                        user_f, item_f, ug, ig, reg, alpha, n_iter, **landings
+                    )
+                if callback is not None:
                     # The checkpoint callback's contract IS a host copy per
                     # chunk boundary (utils/checkpoint materializes exactly
                     # these) — an intentional, paid-for sync, not a hidden one.
                     # albedo: noqa[hidden-host-sync]
                     callback(it, np.asarray(user_f), np.asarray(item_f))
-        # Completion barrier: one ~12-byte device->host read of the
-        # divergence watchdog's on-device health vector (nonfinite count /
-        # max-abs / RMS over BOTH factor tables, utils.watchdog). It depends
-        # on every factor element, so it orders after the whole fit exactly
-        # as block_until_ready does (timed equal on a v5e, CHANGES.md PR 21)
-        # AND surfaces per-fit solve sanity with zero added host syncs on
-        # the happy path.
-        from albedo_tpu.utils.watchdog import factor_health, health_dict
-
-        with timer.section("fit.wait"):
-            health = health_dict(factor_health(user_f, item_f))
-        t2 = time.perf_counter()
-        # Cross-check the static cost model against the compiler's own
-        # memory analysis when the executable handle is held — advisory
-        # (logged loudly on a >2x underestimate), so a stale model surfaces
-        # before it mis-admits a real workload.
-        cross = (
-            capacity_mod.cross_check(admission.plan, compiled_handle)
-            if admission is not None and compiled_handle is not None
-            else None
+        return _PathRun(
+            user_f, item_f, t1, [g[1].shape for g in (*ug, *ig)],
+            compile_s, compile_source,
+            own={"capacity_cross_check": cross},
+            bucket_s=prep_split.get("bucket_s", 0.0),
+            upload_s=prep_split.get("upload_s", 0.0),
+            prep_cached=prep_cached,
         )
-        self.last_fit_report = {
-            "prep_s": round(t1 - t0, 4),
-            "bucket_s": prep_split.get("bucket_s", 0.0),
-            "upload_s": prep_split.get("upload_s", 0.0),
-            "compile_s": round(compile_s, 4),
-            "compile_source": compile_source,
-            "device_s": round(t2 - t1 - compile_s, 4),
-            "prep_cached": bool(cache_warm),
-            "health": health,
-            "mode": "resident",
-            "capacity": None if admission is None else admission.to_dict(),
-            "capacity_cross_check": cross,
-            "cg_gram_entry_share": self._cg_gram_entry_share(g[1].shape for g in (*ug, *ig)),
-            "gather_reformed_entry_share": gather_reformed_entry_share(
-                g[1].shape for g in (*ug, *ig)
-            ),
-        }
 
-        return ALSModel(user_factors=user_f, item_factors=item_f, rank=self.rank)
+    def _initial_factors(self, matrix: StarMatrix, as_array=jnp.asarray) -> tuple:
+        """The tables a fit outside the fused program starts from: the warm
+        start (``init_factors``, through ``as_array``: the sharded fit keeps
+        it on the host until its shards are placed) or the seeded draw,
+        computed eagerly."""
+        if self.init_factors is not None:
+            return tuple(as_array(f, jnp.float32) for f in self.init_factors[:2])
+        return seeded_factors(
+            jax.random.PRNGKey(self.seed), matrix.n_users, matrix.n_items, self.rank
+        )
 
     def _chunked_executables(self, matrix: StarMatrix) -> dict:
         """The chunked path's per-shape executables, kept with the matrix's
@@ -822,19 +840,14 @@ class ImplicitALS:
         return _matrix_cache(matrix).setdefault(key, {})
 
     def _fit_chunked(
-        self,
-        matrix: StarMatrix,
-        callback: Any | None,
-        admission,
-        t0: float,
-        timer: Timer,
-    ) -> ALSModel:
+        self, matrix: StarMatrix, callback: Any | None, timer: Timer
+    ) -> "_PathRun":
         """The degraded-capacity fit: host-streamed bucket groups.
 
         Only the factor tables stay device-resident; every half-sweep
         re-uploads each bucket's slab and solves it with the SAME kernels as
         the fused path (``ops.als.chunked_bucket_update`` wraps
-        ``bucket_solve_body``/``bucket_cg_body``), so the result is
+        ``ops.als.solve_rows``), so the result is
         numerics-parity with the resident path (pinned by
         ``tests/test_als_chunked.py``) — slower, never dead. Measured on one
         v5e at 10M x 1M x 100M stars, rank 128 (``gh10m-r128.fit-streamed``,
@@ -866,10 +879,6 @@ class ImplicitALS:
         of the device: a span is the host's time in the call, and what the
         device still owes is in ``fit.wait``.
         """
-        from albedo_tpu.ops.als import chunked_bucket_update, gramian
-
-        if self.solver not in ("cholesky", "cg"):
-            raise ValueError(f"unknown solver {self.solver!r}")
         with timer.section("fit.prep"):
             user_buckets, item_buckets = self._host_buckets(matrix)
         t1 = time.perf_counter()
@@ -926,17 +935,7 @@ class ImplicitALS:
         timer.add("fit.acquire", compile_s)
 
         with timer.section("fit.init"):
-            if self.init_factors is not None:
-                user_f = jnp.asarray(self.init_factors[0], jnp.float32)
-                item_f = jnp.asarray(self.init_factors[1], jnp.float32)
-            else:
-                # Eager seeded init: same traced PRNG ops + key as the fused
-                # init, so the values are identical (see als_init_fit_fused).
-                key = jax.random.PRNGKey(self.seed)
-                ukey, ikey = jax.random.split(key)
-                scale = 1.0 / np.sqrt(self.rank)
-                user_f = jax.random.normal(ukey, (matrix.n_users, self.rank), jnp.float32) * scale
-                item_f = jax.random.normal(ikey, (matrix.n_items, self.rank), jnp.float32) * scale
+            user_f, item_f = self._initial_factors(matrix)
             reg = jnp.float32(self.reg_param)
             alpha = jnp.float32(self.alpha)
 
@@ -967,60 +966,37 @@ class ImplicitALS:
                 # albedo: noqa[hidden-host-sync]
                 callback(it, np.asarray(user_f), np.asarray(item_f))
 
-        from albedo_tpu.utils.watchdog import factor_health, health_dict
-
-        with timer.section("fit.wait"):
-            health = health_dict(factor_health(user_f, item_f))
-        t2 = time.perf_counter()
         n_buckets = {"user": len(user_buckets), "item": len(item_buckets)}
-        self.last_fit_report = {
-            "prep_s": round(t1 - t0, 4),
-            "bucket_s": round(t1 - t0, 4),
+        return _PathRun(
+            user_f, item_f, t1, [b.shape for b in (*user_buckets, *item_buckets)],
+            compile_s, "+".join(sorted(compile_sources)) or None,
+            own={
+                "chunked_shapes": len(executables),
+                "dispatches": self.max_iter * sum(n_buckets.values()),
+                "buckets": n_buckets,
+                "streamed_bytes_per_sweep": sum(
+                    capacity_mod.bucket_slab_bytes(*b.shape)
+                    for b in (*user_buckets, *item_buckets)
+                ),
+            },
             # Host seconds in the per-bucket uploads (inside device_s).
-            "upload_s": round(timer.totals.get("fit.stream.upload", 0.0), 4),
-            "compile_s": round(compile_s, 4),
-            "compile_source": "+".join(sorted(compile_sources)) or None,
-            "device_s": round(t2 - t1 - compile_s, 4),
-            "prep_cached": False,
-            "health": health,
-            "mode": "chunked",
-            "capacity": None if admission is None else admission.to_dict(),
-            "chunked_shapes": len(executables),
-            "dispatches": self.max_iter * sum(n_buckets.values()),
-            "buckets": n_buckets,
-            "streamed_bytes_per_sweep": sum(
-                capacity_mod.bucket_slab_bytes(*b.shape)
-                for b in (*user_buckets, *item_buckets)
-            ),
-            "cg_gram_entry_share": self._cg_gram_entry_share(
-                b.shape for b in (*user_buckets, *item_buckets)
-            ),
-            "gather_reformed_entry_share": gather_reformed_entry_share(
-                b.shape for b in (*user_buckets, *item_buckets)
-            ),
-        }
-        return ALSModel(user_factors=user_f, item_factors=item_f, rank=self.rank)
+            upload_s=round(timer.totals.get("fit.stream.upload", 0.0), 4),
+        )
 
     def _fit_sharded(
-        self,
-        matrix: StarMatrix,
-        callback: Any | None,
-        admission,
-        t0: float,
-        timer: Timer,
-        streamed: bool,
-        pipelined: bool | None = None,
-    ) -> ALSModel:
+        self, matrix: StarMatrix, callback: Any | None, timer: Timer,
+        streamed: bool, pipelined: bool,
+    ) -> "_PathRun":
         """The ALX-layout fit: BOTH factor tables row-sharded over the
         mesh's data axis, per-device bucket blocks solved against
         all-gathered (or ring-passed) source shards inside shard_map, and —
         when ``streamed`` — interaction buckets uploaded per half-sweep so
         the star matrix is never device-resident whole. The dataflow is
-        PIPELINED by default (double-buffered bucket prefetch, overlapped
-        ring phases, fused landing scatter — ``ALBEDO_PIPELINE=off`` or
-        ``pipelined=False`` reverts to the synchronous PR 8 dataflow). Same
-        kernels as every other path (``ops.als.bucket_solve_body``/
-        ``bucket_cg_body`` via ``parallel.als.ShardedALSFit``), per-shape
+        PIPELINED (double-buffered bucket prefetch, overlapped
+        ring phases, fused landing scatter) unless ``pipelined`` is false,
+        which is the synchronous PR 8 dataflow. Same
+        kernels as every other path (``ops.als.solve_rows`` via
+        ``parallel.als.ShardedALSFit``), per-shape
         executables through the persistent AOT layer, and the watchdog
         health reduction as the completion barrier — parity with the
         single-device resident fit is test-pinned at atol 1e-5.
@@ -1036,73 +1012,60 @@ class ImplicitALS:
             user_buckets, item_buckets = self._host_buckets(matrix)
         t1 = time.perf_counter()
 
-        if self.init_factors is not None:
-            user_f = np.asarray(self.init_factors[0], np.float32)
-            item_f = np.asarray(self.init_factors[1], np.float32)
-        else:
-            # Eager seeded init: same traced PRNG ops + key as the fused
-            # init, so the values are identical (see als_init_fit_fused).
-            key = jax.random.PRNGKey(self.seed)
-            ukey, ikey = jax.random.split(key)
-            scale = 1.0 / np.sqrt(self.rank)
-            user_f = jax.random.normal(ukey, (matrix.n_users, self.rank), jnp.float32) * scale
-            item_f = jax.random.normal(ikey, (matrix.n_items, self.rank), jnp.float32) * scale
-
+        user_f, item_f = self._initial_factors(matrix, np.asarray)
         user_f, item_f, stats = engine.fit(
             user_f, item_f, user_buckets, item_buckets,
             self.reg_param, self.alpha, self.max_iter,
             streamed=streamed, callback=callback, pipelined=pipelined,
         )
-
-        from albedo_tpu.utils.watchdog import factor_health, health_dict
-
-        # The d2h health read doubles as the completion barrier, exactly as
-        # on the resident path.
-        with timer.section("fit.wait"):
-            health = health_dict(factor_health(user_f, item_f))
-        t2 = time.perf_counter()
-        compile_s = stats["compile_s"]
-        timer.add("fit.acquire", compile_s)
-        self.last_fit_report = {
-            "prep_s": round(t1 - t0, 4),
-            "bucket_s": round(t1 - t0, 4),
-            "upload_s": stats["upload_s"],
-            "compile_s": round(compile_s, 4),
-            "compile_source": "+".join(sorted(stats["compile_sources"])) or None,
-            "device_s": round(t2 - t1 - compile_s, 4),
-            "prep_cached": False,
-            "health": health,
-            "mode": "sharded_streamed" if streamed else "sharded",
-            "shard_mode": self.shard_mode,
-            "n_shards": engine.n_shards,
-            "capacity": None if admission is None else admission.to_dict(),
-            "streamed_buckets": stats["streamed_buckets"],
-            "sharded_shapes": stats["n_shapes"],
-            "cg_gram_entry_share": self._cg_gram_entry_share(
-                b.shape for b in (*user_buckets, *item_buckets)
-            ),
-            # Each device gathers its own slots of a bucket (padded to a
-            # multiple of the shards); the ring mode gathers phase by phase
-            # from a table shard, not through ``ops.als._gather``.
-            "gather_reformed_entry_share": 0.0 if self.shard_mode == "ring" else (
-                gather_reformed_entry_share(
-                    (-(-b.shape[0] // engine.n_shards), b.shape[1])
-                    for b in (*user_buckets, *item_buckets)
-                )
-            ),
-            # Pipelined-dataflow accounting: upload_s accumulates inside the
-            # background prefetch thread when pipelined+streamed, so it is
-            # OFF the critical path there; prefetch_wait_s is the time the
-            # sweep actually stalled waiting for a bucket — the visible
-            # (un-hidden) remainder of the upload cost.
-            "pipelined": stats["pipelined"],
-            "prefetch_wait_s": stats["prefetch_wait_s"],
-            # Elasticity cost surface: a bare sharded fit observed no mesh
-            # events; the elastic driver (parallel/elastic.py) overwrites
-            # this with its loss/resume/checkpoint record.
-            "mesh_events": {
-                "losses": 0, "resumes": 0, "degradations": 0,
-                "checkpoint_s": 0.0, "n_shards": engine.n_shards,
+        timer.add("fit.acquire", stats["compile_s"])
+        return _PathRun(
+            user_f, item_f, t1,
+            # Each device gathers and solves its own slots of a bucket
+            # (padded to a multiple of the shards); the ring mode gathers
+            # phase by phase from a table shard, not through
+            # ``ops.als._gather``, and is Cholesky only.
+            [] if self.shard_mode == "ring" else [
+                (-(-b.shape[0] // engine.n_shards), b.shape[1])
+                for b in (*user_buckets, *item_buckets)
+            ],
+            stats["compile_s"], "+".join(sorted(stats["compile_sources"])) or None,
+            own={
+                "shard_mode": self.shard_mode,
+                "n_shards": engine.n_shards,
+                "streamed_buckets": stats["streamed_buckets"],
+                "sharded_shapes": stats["n_shapes"],
+                # Pipelined-dataflow accounting: upload_s accumulates inside the
+                # background prefetch thread when pipelined+streamed, so it is
+                # OFF the critical path there; prefetch_wait_s is the time the
+                # sweep actually stalled waiting for a bucket — the visible
+                # (un-hidden) remainder of the upload cost.
+                "pipelined": stats["pipelined"],
+                "prefetch_wait_s": stats["prefetch_wait_s"],
+                # Elasticity cost surface: a bare sharded fit observed no mesh
+                # events; the elastic driver (parallel/elastic.py) overwrites
+                # this with its loss/resume/checkpoint record.
+                "mesh_events": {
+                    "losses": 0, "resumes": 0, "degradations": 0,
+                    "checkpoint_s": 0.0, "n_shards": engine.n_shards,
+                },
             },
-        }
-        return ALSModel(user_factors=user_f, item_factors=item_f, rank=self.rank)
+            upload_s=stats["upload_s"],
+        )
+
+
+@dataclasses.dataclass
+class _PathRun:
+    """What a fit path hands back for the barrier and the report
+    (``ImplicitALS._finish``)."""
+
+    user_f: Any
+    item_f: Any
+    t1: float                     # perf_counter at the end of the host prep
+    shapes: list                  # bucket shapes as the kernels were handed them
+    compile_s: float
+    compile_source: str | None
+    own: dict                     # the report keys only this path has
+    bucket_s: float | None = None  # None: all of prep_s (no upload in prep)
+    upload_s: float = 0.0
+    prep_cached: bool = False

@@ -391,6 +391,39 @@ def _pcg(matvec, diag, b_vec, x0, cg_steps: int):
     return x
 
 
+def check_solver(solver: str) -> None:
+    """Reject a solver name no kernel answers to. ``ImplicitALS.fit`` and
+    ``ShardedALSFit`` call it before any layout is built; ``solve_rows``
+    calls it again for whoever traces the programs below directly."""
+    if solver not in ("cholesky", "cg"):
+        raise ValueError(f"unknown solver {solver!r} (expected 'cholesky' or 'cg')")
+
+
+def solve_rows(
+    source, yty, target, row_ids, idx, val, mask, reg, alpha,
+    solver: str, cg_steps: int, gather_dtype,
+) -> jax.Array:
+    """One bucket's solved ``(B, k)`` block, by the kernel ``solver`` names:
+    ``"cholesky"`` the exact MLlib-parity solve (``bucket_solve_body``),
+    ``"cg"`` the CG warm-started from the bucket's current rows of ``target``
+    (``bucket_cg_body``). Arguments as ``chunked_bucket_update``'s;
+    ``target`` and ``row_ids`` are read under ``"cg"`` only. The one place a
+    kernel is chosen: the fused sweep, the chunked per-bucket program, the
+    eager reference and the mesh's assembled solve (``parallel.als``, which
+    hands in its all-gathered tables) all trace this, so a change to either
+    body reaches every path."""
+    check_solver(solver)
+    if solver == "cg":
+        x0 = warm_start(target, row_ids)
+        return bucket_cg_body(
+            source, yty, idx, val, mask, x0, reg, alpha, cg_steps,
+            gather_dtype=gather_dtype,
+        )
+    return bucket_solve_body(
+        source, yty, idx, val, mask, reg, alpha, gather_dtype=gather_dtype
+    )
+
+
 # Per-bucket eager reference path (als_half_sweep): parity tests and small
 # interactive runs only — hot fits go through als_fit_fused/als_init_fit_fused,
 # which ARE acquired via utils/aot.
@@ -409,7 +442,10 @@ def solve_bucket(
 ) -> jax.Array:
     """One normal-equation solve for a padded bucket of rows; returns updated
     ``target`` with solved rows scattered in."""
-    solved = bucket_solve_body(source, yty, idx, val, mask, reg, alpha)
+    solved = solve_rows(
+        source, yty, target, row_ids, idx, val, mask, reg, alpha,
+        solver="cholesky", cg_steps=0, gather_dtype=None,
+    )
     return scatter_solved(target, row_ids, solved)
 
 
@@ -435,21 +471,15 @@ def chunked_bucket_update(
     """One bucket's solve for the **chunked host-streamed** fallback path
     (``models.als`` under a ``degrade`` capacity verdict): the bucket slab
     arrives fresh from the host per call, only the factor tables stay
-    device-resident. Same kernels as the fused sweep (``bucket_solve_body``
-    / ``bucket_cg_body``) so the fallback is numerics-parity with the
-    resident path; each target row appears in exactly one bucket, so the
-    sequential scatters land exactly what the fused landing gather lands.
+    device-resident. Same kernels as the fused sweep (``solve_rows``) so the
+    fallback is numerics-parity with the resident path; each target row
+    appears in exactly one bucket, so the sequential scatters land exactly
+    what the fused landing gather lands.
     """
-    if solver == "cg":
-        x0 = warm_start(target, row_ids)
-        solved = bucket_cg_body(
-            source, yty, idx, val, mask, x0, reg, alpha, cg_steps,
-            gather_dtype=gather_dtype,
-        )
-    else:
-        solved = bucket_solve_body(
-            source, yty, idx, val, mask, reg, alpha, gather_dtype=gather_dtype,
-        )
+    solved = solve_rows(
+        source, yty, target, row_ids, idx, val, mask, reg, alpha,
+        solver, cg_steps, gather_dtype,
+    )
     with jax.named_scope("als.chunk.scatter"):
         return scatter_solved(target, row_ids, solved)
 
@@ -494,9 +524,7 @@ def scan_half_sweep(
     whole sweep lives inside a single XLA program with no per-bucket dispatch.
 
     Each row appears in exactly one bucket, so scan order within a half-sweep
-    is irrelevant. ``solver="cholesky"`` is the exact MLlib-parity solve
-    (``bucket_solve_body``, shared with the per-bucket and shard_map paths);
-    ``solver="cg"`` is the warm-started CG (``bucket_cg_body``).
+    is irrelevant. ``solver`` picks the kernel as everywhere (``solve_rows``).
 
     ``landing`` (``models.als`` precomputes it on host) is the inverse
     permutation that lands solved rows by a GATHER from
@@ -506,8 +534,6 @@ def scan_half_sweep(
     ``landing[r] = flat slot position of row r``, or ``n_slots + r`` to keep
     the old factor for rows in no bucket.
     """
-    if solver not in ("cholesky", "cg"):
-        raise ValueError(f"unknown solver {solver!r} (expected 'cholesky' or 'cg')")
     yty = gramian(source)
 
     # Every target row appears in exactly one bucket, so the solves never
@@ -517,18 +543,10 @@ def scan_half_sweep(
     # the (n_target, k) table out of the scan carry.
     def body(_, g):
         row_ids, idx, val, mask = g
-        if solver == "cg":
-            x0 = warm_start(target, row_ids)
-            solved = bucket_cg_body(
-                source, yty, idx, val, mask, x0, reg, alpha, cg_steps,
-                gather_dtype=gather_dtype,
-            )
-        else:
-            solved = bucket_solve_body(
-                source, yty, idx, val, mask, reg, alpha,
-                gather_dtype=gather_dtype,
-            )
-        return None, solved
+        return None, solve_rows(
+            source, yty, target, row_ids, idx, val, mask, reg, alpha,
+            solver, cg_steps, gather_dtype,
+        )
 
     k = target.shape[1]
     all_rows, all_solved = [], []
@@ -602,6 +620,26 @@ def als_fit_fused(
     )
 
 
+def seeded_factors(
+    key: jax.Array, n_users: int, n_items: int, rank: int
+) -> tuple[jax.Array, jax.Array]:
+    """The seeded ``(user, item)`` factor tables every fit starts from:
+    normal draws scaled by ``1/sqrt(rank)``. Traced inside the fused program
+    and called eagerly by the paths that keep their tables outside one
+    (callback, chunked, sharded): the same PRNG ops on the same key, so the
+    draws are the same bits and the eager paths' tables are each other's;
+    inside a program the compiler may fold the scale into the draw's own last
+    multiply, which moves a value by an ulp or two (``tests/test_als.py``).
+    That is what lets the paths be compared with one another, and with the
+    benchmark's reference, row by row."""
+    with jax.named_scope("als.init"):
+        ukey, ikey = jax.random.split(key)
+        scale = 1.0 / jnp.sqrt(jnp.float32(rank))
+        user_f = jax.random.normal(ukey, (n_users, rank), jnp.float32) * scale
+        item_f = jax.random.normal(ikey, (n_items, rank), jnp.float32) * scale
+    return user_f, item_f
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n_users", "n_items", "rank", "solver", "cg_steps", "gather_dtype"),
@@ -626,14 +664,10 @@ def als_init_fit_fused(
 
     Creating the init factors eagerly costs ~6 separate device dispatches
     (PRNGKey, split, 2x normal, 2x scale). Fusing the init into the fit
-    program makes the whole train ONE dispatch and the values identical
-    (same traced PRNG ops, same key).
+    program makes the whole train ONE dispatch, on the values
+    ``seeded_factors`` gives any other path.
     """
-    with jax.named_scope("als.init"):
-        ukey, ikey = jax.random.split(key)
-        scale = 1.0 / jnp.sqrt(jnp.float32(rank))
-        user_f = jax.random.normal(ukey, (n_users, rank), jnp.float32) * scale
-        item_f = jax.random.normal(ikey, (n_items, rank), jnp.float32) * scale
+    user_f, item_f = seeded_factors(key, n_users, n_items, rank)
     return _fit_loop(
         user_f, item_f, user_groups, item_groups, reg, alpha, n_iter,
         solver, cg_steps, user_landing, item_landing, gather_dtype,

@@ -33,7 +33,8 @@ replication makes the per-bucket arbitrary-index gather local.
    (ARCHITECTURE.md "Sharded ALS").
 
 The sharded dataflow is PIPELINED end to end by default (ARCHITECTURE.md
-"Pipelined sharded dataflow"; ``ALBEDO_PIPELINE=off`` reverts every stage):
+"Pipelined sharded dataflow"; ``ShardedALSFit.fit(pipelined=False)`` is the
+synchronous one):
 a background prefetcher (`_BucketPrefetcher`) uploads bucket i+1 while
 bucket i's solve is dispatched (double-buffered — the mesh never waits on a
 cold upload after the first bucket), ring phases issue phase p+1's
@@ -58,16 +59,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from albedo_tpu.datasets.ragged import Bucket, device_bucket
 from albedo_tpu.ops.als import (
-    bucket_cg_body,
     bucket_partial_terms,
-    bucket_solve_body,
+    check_solver,
     scatter_solved,
     solve_corrected,
-    warm_start,
+    solve_rows,
 )
 from albedo_tpu.parallel.mesh import DATA_AXIS, pad_rows_to, row_sharded
 from albedo_tpu.utils import faults
-from albedo_tpu.utils.dataflow import pipeline_enabled
 
 # Chaos hooks for the fully sharded fit: `als.shard.gather` fires once per
 # half-sweep ahead of the source-shard assembly (the all-gather / ring pass),
@@ -88,8 +87,8 @@ SHARD_COLLECTIVE_FAULT = faults.site("als.shard.collective")
 # fail, wedge (delay), or kill the prefetch thread specifically. An error
 # there is delivered to the consuming sweep and surfaces as a clean failed
 # fit; a wedge is bounded by the collective deadline (`PrefetchStalled`),
-# never a hang. The site never fires with ALBEDO_PIPELINE=off, on the
-# resident sharded path, or on the synchronous streamed path.
+# never a hang. The site never fires on the resident sharded path or on the
+# synchronous streamed path.
 SHARD_PREFETCH_FAULT = faults.site("als.shard.prefetch")
 
 
@@ -179,9 +178,12 @@ def make_sharded_solver(mesh: Mesh, axis: str = DATA_AXIS):
 
 def _local_bucket_solve(source, yty, row_ids, idx, val, mask, reg, alpha):
     """Per-device slice of a bucket solve; math shared with the single-device
-    path via ``ops.als.bucket_solve_body``."""
-    del row_ids  # only needed for the scatter, outside the shard
-    return bucket_solve_body(source, yty, idx, val, mask, reg, alpha)
+    path via ``ops.als.solve_rows`` (exact solve: no target rows are read;
+    ``row_ids`` serves the scatter, outside the shard)."""
+    return solve_rows(
+        source, yty, None, row_ids, idx, val, mask, reg, alpha,
+        solver="cholesky", cg_steps=0, gather_dtype=None,
+    )
 
 
 # --- fully sharded fit (ALX layout) -------------------------------------------
@@ -213,18 +215,15 @@ def _assembled_solve(
 ):
     """Per-device bucket solve against the all-gathered source table."""
     source = jax.lax.all_gather(source_l, axis, axis=0, tiled=True)
+    target = None
     if solver == "cg":
         # Warm starts read the PRE-SWEEP target rows, which live on whatever
         # shard owns them — assemble the target too (priced by the cost
         # model as the CG mode's extra transient).
         target = jax.lax.all_gather(target_l, axis, axis=0, tiled=True)
-        x0 = warm_start(target, row_ids_l)
-        return bucket_cg_body(
-            source, yty, idx_l, val_l, mask_l, x0, reg, alpha, cg_steps,
-            gather_dtype=gather_dtype,
-        )
-    return bucket_solve_body(
-        source, yty, idx_l, val_l, mask_l, reg, alpha, gather_dtype=gather_dtype
+    return solve_rows(
+        source, yty, target, row_ids_l, idx_l, val_l, mask_l, reg, alpha,
+        solver, cg_steps, gather_dtype,
     )
 
 
@@ -244,7 +243,7 @@ def _ring_solve(
     same dataflow graph, same math (the permute reads the same ``src`` the
     compute does), only the issue order changes so the async-collective
     scheduler can hide the hop latency. The synchronous order (compute,
-    then permute) is kept for ``ALBEDO_PIPELINE=off`` A/B."""
+    then permute) is the synchronous dataflow's (``pipelined=False``)."""
     rows_per = source_l.shape[0]
     k = source_l.shape[1]
     shard = jax.lax.axis_index(axis)
@@ -677,8 +676,7 @@ class ShardedALSFit:
         gather_dtype: str | None = None,
         mode: str = "allgather",
     ):
-        if solver not in ("cholesky", "cg"):
-            raise ValueError(f"unknown solver {solver!r}")
+        check_solver(solver)
         if mode not in ("allgather", "ring"):
             raise ValueError(f"unknown shard mode {mode!r}")
         if mode == "ring" and solver == "cg":
@@ -821,7 +819,7 @@ class ShardedALSFit:
         n_iter: int,
         streamed: bool = False,
         callback=None,
-        pipelined: bool | None = None,
+        pipelined: bool = True,
     ) -> tuple[jax.Array, jax.Array, dict]:
         """Run ``n_iter`` full sweeps; returns ``(user_f, item_f, stats)``
         with the factor tables trimmed back to their unpadded row counts.
@@ -832,15 +830,13 @@ class ShardedALSFit:
         spill files through such a provider without ever holding the whole
         side in memory.
 
-        ``pipelined`` (default: the ``ALBEDO_PIPELINE`` switch) runs the
+        ``pipelined`` (the default) runs the
         pipelined dataflow — double-buffered bucket prefetch when
         ``streamed``, overlapped ring phases, fused landing scatter —
         numerically identical to the synchronous path (parity-pinned at
         1e-5 in ``tests/test_sharded_als.py``); ``False`` is the
         synchronous A/B and triage path.
         """
-        if pipelined is None:
-            pipelined = pipeline_enabled()
         pipelined = bool(pipelined)
         n_users, n_items = int(user_f.shape[0]), int(item_f.shape[0])
         u_provider = user_buckets if callable(user_buckets) else (lambda: user_buckets)
@@ -897,7 +893,7 @@ class ShardedALSSweep:
     parity test). ``ImplicitALS.fit`` itself now runs the fused single-dispatch
     path with batch-axis-sharded bucket groups, letting XLA's SPMD partitioner
     insert the equivalent collectives (``models/als.py device_groups``); both
-    share the per-bucket math in ``ops.als.bucket_solve_body``.
+    share the per-bucket math in ``ops.als.solve_rows``.
     """
 
     def __init__(self, mesh: Mesh, axis: str = DATA_AXIS):

@@ -2483,9 +2483,6 @@ def scale_bench() -> dict:
     forced_virtual = "xla_force_host_platform_device_count" in os.environ.get(
         "XLA_FLAGS", ""
     )
-    from albedo_tpu.utils.dataflow import pipeline_enabled
-
-    pipeline_on = pipeline_enabled()
     record = {
         "metric": "sharded_als_weak_scaling",
         "unit": "per-sweep wall-clock s at max device count (weak scaling)",
@@ -2499,7 +2496,8 @@ def scale_bench() -> dict:
         "real devices: efficiency_vs_1chip is the weak-scaling figure",
         "weak_scaling": curve,
         "roofline_gbps_per_chip": roofline_gbps,
-        "pipeline": "on" if pipeline_on else "off",
+        # the dataflow the headline per_sweep_s ran, by the fit's own report
+        "pipeline": "on" if stats["pipelined"] else "off",
         "ring_overlap_probe": ring_probe,
         "largest_fittable": largest,
         "mode": mode,

@@ -1,6 +1,8 @@
 """Implicit ALS: kernel parity vs a dense numpy reference, objective descent,
 and structure recovery on planted synthetic data."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -303,3 +305,99 @@ def test_fit_layout_cache_and_report(small_matrix):
     als2.fit(m)
     assert als2.last_fit_report["prep_cached"] is True
     assert set(als2.last_fit_report) >= {"prep_s", "device_s", "prep_cached"}
+
+
+# --- one fit: what every path decides in one place ---------------------------
+# (`ImplicitALS._choose_path` / `_finish`, `ops.als.check_solver` /
+# `seeded_factors`.) The paths are forced here; admission's own choices are
+# covered where each path is (test_als_chunked.py, test_sharded_als.py).
+
+FIT_PATHS = {
+    "resident": dict(chunked=False),
+    "chunked": dict(chunked=True),
+    "sharded": dict(sharded="resident"),
+    "sharded_streamed": dict(sharded="streamed"),
+    "sharded_streamed_sync": dict(sharded="streamed_sync"),
+}
+
+
+def _estimator(path, **kw):
+    from albedo_tpu.parallel import make_mesh
+
+    forced = dict(FIT_PATHS[path])
+    if "sharded" in forced:
+        forced["mesh"] = make_mesh(8)
+    return ImplicitALS(rank=8, batch_size=32, seed=5, **forced, **kw)
+
+
+@pytest.mark.parametrize("path", ["resident", "chunked", "sharded"])
+def test_unknown_solver_is_refused_before_any_layout_is_built(path):
+    from albedo_tpu.models.als import _matrix_cache
+
+    m = synthetic_stars(n_users=40, n_items=30, mean_stars=5, seed=2)
+    est = _estimator(path, max_iter=1, solver="lu")
+    with pytest.raises(ValueError, match=r"unknown solver 'lu' \(expected 'cholesky' or 'cg'\)"):
+        est.fit(m)
+    # nothing was priced, bucketed, uploaded or compiled for it
+    assert _matrix_cache(m) == {}
+    assert not hasattr(est, "last_fit_report")
+
+
+SHARED_REPORT_KEYS = {
+    "prep_s": float, "bucket_s": float, "upload_s": float, "compile_s": float,
+    "compile_source": (str, type(None)), "device_s": float, "prep_cached": bool,
+    "health": dict, "mode": str, "capacity": (dict, type(None)),
+    "cg_gram_entry_share": float, "gather_reformed_entry_share": float,
+    "spans": dict,
+}
+OWN_REPORT_KEYS = {
+    "resident": {"capacity_cross_check"},
+    "chunked": {"chunked_shapes", "dispatches", "buckets", "streamed_bytes_per_sweep"},
+    "sharded": {"shard_mode", "n_shards", "streamed_buckets", "sharded_shapes",
+                "pipelined", "prefetch_wait_s", "mesh_events"},
+}
+
+
+@pytest.mark.parametrize("path", list(FIT_PATHS))
+def test_every_path_reports_the_shared_keys_and_spans(path, small_matrix):
+    """The contract the benchmark's readers rely on, whichever path ran."""
+    est = _estimator(path, max_iter=1, solver="cg")
+    est.fit(small_matrix)
+    rep = est.last_fit_report
+    for key, kind in SHARED_REPORT_KEYS.items():
+        assert isinstance(rep[key], kind), (key, rep[key])
+    assert set(rep) - set(SHARED_REPORT_KEYS) == OWN_REPORT_KEYS[path.split("_")[0]]
+    # the synchronous dataflow is the streamed mode's, told apart by `pipelined`
+    assert rep["mode"] == ("sharded_streamed" if path.endswith("_sync") else path)
+    if path.startswith("sharded"):
+        assert rep["pipelined"] is (path != "sharded_streamed_sync")
+    assert set(rep["health"]) == {"nonfinite", "max_abs", "rms"}
+    assert rep["compile_s"] >= 0 and 0 <= rep["cg_gram_entry_share"] <= 1
+    totals, counts = rep["spans"]["totals"], rep["spans"]["counts"]
+    assert counts["fit"] == counts["fit.wait"] == counts["fit.prep"] == 1
+    assert totals["fit"] >= totals["fit.wait"] > 0
+    assert totals["fit.acquire"] >= rep["compile_s"] - 1e-3
+
+
+@pytest.mark.parametrize("path", list(FIT_PATHS) + ["resident_callback"])
+def test_every_path_starts_from_the_same_seeded_tables(path):
+    """Every path's sweep loop runs ``max_iter`` times, so a fit of no sweeps
+    returns its first-sweep input: ``ops.als.seeded_factors``. The paths that
+    call it eagerly return its bits — also at a rank whose ``1/sqrt(rank)``
+    rounds differently in float64, where the copies this replaced (a
+    float64 scale, rounded) were one bit off the fused program's. The fused
+    program traces the same function and XLA folds the draw's own last
+    multiply into the scale's, so its tables may differ in the last two bits."""
+    from albedo_tpu.ops.als import seeded_factors
+
+    m = synthetic_stars(n_users=40, n_items=30, mean_stars=5, seed=2)
+    rank = 7
+    est = dataclasses.replace(
+        _estimator(path.removesuffix("_callback"), max_iter=0), rank=rank)
+    model = est.fit(m, callback=(lambda *a: None) if path.endswith("_callback") else None)
+    want = seeded_factors(jax.random.PRNGKey(est.seed), m.n_users, m.n_items, rank)
+    for got, table in zip((model.user_factors, model.item_factors), want):
+        if path == "resident":
+            np.testing.assert_array_max_ulp(got, np.asarray(table), maxulp=2)
+        else:
+            np.testing.assert_array_equal(got, np.asarray(table))

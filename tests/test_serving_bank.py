@@ -23,10 +23,18 @@ from albedo_tpu.recommenders import (  # noqa: E402
 )
 from albedo_tpu.retrieval import BankStage, RetrievalBank  # noqa: E402
 from albedo_tpu.serving import RecommendationService, serve  # noqa: E402
+from albedo_tpu.serving.overload import OverloadConfig  # noqa: E402
 from albedo_tpu.serving.pipeline import StageDeadlines, TwoStagePipeline  # noqa: E402
 from albedo_tpu.utils import events, faults  # noqa: E402
 
 K = 10
+# For the tests that assert an UNDEGRADED answer: stage budgets, a bank wait
+# and an overload SLO that no machine's load can reach. The defaults (2 s of
+# candidates, 0.5 s of ranker, a 1 s bank wait, a 0.25 s batch SLO) are real
+# deadlines, and a first request that compiles, on cores the rest of the
+# suite is loading, misses them and is answered degraded — rightly.
+PATIENT_S = 600.0
+PATIENT_DEADLINES = StageDeadlines(candidates_s=PATIENT_S, ranker_s=PATIENT_S)
 
 
 @pytest.fixture(scope="module")
@@ -43,7 +51,7 @@ def world():
     return tables, matrix, model, als, tfidf, pop
 
 
-def _stage(world):
+def _stage(world, timeout_s=1.0):
     tables, matrix, model, als, tfidf, _pop = world
     indptr, cols, _ = matrix.csr()
     excl = padded_rows(indptr, cols, np.arange(matrix.n_users))
@@ -52,14 +60,18 @@ def _stage(world):
     bank.register(tfidf.bank_registration())
     bank.build(matrix=matrix, exclude_table=excl)
     return BankStage(
-        bank, matrix, fallbacks={"als": als, "tfidf": tfidf}, top_k=K
+        bank, matrix, fallbacks={"als": als, "tfidf": tfidf}, top_k=K,
+        timeout_s=timeout_s,
     )
 
 
 def test_bank_serves_its_sources_threaded_sources_stay(world):
     _tables, matrix, _model, als, tfidf, pop = world
     pipe = TwoStagePipeline(
-        {"als": als, "tfidf": tfidf, "popularity": pop}, bank_stage=_stage(world)
+        {"als": als, "tfidf": tfidf, "popularity": pop},
+        bank_stage=_stage(world, timeout_s=PATIENT_S),
+        # this first request compiles the bank's fused query
+        deadlines=PATIENT_DEADLINES,
     )
     try:
         out = pipe.recommend(int(matrix.user_ids[0]), 30)
@@ -315,8 +327,9 @@ def test_warm_service_compiles_bank_and_ranker_before_the_first_request(world):
 
     service = RecommendationService(
         model, matrix, recommenders={"popularity": pop},
-        ranker=CountingRanker(), bank_stage=_stage(world), warm=True,
-        default_k=K,
+        ranker=CountingRanker(), bank_stage=_stage(world, timeout_s=PATIENT_S),
+        warm=True, default_k=K, deadlines=PATIENT_DEADLINES,
+        overload_config=OverloadConfig(slo_s=PATIENT_S, codel_target_s=PATIENT_S),
     )
     try:
         assert service.batcher.warmed

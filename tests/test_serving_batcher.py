@@ -12,6 +12,7 @@ jax = pytest.importorskip("jax")
 from albedo_tpu.datasets import synthetic_tables  # noqa: E402
 from albedo_tpu.models.als import ImplicitALS  # noqa: E402
 from albedo_tpu.serving import MicroBatcher, QueueOverflow, RecommendationService  # noqa: E402
+from albedo_tpu.serving.overload import OverloadConfig  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -27,8 +28,15 @@ def test_batched_parity_byte_identical(artifacts):
     single-request path for random concurrent request mixes (mixed users,
     ks, exclusion flags)."""
     tables, matrix, model = artifacts
+    # The batched engine is cold: its first batches compile inside the batch,
+    # and on a loaded machine a compile reads as a breach of the default
+    # 0.25 s batch SLO — three in a row and the brownout ladder sheds the
+    # very requests whose answers are compared. The overload layer stays on
+    # the path (its limit admits, its ladder is asked) with an SLO that no
+    # compile can breach; what it does under pressure is test_overload.py's.
+    calm = OverloadConfig(slo_s=600.0, codel_target_s=600.0)
     with RecommendationService(model, matrix, batching=False) as single, \
-         RecommendationService(model, matrix, batching=True) as batched:
+         RecommendationService(model, matrix, batching=True, overload_config=calm) as batched:
         rng = np.random.default_rng(0)
         mixes = [
             (int(rng.choice(matrix.user_ids)), int(rng.choice([3, 7, 30])),

@@ -153,7 +153,8 @@ class TestPipelinedDataflow:
         assert faults.FAULTS.hits("als.shard.prefetch") > 0
         _parity(model, reference)
 
-    def test_streamed_sync_mode_reachable_for_triage(self, mesh8, matrix, reference):
+    def test_streamed_sync_mode_reachable_for_triage(
+            self, mesh8, matrix, reference, monkeypatch):
         before = faults.FAULTS.hits("als.shard.prefetch")
         est = ImplicitALS(**KW, mesh=mesh8, sharded="streamed_sync")
         model = est.fit(matrix)
@@ -163,15 +164,13 @@ class TestPipelinedDataflow:
         # The synchronous path never touches the prefetch surface.
         assert faults.FAULTS.hits("als.shard.prefetch") == before
         _parity(model, reference)
-
-    def test_env_off_switch_reverts_to_sync(self, mesh8, matrix, reference, monkeypatch):
-        monkeypatch.setenv("ALBEDO_PIPELINE", "off")
-        before = faults.FAULTS.hits("als.shard.prefetch")
-        est = ImplicitALS(**KW, mesh=mesh8, sharded="streamed")
-        model = est.fit(matrix)
-        assert est.last_fit_report["pipelined"] is False
-        assert faults.FAULTS.hits("als.shard.prefetch") == before
-        _parity(model, reference)
+        # The ladder always prices this dataflow, as its last rung: a refusal
+        # names every rung it tried.
+        monkeypatch.setenv("ALBEDO_DEVICE_MEM_BYTES", "1k")
+        with pytest.raises(capacity.CapacityExceeded) as refused:
+            est.admission_mesh(matrix)
+        assert refused.value.verdict.detail.rstrip(")").split(", ")[-1].startswith(
+            "als_fit_sharded_streamed_sync=")
 
 
 class TestPrefetchFaultSite:

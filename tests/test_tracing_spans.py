@@ -3,7 +3,9 @@ trace span), the host spans of ``ImplicitALS.fit`` and the device scopes and
 module name of the fused ALS program."""
 
 import glob
+import itertools
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -94,7 +96,19 @@ def assert_children_within_parents(totals: dict[str, float]) -> None:
             assert seconds <= totals[parent] + MS, (name, seconds, totals[parent])
 
 
-def test_cold_fit_publishes_its_spans_beside_the_keys_they_refine():
+@pytest.fixture
+def counted_clock(monkeypatch):
+    """A clock the test controls: every read of ``time.perf_counter``, from
+    any thread, is 50 microseconds after the read before it. A span or a
+    report key is then the number of reads between its two ends, so how a key
+    relates to the span that refines it (each to ``MS``) says which reads it
+    is made of and not how long a loaded machine kept a thread waiting between
+    two of them."""
+    reads = itertools.count()
+    monkeypatch.setattr(time, "perf_counter", lambda: next(reads) * 50e-6)
+
+
+def test_cold_fit_publishes_its_spans_beside_the_keys_they_refine(counted_clock):
     als = ImplicitALS(rank=4, max_iter=2, seed=3, solver="cg")
     als.fit(stars())
     report = als.last_fit_report
