@@ -39,8 +39,11 @@ from albedo_tpu.ops.als import (
     cg_gram_entry_share,
     check_solver,
     chunked_bucket_update,
+    gather_packed_entry_share,
     gather_reformed_entry_share,
+    gather_table,
     gramian,
+    scanned_shape,
     seeded_factors,
 )
 from albedo_tpu.ops.topk import topk_scores
@@ -603,11 +606,13 @@ class ImplicitALS:
         ``upload_s`` parts, ``compile_s`` (AOT executable acquisition — 0 on
         an in-memory hit; ``compile_source`` says memory/disk/compile),
         ``device_s`` (the fused training dispatch, synchronized),
-        ``prep_cached`` (whether the layout cache was warm) and
+        ``prep_cached`` (whether the layout cache was warm),
         ``cg_gram_entry_share`` (the share of padded entries in buckets whose
         CG ran on the explicit Gramian, ``ops.als.cg_uses_gramian``; 0 under
-        Cholesky) and ``gather_reformed_entry_share`` (the share in buckets
-        gathered at a grown slot count, ``ops.als.gather_slots``). ``spans`` is the
+        Cholesky), ``gather_reformed_entry_share`` (the share in buckets
+        gathered at a grown slot count, ``ops.als.gather_slots``) and
+        ``gather_packed_entry_share`` (the share in buckets whose gather read
+        a line table, ``ops.als.gather_packs_rows``). ``spans`` is the
         same call as a per-fit ``Timer`` snapshot (``{"totals", "counts"}``;
         each also an ``albedo.<name>`` host span in a profiler trace):
         ``fit`` > ``fit.admission``, ``fit.prep`` (children: see
@@ -722,6 +727,7 @@ class ImplicitALS:
                 cg_gram_entry_share(run.shapes, self.rank) if self.solver == "cg" else 0.0
             ),
             "gather_reformed_entry_share": gather_reformed_entry_share(run.shapes),
+            "gather_packed_entry_share": gather_packed_entry_share(run.shapes, self.rank),
             **run.own,
         }
 
@@ -808,7 +814,7 @@ class ImplicitALS:
                     # albedo: noqa[hidden-host-sync]
                     callback(it, np.asarray(user_f), np.asarray(item_f))
         return _PathRun(
-            user_f, item_f, t1, [g[1].shape for g in (*ug, *ig)],
+            user_f, item_f, t1, [scanned_shape(g[1].shape, self.rank) for g in (*ug, *ig)],
             compile_s, compile_source,
             own={"capacity_cross_check": cross},
             bucket_s=prep_split.get("bucket_s", 0.0),
@@ -897,7 +903,9 @@ class ImplicitALS:
             f32, i32 = jnp.float32, jnp.int32
             sds = jax.ShapeDtypeStruct
             args = (
-                sds((n_source, self.rank), f32), sds((self.rank, self.rank), f32),
+                # the fixed side's table in the form the gather reads it
+                jax.eval_shape(gather_table, sds((n_source, self.rank), f32)),
+                sds((self.rank, self.rank), f32),
                 sds((n_target, self.rank), f32), sds(shape[:1], i32),
                 sds(shape, i32), sds(shape, f32), sds(shape, jnp.bool_),
                 sds((), f32), sds((), f32),
@@ -947,6 +955,7 @@ class ImplicitALS:
             with timer.section("fit.stream"):
                 with timer.section("fit.stream.gramian"):
                     yty = gramian(source)
+                    table = gather_table(source)
                 for b in buckets:
                     with timer.section("fit.stream.upload"):
                         slab = (jnp.asarray(b.row_ids), jnp.asarray(b.idx),
@@ -954,7 +963,7 @@ class ImplicitALS:
                     with timer.section("fit.stream.acquire"):
                         compiled = executables[source.shape[0], target.shape[0], b.shape]
                     with timer.section("fit.stream.dispatch"):
-                        target = compiled(source, yty, target, *slab, reg, alpha)
+                        target = compiled(table, yty, target, *slab, reg, alpha)
             return target
 
         for it in range(self.max_iter):

@@ -34,7 +34,11 @@ bound, so the way to a faster gather is to be handed the compiler's fast form.
 Which form it takes follows from the flat row count ``B * L`` alone - XLA
 flattens any index array to one vector, so its blocking changes nothing
 (measured: within 3.4%) and the order of its entries next to nothing - and
-``gather_slots`` picks the slot count that gets the 256-row form.
+``gather_slots`` picks the slot count that gets the 256-row form. Where it
+reads from follows from the table's padded size: up to rank 64 two factor rows
+share a 128-lane line (``gather_table``), the table is half as large, and the
+compiler keeps it in VMEM ahead of every gather that ``gather_pieces`` keeps
+small enough.
 
 Phases carry ``jax.named_scope`` names (HLO metadata only: the compiled code
 does not move) so a profiler trace splits the one fused program by what the
@@ -127,6 +131,98 @@ def gather_reformed_entry_share(shapes) -> float:
     return reformed / total if total else 0.0
 
 
+# A factor row takes a whole 128-lane line of the (8, 128) tiling whatever its
+# rank, so a table's padded size, and with it whether the compiler copies it
+# into VMEM ahead of a gather (PERF.md section 5), is set by its row count
+# alone. Where two rows fit a line they are handed to the gather as one, each
+# from the start of its half: a whole line gathers at 1.6-2.5 ns a row from
+# VMEM where a 100-lane one (rank 50's rows end to end) takes 3.1-3.9, and the
+# sweep 613 ms against 654.
+LANES = 128
+HALF = LANES // 2
+
+
+def gather_packs_rows(rank: int) -> bool:
+    """Whether a rank-``rank`` table is gathered from its line table
+    (``gather_table``). Static shapes only: the kernel and the fit report's
+    counter share this choice."""
+    return 2 * rank <= LANES
+
+
+def gather_table(source: jax.Array) -> jax.Array:
+    """The form in which ``_gather`` reads the ``(n, k)`` table ``source``:
+    the table itself where two rows do not fit a line, else its line table
+    ``(ceil(n / 2), LANES)`` - row ``2i`` in lanes ``0:k`` and row ``2i + 1``
+    in lanes ``HALF:HALF + k`` of line ``i``, zeros between, an odd row count
+    padded by one zero row - which is half the table's padded size. A relayout
+    of the whole table, so built once a half-sweep, beside ``gramian(source)``,
+    never once a bucket."""
+    n, k = source.shape
+    if not gather_packs_rows(k):
+        return source
+    with jax.named_scope("als.gather"):
+        return jnp.pad(source, ((0, n % 2), (0, HALF - k))).reshape(-1, LANES)
+
+
+def gather_packed_entry_share(shapes, rank: int) -> float:
+    """Share of a fit's padded entries, over the bucket shapes ``(..., B, L)``
+    of both sides, in buckets whose gather read a line table."""
+    return float(gather_packs_rows(rank) and any(math.prod(shape) for shape in shapes))
+
+
+# Inside the fused fit the compiler keeps a line table in VMEM ahead of a
+# gather only while the gather is small, and copies it back to HBM ahead of a
+# larger one. Compiled for a described v5e at albedo-r50's layout (76.8 and
+# 115.2 MB of lines), the table is in VMEM (``S(1)``) for 53% of the gathered
+# rows with every bucket gathered whole, 75% in pieces of at most 2^20 flat
+# rows, 98.7% at 786,432 and for every gather at 2^19; on the chip (100-lane
+# lines, a bucket's pieces as slices) the sweep took 851 ms whole, 695 at
+# 786,432 and 645 at 2^19, against the parent's 961 (PERF.md section 6).
+GATHER_VMEM_ROWS = 1 << 19
+
+
+def gather_pieces(n_slots: int, length: int, packed: bool) -> tuple[int, int]:
+    """``(pieces, slots a piece)`` in which the fused sweep scans a
+    ``(n_slots, length)`` bucket: whole where its gather reads the table as it
+    is (``packed`` false) or is small enough already, else in equal pieces of
+    at most ``GATHER_VMEM_ROWS`` flat rows (one slot row, where a row is
+    longer) - of the counts from the fewest that do to twice as many, the one
+    that leaves the fewest empty slot rows in the last piece. Static shapes
+    only: ``scan_half_sweep`` and the shapes a fit reports share this choice."""
+    fewest = -(-n_slots // max(1, GATHER_VMEM_ROWS // length)) if packed else 1
+    pieces = min(range(fewest, min(2 * fewest, n_slots) + 1), key=lambda n: (-n_slots % n, n))
+    return pieces, -(-n_slots // pieces)
+
+
+def scanned_shape(shape: tuple[int, int, int], rank: int) -> tuple[int, int, int]:
+    """The ``(buckets, B, L)`` that ``scan_half_sweep`` solves a rank-``rank``
+    fit's ``(N, B, L)`` group at: its buckets in their pieces."""
+    n, n_slots, length = shape
+    pieces, per = gather_pieces(n_slots, length, gather_packs_rows(rank))
+    return n * pieces, per, length
+
+
+def _fold(x: jax.Array, rank: int, axes=(-1,)) -> jax.Array:
+    """A contraction of a line-table block back at ``rank`` lanes: the sum of
+    its two halves' first ``rank`` lanes along ``axes`` (every entry lives in
+    one half and is zero in the other; what two axes hold across the halves is
+    dropped). The identity on what a plain block gave."""
+    if x.shape[-1] == rank:
+        return x
+    lower = (Ellipsis,) + (slice(None, rank),) * len(axes)
+    upper = (Ellipsis,) + (slice(HALF, HALF + rank),) * len(axes)
+    return x[lower] + x[upper]
+
+
+def _spread(p: jax.Array, width: int) -> jax.Array:
+    """``(B, k)`` vectors at a gathered block's ``width``: once in each half
+    of a line-table block's, so that an entry in either meets its vector."""
+    if p.shape[-1] == width:
+        return p
+    p = jnp.pad(p, ((0, 0), (0, HALF - p.shape[-1])))
+    return jnp.concatenate([p, p], axis=-1)
+
+
 def _with_slots(a: jax.Array, n_slots: int) -> jax.Array:
     """``a`` with empty slot rows (zeros: no entry, no weight, a zero
     iterate) appended up to ``n_slots``."""
@@ -135,15 +231,32 @@ def _with_slots(a: jax.Array, n_slots: int) -> jax.Array:
     return jnp.pad(a, ((0, n_slots - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
 
 
-def _gather(source: jax.Array, idx: jax.Array, gather_dtype) -> jax.Array:
+def _gather(
+    source: jax.Array, idx: jax.Array, gather_dtype, rank: int
+) -> tuple[jax.Array, jax.Array]:
     """Row-gather the fixed side's factors, optionally through a reduced-
     precision copy of the table, at the slot count XLA's gather runs fastest
-    on.
+    on. ``source`` is the ``(n, rank)`` table, or its line table
+    (``gather_table``), told apart by their width.
 
     The block returned is ``(gather_slots(B, L), L, k)``: its first ``B`` slot
     rows are ``source[idx]``, and the few beyond them are empty slots like the
     ones every bucket's slot tier already carries (index 0, to be given no
-    weight). Growing the bucket is the one handle there is - a reshape of
+    weight). From a line table it is ``(gather_slots(B, L), L, LANES)``: line
+    ``idx >> 1`` with the half that is another row's set to zero, so row
+    ``idx`` sits in lanes ``0:k`` (``idx`` even) or ``HALF:HALF + k`` (odd)
+    and every contraction over the block is ``_fold``-ed back to ``k``.
+    Choosing the half into a ``k``-wide block instead costs two passes over a
+    block that a lane tile pads to the same 512 B a row either way (a
+    ``where`` of two lane slices, 14.5-19.1 ms a bucket of 1.2-2.1M rows
+    against 8.2-11.3 folded, gather and contractions: PERF.md section 5); the
+    mask is an elementwise producer of whatever reads the block. Beside the
+    block come the lines as they were fetched (the block itself, from a plain
+    table): the second operand of a product whose first is already masked
+    needs no mask, and leaves the other row's terms where ``_fold`` drops
+    them (135 against 155 ms a sweep in ``als.cg.gram``).
+
+    Growing the bucket is the one handle on the gather's form - a reshape of
     ``idx`` reaches the same flat gather - and it is all but free: the index
     padding fuses into the gather's own index clamp, the callers' padding of
     ``val``/``mask`` into the elementwise passes that read them, and the block
@@ -156,9 +269,14 @@ def _gather(source: jax.Array, idx: jax.Array, gather_dtype) -> jax.Array:
     MXU's native bf16-in/f32-out mode."""
     with jax.named_scope("als.gather"):
         idx = _with_slots(idx, gather_slots(*idx.shape))
-        if gather_dtype is None:
-            return source[idx]
-        return source.astype(jnp.dtype(gather_dtype))[idx]
+        if gather_dtype is not None:
+            source = source.astype(jnp.dtype(gather_dtype))
+        if source.shape[1] == rank:
+            rows = source[idx]
+            return rows, rows
+        lines = source[idx >> 1]
+        upper = jnp.arange(LANES) >= HALF
+        return jnp.where(upper == (idx & 1).astype(bool)[..., None], lines, 0), lines
 
 
 def _gdot(spec: str, gathered: jax.Array, other: jax.Array) -> jax.Array:
@@ -172,8 +290,8 @@ def _gdot(spec: str, gathered: jax.Array, other: jax.Array) -> jax.Array:
 
 
 def bucket_solve_body(
-    source: jax.Array,   # (n_source, k) fixed side's factors
-    yty: jax.Array,      # (k, k) gramian of `source`
+    source: jax.Array,   # (n_source, k) fixed side's factors, or their gather_table
+    yty: jax.Array,      # (k, k) gramian of the factors
     idx: jax.Array,      # (B, L) int32 indices into `source`
     val: jax.Array,      # (B, L) float32 ratings, 0 on padding
     mask: jax.Array,     # (B, L) bool
@@ -184,13 +302,14 @@ def bucket_solve_body(
     """The normal-equation solve for a padded bucket: gather → fused Gramian
     correction → batched Cholesky. Shared by the single-device and shard_map'd
     paths (``parallel.als``), so a parity fix lands in both."""
-    n_slots = idx.shape[0]
-    gathered = _gather(source, idx, gather_dtype)  # (B', L, k), B' >= B
+    n_slots, k = idx.shape[0], yty.shape[0]
+    gathered, _ = _gather(source, idx, gather_dtype, k)  # (B', L, k or LANES), B' >= B
     val, mask = (_with_slots(a, gathered.shape[0]) for a in (val, mask))
     c1 = alpha * val                            # (B', L); 0 on padding
     w = jnp.where(mask, 1.0 + c1, 0.0)          # b-vector weights
 
     corr, b_vec = bucket_partial_terms(gathered, c1, w)
+    corr, b_vec = _fold(corr, k, axes=(-2, -1)), _fold(b_vec, k)
     n_b = mask.sum(axis=1).astype(jnp.float32)
     return solve_corrected(yty, corr, b_vec, n_b, reg)[:n_slots]
 
@@ -272,8 +391,8 @@ def cg_gram_entry_share(shapes, rank: int) -> float:
 
 
 def bucket_cg_body(
-    source: jax.Array,   # (n_source, k) fixed side's factors
-    yty: jax.Array,      # (k, k) gramian of `source`
+    source: jax.Array,   # (n_source, k) fixed side's factors, or their gather_table
+    yty: jax.Array,      # (k, k) gramian of the factors
     idx: jax.Array,      # (B, L) int32 indices into `source`
     val: jax.Array,      # (B, L) float32 ratings, 0 on padding
     mask: jax.Array,     # (B, L) bool
@@ -304,25 +423,29 @@ def bucket_cg_body(
     remains the parity reference.
     """
     n_slots = idx.shape[0]
-    gathered = _gather(source, idx, gather_dtype)  # (B', L, k), B' >= B
+    gathered, lines = _gather(source, idx, gather_dtype, yty.shape[0])  # (B', L, k or LANES), B' >= B
     with jax.named_scope("als.cg"):
         val, mask, x0 = (_with_slots(a, gathered.shape[0]) for a in (val, mask, x0))
-        return _cg_solve(gathered, yty, val, mask, x0, reg, alpha, cg_steps)[:n_slots]
+        return _cg_solve(gathered, lines, yty, val, mask, x0, reg, alpha, cg_steps)[:n_slots]
 
 
-def _cg_solve(gathered, yty, val, mask, x0, reg, alpha, cg_steps):
-    """``bucket_cg_body`` after its gather, under the ``als.cg`` scope."""
+def _cg_solve(gathered, lines, yty, val, mask, x0, reg, alpha, cg_steps):
+    """``bucket_cg_body`` after its gather, under the ``als.cg`` scope. A
+    line-table block (``_gather``) is contracted at its own ``LANES`` lanes,
+    which the tiling pads a ``k``-wide one to anyway, and each result folded
+    back to ``k`` where it is ``(B, LANES)`` or ``(B, LANES, LANES)`` small."""
+    k, width = yty.shape[0], gathered.shape[-1]
     with jax.named_scope("als.cg.rhs"):
         c1 = alpha * val                            # (B, L); 0 on padding
         w = jnp.where(mask, 1.0 + c1, 0.0)
         n_b = mask.sum(axis=1).astype(jnp.float32)
         # f32 weights for the b-vector under bf16 gathers — see
         # bucket_partial_terms.
-        b_vec = jnp.einsum(
+        b_vec = _fold(jnp.einsum(
             "blk,bl->bk", gathered, w, preferred_element_type=jnp.float32
-        )
+        ), k)
 
-    if cg_uses_gramian(*gathered.shape[1:]):
+    if cg_uses_gramian(gathered.shape[1], k):
         with jax.named_scope("als.cg.gram"):
             # A = YtY + sum_l c1 y y^T + reg n I. The scaling is an
             # elementwise producer of the contraction's operand, for XLA to
@@ -330,11 +453,11 @@ def _cg_solve(gathered, yty, val, mask, x0, reg, alpha, cg_steps):
             scaled = gathered * c1[..., None].astype(gathered.dtype)
             a_mat = (
                 yty[None]
-                + jnp.einsum(
-                    "blk,blm->bkm", scaled, gathered,
+                + _fold(jnp.einsum(
+                    "blk,blm->bkm", scaled, lines,
                     preferred_element_type=jnp.float32,
-                )
-                + (reg * n_b)[:, None, None] * jnp.eye(yty.shape[0], dtype=jnp.float32)
+                ), k, axes=(-2, -1))
+                + (reg * n_b)[:, None, None] * jnp.eye(k, dtype=jnp.float32)
             )
         with jax.named_scope("als.cg.precond"):
             diag = jnp.maximum(jnp.diagonal(a_mat, axis1=1, axis2=2), 1e-12)
@@ -349,17 +472,17 @@ def _cg_solve(gathered, yty, val, mask, x0, reg, alpha, cg_steps):
         with jax.named_scope("als.cg.precond"):
             diag = (
                 jnp.diagonal(yty)[None]
-                + _gdot("blk,bl->bk", gathered * gathered, c1)
+                + _fold(_gdot("blk,bl->bk", gathered * gathered, c1), k)
                 + (reg * n_b)[:, None]
             )
             diag = jnp.maximum(diag, 1e-12)
 
         def matvec(p):
             with jax.named_scope("als.cg.matvec"):
-                t = c1 * _gdot("blk,bk->bl", gathered, p)
+                t = c1 * _gdot("blk,bk->bl", gathered, _spread(p, width))
                 return (
                     p @ yty
-                    + _gdot("blk,bl->bk", gathered, t)
+                    + _fold(_gdot("blk,bl->bk", gathered, t), k)
                     + (reg * n_b)[:, None] * p
                 )
 
@@ -406,8 +529,10 @@ def solve_rows(
     """One bucket's solved ``(B, k)`` block, by the kernel ``solver`` names:
     ``"cholesky"`` the exact MLlib-parity solve (``bucket_solve_body``),
     ``"cg"`` the CG warm-started from the bucket's current rows of ``target``
-    (``bucket_cg_body``). Arguments as ``chunked_bucket_update``'s;
-    ``target`` and ``row_ids`` are read under ``"cg"`` only. The one place a
+    (``bucket_cg_body``). Arguments as ``chunked_bucket_update``'s: ``source``
+    serves the gather alone, so it comes in the gather's form
+    (``gather_table``, built where ``yty`` is); ``target`` and ``row_ids`` are
+    read under ``"cg"`` only. The one place a
     kernel is chosen: the fused sweep, the chunked per-bucket program, the
     eager reference and the mesh's assembled solve (``parallel.als``, which
     hands in its all-gathered tables) all trace this, so a change to either
@@ -430,8 +555,8 @@ def solve_rows(
 # albedo: noqa[bare-jit]
 @functools.partial(jax.jit, donate_argnames=("target",))
 def solve_bucket(
-    source: jax.Array,   # (n_source, k) fixed side's factors
-    yty: jax.Array,      # (k, k) gramian of `source`
+    source: jax.Array,   # gather_table of the (n_source, k) fixed side's factors
+    yty: jax.Array,      # (k, k) gramian of those factors
     target: jax.Array,   # (n_target, k) factors being updated (donated)
     row_ids: jax.Array,  # (B,) int32 target rows, -1 on padding slots
     idx: jax.Array,      # (B, L) int32 indices into `source`
@@ -455,8 +580,8 @@ def solve_bucket(
     static_argnames=("solver", "cg_steps", "gather_dtype"),
 )
 def chunked_bucket_update(
-    source: jax.Array,   # (n_source, k) fixed side's factors
-    yty: jax.Array,      # (k, k) gramian of `source`
+    source: jax.Array,   # gather_table of the (n_source, k) fixed side's factors
+    yty: jax.Array,      # (k, k) gramian of those factors
     target: jax.Array,   # (n_target, k) factors being updated (donated)
     row_ids: jax.Array,  # (B,) int32 target rows, -1 on padding slots
     idx: jax.Array,      # (B, L) int32 indices into `source`
@@ -496,11 +621,12 @@ def als_half_sweep(
     One compiled kernel per distinct bucket shape (O(log max_len) shapes).
     """
     yty = gramian(source)
+    table = gather_table(source)
     reg_arr = jnp.float32(reg)
     alpha_arr = jnp.float32(alpha)
     for b in buckets:
         target = solve_bucket(
-            source, yty, target,
+            table, yty, target,
             jnp.asarray(b.row_ids), jnp.asarray(b.idx),
             jnp.asarray(b.val), jnp.asarray(b.mask),
             reg_arr, alpha_arr,
@@ -535,6 +661,7 @@ def scan_half_sweep(
     the old factor for rows in no bucket.
     """
     yty = gramian(source)
+    table = gather_table(source)
 
     # Every target row appears in exactly one bucket, so the solves never
     # read rows written this half-sweep: solve all groups against the
@@ -544,16 +671,29 @@ def scan_half_sweep(
     def body(_, g):
         row_ids, idx, val, mask = g
         return None, solve_rows(
-            source, yty, target, row_ids, idx, val, mask, reg, alpha,
+            table, yty, target, row_ids, idx, val, mask, reg, alpha,
             solver, cg_steps, gather_dtype,
         )
 
     k = target.shape[1]
     all_rows, all_solved = [], []
     for g in groups:
-        _, solved = jax.lax.scan(body, None, (g.row_ids, g.idx, g.val, g.mask))
+        # A slot row's solve is its own, so a bucket scanned in pieces is the
+        # bucket solved, with every piece's gather small enough for the
+        # compiler to keep a line table in VMEM (GATHER_VMEM_ROWS); the last
+        # piece's empty slot rows are cut from the solved block.
+        n, n_slots, _ = g.idx.shape
+        pieces, per = gather_pieces(*g.idx.shape[1:], packed=gather_packs_rows(k))
+        xs = (g.row_ids, g.idx, g.val, g.mask)
+        if pieces > 1:
+            grown = ((0, 0), (0, pieces * per - n_slots))
+            xs = tuple(
+                jnp.pad(a, grown + ((0, 0),) * (a.ndim - 2)).reshape(n * pieces, per, *a.shape[2:])
+                for a in xs
+            )
+        _, solved = jax.lax.scan(body, None, xs)
         all_rows.append(g.row_ids.reshape(-1))
-        all_solved.append(solved.reshape(-1, k))
+        all_solved.append(solved.reshape(n, pieces * per, k)[:, :n_slots].reshape(-1, k))
     if landing is not None:
         with jax.named_scope("als.landing"):
             pool = jnp.concatenate(all_solved + [target])

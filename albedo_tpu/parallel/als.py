@@ -61,6 +61,7 @@ from albedo_tpu.datasets.ragged import Bucket, device_bucket
 from albedo_tpu.ops.als import (
     bucket_partial_terms,
     check_solver,
+    gather_table,
     scatter_solved,
     solve_corrected,
     solve_rows,
@@ -213,8 +214,9 @@ def _assembled_solve(
     source_l, yty, target_l, row_ids_l, idx_l, val_l, mask_l, reg, alpha,
     *, axis, solver, cg_steps, gather_dtype,
 ):
-    """Per-device bucket solve against the all-gathered source table."""
-    source = jax.lax.all_gather(source_l, axis, axis=0, tiled=True)
+    """Per-device bucket solve against the all-gathered source table, in the
+    form the gather reads it (``ops.als.gather_table``)."""
+    source = gather_table(jax.lax.all_gather(source_l, axis, axis=0, tiled=True))
     target = None
     if solver == "cg":
         # Warm starts read the PRE-SWEEP target rows, which live on whatever

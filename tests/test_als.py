@@ -348,7 +348,7 @@ SHARED_REPORT_KEYS = {
     "compile_source": (str, type(None)), "device_s": float, "prep_cached": bool,
     "health": dict, "mode": str, "capacity": (dict, type(None)),
     "cg_gram_entry_share": float, "gather_reformed_entry_share": float,
-    "spans": dict,
+    "gather_packed_entry_share": float, "spans": dict,
 }
 OWN_REPORT_KEYS = {
     "resident": {"capacity_cross_check"},
@@ -373,10 +373,26 @@ def test_every_path_reports_the_shared_keys_and_spans(path, small_matrix):
         assert rep["pipelined"] is (path != "sharded_streamed_sync")
     assert set(rep["health"]) == {"nonfinite", "max_abs", "rms"}
     assert rep["compile_s"] >= 0 and 0 <= rep["cg_gram_entry_share"] <= 1
+    assert rep["gather_packed_entry_share"] == 1.0       # rank 8: two rows a 128-lane line
     totals, counts = rep["spans"]["totals"], rep["spans"]["counts"]
     assert counts["fit"] == counts["fit.wait"] == counts["fit.prep"] == 1
     assert totals["fit"] >= totals["fit.wait"] > 0
     assert totals["fit.acquire"] >= rep["compile_s"] - 1e-3
+
+
+@pytest.mark.parametrize("rank,want", [(8, 1.0), (64, 1.0), (65, 0.0), (128, 0.0)])
+def test_packed_share_is_the_ranks_and_the_same_on_the_fused_and_chunked_paths(rank, want, small_matrix):
+    """``gather_packed_entry_share``: every padded entry where two factor rows
+    fit a 128-lane line (``ops.als.gather_packs_rows``), none where they do
+    not, whichever path gathered one layout."""
+    shares = []
+    for path in ("resident", "chunked"):
+        est = ImplicitALS(rank=rank, batch_size=32, seed=5, max_iter=1, solver="cg", **FIT_PATHS[path])
+        est.fit(small_matrix)
+        assert est.last_fit_report["mode"] == path
+        shares.append(est.last_fit_report["gather_packed_entry_share"])
+    assert shares == [want, want]
+    assert all(isinstance(share, float) for share in shares)
 
 
 @pytest.mark.parametrize("path", list(FIT_PATHS) + ["resident_callback"])

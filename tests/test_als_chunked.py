@@ -242,13 +242,15 @@ class TestStreamObservability:
         bucket's dispatch would copy the whole target table."""
         import jax.numpy as jnp
 
+        from albedo_tpu.ops.als import gather_table
+
         est, m = _degraded(monkeypatch, 10)
         est.fit(m)
         (key, compiled), *_ = est._chunked_executables(m).items()
         n_source, n_target, (b, l) = key
         target = jnp.ones((n_target, 8), jnp.float32)
-        compiled(
-            jnp.ones((n_source, 8), jnp.float32), jnp.eye(8, dtype=jnp.float32), target,
+        compiled(   # (the fixed side's table in the form the gather reads it)
+            gather_table(jnp.ones((n_source, 8), jnp.float32)), jnp.eye(8, dtype=jnp.float32), target,
             jnp.full((b,), -1, jnp.int32), jnp.zeros((b, l), jnp.int32),
             jnp.zeros((b, l), jnp.float32), jnp.zeros((b, l), bool),
             jnp.float32(0.5), jnp.float32(40.0),
