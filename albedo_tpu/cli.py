@@ -125,8 +125,10 @@ def build_parser() -> argparse.ArgumentParser:
         "--shard-mode",
         choices=("allgather", "ring"),
         default="allgather",
-        help="sharded-fit source assembly: allgather (full table transient "
-        "per bucket) or ring (ppermute'd 1/n shards, cholesky only)",
+        help="sharded-fit source assembly: allgather (the full table, "
+        "assembled once a half-sweep with resident buckets and every device "
+        "solving its own rows; once a bucket with streamed ones) or ring "
+        "(ppermute'd 1/n shards, a bucket at a time, cholesky only)",
     )
     parser.add_argument(
         "--no-compilation-cache",

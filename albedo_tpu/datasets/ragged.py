@@ -391,6 +391,132 @@ def grouped_bucket_rows(
     return groups
 
 
+def shard_rows(n_rows: int, n_shards: int) -> int:
+    """Rows a shard of a row-sharded ``n_rows``-row table: contiguous ranges
+    of one size, the last padded (``parallel.mesh.pad_rows_to``)."""
+    return -(-n_rows // n_shards)
+
+
+def balanced_shards(indptr: np.ndarray, n_shards: int) -> tuple[np.ndarray, np.ndarray]:
+    """Which shard owns which row, so that every shard holds the same row
+    lengths to within one row: rows dealt to the shards in turn in order of
+    their length (stable: by length, then by row id). Returns
+    ``(phys_of_logical, logical_of_phys)``, two permutations of
+    ``range(n_shards * shard_rows(n_rows, n_shards))``, inverse to one
+    another: physical row ``d * rows_per + i`` is shard ``d``'s ``i``-th row,
+    and the few ids from ``n_rows`` up are the table's zero padding rows on
+    both sides.
+
+    Ownership by contiguous id ranges would hand each shard whatever lengths
+    its ids happen to have: a half-sweep then takes as long as the shard that
+    drew the heaviest rows, its bucket shapes differ from matrix to matrix of
+    the same degree sequence (every seed compiles anew), and its time from
+    seed to seed. Dealt in turn, the shards' bucket plans are the same
+    function of the degree sequence alone."""
+    n_rows = indptr.shape[0] - 1
+    rows_per = shard_rows(n_rows, n_shards)
+    n_pad = rows_per * n_shards
+    order = np.argsort(np.diff(indptr), kind="stable")
+    rank = np.arange(n_rows, dtype=np.int64)
+    logical_of_phys = np.full(n_pad, -1, dtype=np.int32)
+    logical_of_phys[(rank % n_shards) * rows_per + rank // n_shards] = order
+    # the padding rows, logical and physical, stand for one another
+    logical_of_phys[logical_of_phys < 0] = np.arange(n_rows, n_pad, dtype=np.int32)
+    phys_of_logical = np.empty(n_pad, dtype=np.int32)
+    phys_of_logical[logical_of_phys] = np.arange(n_pad, dtype=np.int32)
+    return phys_of_logical, logical_of_phys
+
+
+def shard_grouped_bucket_rows(
+    indptr: np.ndarray,
+    indices: np.ndarray,
+    vals: np.ndarray,
+    logical_of_phys: np.ndarray,
+    n_shards: int,
+    batch_size: int = 1024,
+    len_multiple: int = 8,
+    max_len: int | None = None,
+    max_entries: int | None = None,
+    workers: int | None = None,
+) -> list[Bucket]:
+    """Bucket every shard's OWN rows (the ALX layout, arXiv:2112.02194):
+    shape groups ``(N, n_shards * B, L)`` as ``grouped_bucket_rows`` makes
+    them, in which slots ``[d * B, (d + 1) * B)`` of every bucket hold rows
+    of shard ``d`` under row ids LOCAL to that shard. ``logical_of_phys``
+    says which rows those are (:func:`balanced_shards`: shard ``d``'s
+    ``i``-th row is CSR row ``logical_of_phys[d * rows_per + i]``);
+    ``indices`` are written into the slabs as they are, so they come in the
+    numbering of the OTHER side's table as the devices hold it.
+
+    Each shard's rows are planned alone (``plan_buckets``: the same length
+    tiers, chunked by the same slot and entry budgets), and bucket ``j`` of a
+    length tier takes the slot count of the shard that has most rows in it,
+    so that the batch axis splits evenly over a mesh axis of ``n_shards``
+    and ``shard_map`` sees one shape. A shard with fewer rows there has
+    empty slots (``row_ids == -1``, zero weight), and one with no bucket
+    ``j`` at all an empty bucket. With the slot axis sharded over the mesh,
+    a device solves its own rows from its own slots: warm starts are read
+    from, and solved rows land in, its own shard of the table."""
+    n_rows = indptr.shape[0] - 1
+    rows_per = shard_rows(n_rows, n_shards)
+    lengths = np.diff(indptr)
+    owned: list[np.ndarray] = []          # a shard's rows, by local row id
+    tiers: list[dict[int, list[BucketPlan]]] = []
+    for d in range(n_shards):
+        rows = logical_of_phys[d * rows_per:(d + 1) * rows_per]
+        rows = rows[rows < n_rows]        # (padding rows come last)
+        owned.append(rows)
+        local_indptr = np.zeros(rows.shape[0] + 1, dtype=np.int64)
+        np.cumsum(lengths[rows], out=local_indptr[1:])
+        by_len: dict[int, list[BucketPlan]] = {}
+        for p in plan_buckets(
+            local_indptr, batch_size=batch_size, len_multiple=len_multiple,
+            max_len=max_len, max_entries=max_entries,
+        ):
+            by_len.setdefault(p.shape[1], []).append(p)
+        tiers.append(by_len)
+    by_shape: dict[tuple[int, int], list[list[BucketPlan | None]]] = {}
+    for length in sorted(set().union(*tiers)):
+        lists = [t.get(length, []) for t in tiers]
+        for j in range(max(map(len, lists))):
+            plans = [ps[j] if j < len(ps) else None for ps in lists]
+            b = max(p.shape[0] for p in plans if p is not None)
+            by_shape.setdefault((b, length), []).append(plans)
+
+    groups: list[Bucket] = []
+    tasks: list[tuple[Bucket, int, BucketPlan]] = []
+    for (b, pad_l), buckets in sorted(by_shape.items()):
+        n = len(buckets)
+        g = Bucket(
+            row_ids=np.full((n, n_shards * b), -1, dtype=np.int32),
+            idx=np.zeros((n, n_shards * b, pad_l), dtype=np.int32),
+            val=np.zeros((n, n_shards * b, pad_l), dtype=np.float32),
+            mask=np.zeros((n, n_shards * b, pad_l), dtype=bool),
+        )
+        groups.append(g)
+        for si, plans in enumerate(buckets):
+            for d, p in enumerate(plans):
+                if p is not None:
+                    mine = slice(d * b, (d + 1) * b)
+                    out = Bucket(row_ids=g.row_ids[si, mine], idx=g.idx[si, mine],
+                                 val=g.val[si, mine], mask=g.mask[si, mine])
+                    tasks.append((out, d, p))
+
+    def fill(task: tuple[Bucket, int, BucketPlan]) -> None:
+        out, d, p = task
+        # filled from the CSR rows the local ids stand for, kept under the local ids
+        fill_bucket(dataclasses.replace(p, rows=owned[d][p.rows]), indptr, indices, vals, out=out)
+        out.row_ids[:p.rows.shape[0]] = p.rows
+
+    if workers and workers > 1 and len(tasks) > 1:
+        with ThreadPoolExecutor(max_workers=workers) as pool:
+            list(pool.map(fill, tasks))
+    else:
+        for task in tasks:
+            fill(task)
+    return groups
+
+
 def padded_rows(
     indptr: np.ndarray, indices: np.ndarray, rows: np.ndarray, fill: int = -1
 ) -> np.ndarray:

@@ -15,6 +15,7 @@ then user factors.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import time
 import weakref
@@ -29,8 +30,11 @@ from albedo_tpu.datasets.ragged import (
     Bucket,
     bucket_rows,
     device_bucket,
+    balanced_shards,
     group_buckets,
     grouped_bucket_rows,
+    shard_grouped_bucket_rows,
+    shard_rows,
 )
 from albedo_tpu.datasets.star_matrix import StarMatrix
 from albedo_tpu.ops.als import (
@@ -67,6 +71,20 @@ CHUNKED_SPANS = (
     "fit.stream.acquire", "fit.stream.dispatch", "fit.wait",
 )
 
+# The spans of a resident row-sharded fit (``sharded="resident"``,
+# ``shard_mode="allgather"``; ``ImplicitALS._fit_sharded_resident`` says what
+# each is around), for the tests and the benchmark's ``fit_sharded`` driver.
+SHARDED_SPANS = (
+    "fit", "fit.prep", "fit.acquire", "fit.init",
+    "fit.shard", "fit.shard.gramian", "fit.shard.assemble",
+    "fit.shard.dispatch", "fit.relayout", "fit.wait",
+)
+
+
+def _cut(table, n_rows: int):
+    """``table`` without its row padding, itself where it has none."""
+    return table if table.shape[0] == n_rows else table[:n_rows]
+
 
 class ALSModel:
     """Trained factor matrices, indexed by dense user/item indices.
@@ -77,10 +95,19 @@ class ALSModel:
     device->host transfer that evaluation may never need, and the retrieval
     path can keep scoring on device."""
 
-    def __init__(self, user_factors, item_factors, rank: int):
+    def __init__(self, user_factors, item_factors, rank: int,
+                 n_users: int | None = None, n_items: int | None = None):
+        # A row-sharded fit hands its tables back as they lie on the mesh:
+        # rows padded with zeros to a shard-count multiple. ``n_users`` /
+        # ``n_items`` are the logical row counts; the host copies are cut to
+        # them on the host, and a device copy only where serving asks for
+        # one (cutting a sharded table on the device to a count the mesh
+        # does not divide gathers all of it onto every device).
         self._uf_raw = user_factors
         self._vf_raw = item_factors
         self.rank = int(rank)
+        self.n_users = int(user_factors.shape[0] if n_users is None else n_users)
+        self.n_items = int(item_factors.shape[0] if n_items is None else n_items)
         self._uf_np: np.ndarray | None = None
         self._vf_np: np.ndarray | None = None
         self._dev: tuple[jax.Array, jax.Array] | None = None
@@ -89,13 +116,13 @@ class ALSModel:
     @property
     def user_factors(self) -> np.ndarray:  # (n_users, rank) float32
         if self._uf_np is None:
-            self._uf_np = np.asarray(self._uf_raw, dtype=np.float32)
+            self._uf_np = _cut(np.asarray(self._uf_raw, dtype=np.float32), self.n_users)
         return self._uf_np
 
     @property
     def item_factors(self) -> np.ndarray:  # (n_items, rank) float32
         if self._vf_np is None:
-            self._vf_np = np.asarray(self._vf_raw, dtype=np.float32)
+            self._vf_np = _cut(np.asarray(self._vf_raw, dtype=np.float32), self.n_items)
         return self._vf_np
 
     def device_factors(self) -> tuple[jax.Array, jax.Array]:
@@ -106,7 +133,7 @@ class ALSModel:
         NOT pay this pin for host-backed models (see below)."""
         if self._dev is None:
             uf = (
-                self._uf_raw
+                _cut(self._uf_raw, self.n_users)
                 if isinstance(self._uf_raw, jax.Array)
                 else jnp.asarray(self.user_factors)
             )
@@ -121,7 +148,7 @@ class ALSModel:
             return self._dev[1]
         if self._vf_dev is None:
             self._vf_dev = (
-                self._vf_raw
+                _cut(self._vf_raw, self.n_items)
                 if isinstance(self._vf_raw, jax.Array)
                 else jnp.asarray(self.item_factors)
             )
@@ -141,7 +168,7 @@ class ALSModel:
     ) -> tuple[np.ndarray, np.ndarray]:
         """Top-k items for the given users: (scores (U, k), item_idx (U, k))."""
         ui = np.asarray(user_indices)
-        n = self._uf_raw.shape[0]
+        n = self.n_users
         if ui.size and (int(ui.min()) < 0 or int(ui.max()) >= n):
             # Out-of-range indices (including negatives — dense user indices
             # have no wrap-around meaning here) are rejected on BOTH paths:
@@ -192,6 +219,21 @@ def _landing_perm(buckets: list[Bucket], n_target: int) -> np.ndarray:
         offset += rid.size
     return landing
 
+
+
+def _shard_landing_perm(groups: list[Bucket], n_shards: int, rows_per: int) -> np.ndarray:
+    """``_landing_perm`` of every shard of own-rows shape groups
+    (``ragged.shard_grouped_bucket_rows``: shard ``d``'s slots of a bucket
+    are ``[d * B, (d + 1) * B)``, its row ids local), one after another:
+    the ``(n_shards * rows_per,)`` vector that, row-sharded, hands each
+    device the landing permutation of its own solved blocks."""
+    def slots(g: Bucket, d: int) -> Bucket:
+        b = g.row_ids.shape[1] // n_shards
+        return dataclasses.replace(g, row_ids=g.row_ids[:, d * b:(d + 1) * b])
+
+    return np.concatenate([
+        _landing_perm([slots(g, d) for g in groups], rows_per) for d in range(n_shards)
+    ])
 
 # Weakref-keyed per-matrix caches (ADVICE r5 #1): keyed by id() with a
 # finalizer that drops the entry when the matrix is collected, so a
@@ -287,8 +329,10 @@ class ImplicitALS:
     # driver (parallel/elastic.py): mesh-portable sweep-boundary
     # checkpoints + mid-fit device-loss remesh-resume.
     sharded: Any | None = None
-    # Source-factor assembly for the sharded path: "allgather" (full table
-    # transient per bucket) or "ring" (ppermute'd 1/n shards, cholesky only).
+    # Source-factor assembly for the sharded path: "allgather" (the full
+    # table: once a half-sweep with resident buckets, every device solving
+    # its own rows; once a bucket with streamed ones) or "ring" (ppermute'd
+    # 1/n shards, a bucket at a time, cholesky only).
     shard_mode: str = "allgather"
 
     def _layout_kwargs(self) -> dict:
@@ -640,7 +684,10 @@ class ImplicitALS:
                 )
             self.last_fit_report = self._finish(run, path, admission, t0, timer)
         self.last_fit_report["spans"] = timer.snapshot()
-        return ALSModel(user_factors=run.user_f, item_factors=run.item_f, rank=self.rank)
+        return ALSModel(
+            user_factors=run.user_f, item_factors=run.item_f, rank=self.rank,
+            n_users=matrix.n_users, n_items=matrix.n_items,
+        )
 
     def _choose_path(self, matrix: StarMatrix, timer: Timer) -> tuple[str, Any]:
         """Which fit runs, and the admission verdict that said so (``None``
@@ -1009,14 +1056,25 @@ class ImplicitALS:
         executables through the persistent AOT layer, and the watchdog
         health reduction as the completion barrier — parity with the
         single-device resident fit is test-pinned at atol 1e-5.
+
+        Resident buckets under ``shard_mode="allgather"`` (what ``train_als
+        --mesh-devices n --sharded resident`` builds) take the dataflow of
+        :meth:`_fit_sharded_resident` instead: the source table assembled
+        ONCE a half-sweep, every device solving its own rows.
         """
-        from albedo_tpu.parallel.als import sharded_fit_engine
+        from albedo_tpu.parallel.als import (
+            assembled_bytes_per_sweep,
+            collective_bytes_per_sweep,
+            sharded_fit_engine,
+        )
         from albedo_tpu.parallel.mesh import DATA_AXIS
 
         engine = sharded_fit_engine(
             self.mesh, DATA_AXIS, self.solver, self.cg_steps,
             self.gather_dtype, self.shard_mode,
         )
+        if not streamed and self.shard_mode == "allgather":
+            return self._fit_sharded_resident(matrix, callback, timer, engine)
         with timer.section("fit.prep"):
             user_buckets, item_buckets = self._host_buckets(matrix)
         t1 = time.perf_counter()
@@ -1028,22 +1086,33 @@ class ImplicitALS:
             streamed=streamed, callback=callback, pipelined=pipelined,
         )
         timer.add("fit.acquire", stats["compile_s"])
+        # Each device gathers and solves its own slots of a bucket (padded to
+        # a multiple of the shards).
+        n, ring = engine.n_shards, self.shard_mode == "ring"
+        local = [(-(-b.shape[0] // n), b.shape[1]) for b in (*user_buckets, *item_buckets)]
+        # Every bucket's program moves whole tables: all-gathered (the source,
+        # and under CG the target), or ring-passed shard by shard and never
+        # assembled; then its solved rows, all-gathered to land.
+        tables = assembled_bytes_per_sweep(
+            matrix.n_users, matrix.n_items, self.rank, n,
+            (len(user_buckets), len(item_buckets)), self.solver,
+        )
         return _PathRun(
             user_f, item_f, t1,
-            # Each device gathers and solves its own slots of a bucket
-            # (padded to a multiple of the shards); the ring mode gathers
-            # phase by phase from a table shard, not through
-            # ``ops.als._gather``, and is Cholesky only.
-            [] if self.shard_mode == "ring" else [
-                (-(-b.shape[0] // engine.n_shards), b.shape[1])
-                for b in (*user_buckets, *item_buckets)
-            ],
+            # The ring mode gathers phase by phase from a table shard, not
+            # through ``ops.als._gather``, and is Cholesky only.
+            [] if ring else local,
             stats["compile_s"], "+".join(sorted(stats["compile_sources"])) or None,
             own={
                 "shard_mode": self.shard_mode,
-                "n_shards": engine.n_shards,
+                "n_shards": n,
                 "streamed_buckets": stats["streamed_buckets"],
                 "sharded_shapes": stats["n_shapes"],
+                "assembled_bytes_per_sweep": 0 if ring else tables,
+                "collective_bytes_per_sweep": collective_bytes_per_sweep(
+                    self.rank, n, tables, landed_rows=n * sum(b for b, _ in local)),
+                "dispatches": stats["dispatches"],
+                "shard_padded_entries": sum(b * ln for b, ln in local),
                 # Pipelined-dataflow accounting: upload_s accumulates inside the
                 # background prefetch thread when pipelined+streamed, so it is
                 # OFF the critical path there; prefetch_wait_s is the time the
@@ -1060,6 +1129,167 @@ class ImplicitALS:
                 },
             },
             upload_s=stats["upload_s"],
+        )
+
+    def _sharded_groups_cache_key(self) -> tuple:
+        return ("device_sharded", *self._groups_cache_key()[1:])
+
+    def _device_groups_sharded(self, matrix: StarMatrix, timer: Timer, engine) -> tuple:
+        """``(user_groups, item_groups, user_landing, item_landing,
+        user_rows, item_rows)`` on the mesh for the resident row-sharded fit.
+        Rows are dealt to the shards in turn by length
+        (``datasets.ragged.balanced_shards``), so every shard holds the same
+        lengths and the shapes are the degree sequence's alone; every
+        shard's OWN rows are bucketed (``shard_grouped_bucket_rows``, source
+        indices in the other side's dealt order), each group's slot axis
+        laid over the mesh's data axis, each side's per-shard landing
+        permutations (``_landing_perm`` of a shard's slots, local row ids)
+        stacked under the row sharding. ``*_rows`` are a side's
+        ``(logical_of_phys, phys_of_logical)`` on the mesh: the order the
+        seeded tables are laid out in, and the way back. Memoized per
+        (matrix, layout, mesh, backend) like :meth:`device_groups`, with the
+        same spans: ``fit.prep.index`` (the CSR + CSC build and the deal),
+        ``fit.prep.fill`` and ``fit.prep.upload`` (= ``upload_s``)."""
+        key = self._sharded_groups_cache_key()
+        cache = _matrix_cache(matrix)
+        if key in cache:
+            self.last_prep_timings = {"bucket_s": 0.0, "upload_s": 0.0}
+            return cache[key]
+        t0 = time.perf_counter()
+        n = engine.n_shards
+        with timer.section("fit.prep.index"), ThreadPoolExecutor(max_workers=2) as sides:
+            csr_f, csc_f = sides.submit(matrix.csr), sides.submit(matrix.csc)
+            csr, csc = csr_f.result(), csc_f.result()
+            dealt = [balanced_shards(csx[0], n) for csx in (csr, csc)]
+
+        def build_side(csx, rows, source_rows):
+            indptr, indices, vals = csx
+            with timer.section("fit.prep.fill"):
+                groups = shard_grouped_bucket_rows(
+                    indptr, source_rows[0][indices], vals, rows[1], n,
+                    **self._layout_kwargs(), workers=_bucket_workers())
+                landing = _shard_landing_perm(groups, n, shard_rows(indptr.shape[0] - 1, n))
+            with timer.section("fit.prep.upload"):
+                return ([engine.put_group(g) for g in groups], engine.put_rows(landing),
+                        (engine.put_rows(rows[1]), engine.put_rows(rows[0])))
+
+        ug, u_land, u_rows = build_side(csr, dealt[0], dealt[1])
+        ig, i_land, i_rows = build_side(csc, dealt[1], dealt[0])
+        upload = timer.totals["fit.prep.upload"]
+        self.last_prep_timings = {
+            "bucket_s": round(max(0.0, time.perf_counter() - t0 - upload), 4),
+            "upload_s": round(upload, 4),
+        }
+        cache[key] = (ug, ig, u_land, i_land, u_rows, i_rows)
+        return cache[key]
+
+    def _fit_sharded_resident(
+        self, matrix: StarMatrix, callback: Any | None, timer: Timer, engine
+    ) -> "_PathRun":
+        """The row-sharded fit with resident buckets (``sharded="resident"``,
+        ``shard_mode="allgather"``: the ALX layout, arXiv:2112.02194). Both
+        tables stay row-sharded over the mesh from the seeded draw to the
+        returned model; every device buckets, solves and lands its OWN rows
+        with the one-chip sweep's kernels (``ops.als.scan_group``), and the
+        only bytes that cross the mesh are each source table's other shards
+        ONCE a half-sweep and the ``(k, k)`` psum
+        (``parallel/als.py``, "the resident dataflow").
+
+        Spans (``SHARDED_SPANS``): ``fit.prep`` (bucketing each shard's rows
+        and the one upload, ``.index`` / ``.fill`` / ``.upload`` inside),
+        ``fit.acquire`` (every shape's executable ahead of the first sweep,
+        on threads; = ``compile_s``), ``fit.init`` (the seeded tables, made
+        on the mesh), one ``fit.shard`` a half-sweep holding
+        ``fit.shard.gramian`` (the psum program's dispatch),
+        ``fit.shard.assemble`` (the assembly's) and ``fit.shard.dispatch``
+        (a program a shape group and the landing); ``fit.relayout`` (the
+        fitted tables back into the logical row order) and ``fit.wait`` end
+        the fit. Device scopes: ``als.shard.gramian``, ``als.shard.assemble``
+        (the all-gather and the relayout ``gather_table`` needs),
+        ``als.shard.land``, ``als.shard.relayout`` (the seeded tables into
+        the order the shards own rows in, and the fitted ones back), and
+        ``als.gather`` / ``als.cg`` inside the group programs. Counters: see
+        the ``own`` keys below.
+        """
+        from albedo_tpu.parallel.als import collective_bytes_per_sweep
+
+        prep_cached = self._sharded_groups_cache_key() in _matrix_cache(matrix)
+        with timer.section("fit.prep"):
+            ug, ig, u_land, i_land, u_rows, i_rows = self._device_groups_sharded(
+                matrix, timer, engine)
+        prep_split = dict(self.last_prep_timings)
+        t1 = time.perf_counter()
+
+        n = engine.n_shards
+        shapes_u = [tuple(g[1].shape) for g in ug]
+        shapes_i = [tuple(g[1].shape) for g in ig]
+        stats = {"compile_s": 0.0, "compile_sources": set(), "dispatches": 0,
+                 "assembled_bytes": 0}
+        sizes = (matrix.n_users, matrix.n_items, self.rank)
+        with timer.section("fit.acquire"):
+            engine.acquire_local(*sizes, shapes_u, shapes_i, stats, timer,
+                                 workers=_bucket_workers() or 1)
+        compile_s = time.perf_counter() - t1
+
+        with timer.section("fit.init"):
+            if self.init_factors is None:
+                user_sh, item_sh = engine.seeded_tables(
+                    jax.random.PRNGKey(self.seed), u_rows[0], i_rows[0], *sizes, stats)
+            else:
+                # a warm start comes in the logical order, like the model goes out
+                user_sh, item_sh = (
+                    engine.relayout(engine.shard_table(f), rows[0], stats)
+                    for f, rows in zip(self._initial_factors(matrix, np.asarray), (u_rows, i_rows)))
+
+        def logical(user_sh, item_sh):
+            return (engine.relayout(user_sh, u_rows[1], stats),
+                    engine.relayout(item_sh, i_rows[1], stats))
+
+        def after_sweep(it, user_sh, item_sh):
+            # Checkpoint-callback host copies, by contract (see fit()).
+            user_f, item_f = logical(user_sh, item_sh)
+            callback(
+                it,
+                np.asarray(user_f)[:matrix.n_users],   # albedo: noqa[hidden-host-sync]
+                np.asarray(item_f)[:matrix.n_items],   # albedo: noqa[hidden-host-sync]
+            )
+
+        user_sh, item_sh = engine.fit_local(
+            user_sh, item_sh, ug, ig, u_land, i_land, self.reg_param, self.alpha,
+            self.max_iter, stats, timer, after_sweep=None if callback is None else after_sweep,
+        )
+        with timer.section("fit.relayout"):
+            user_sh, item_sh = logical(user_sh, item_sh)
+        # each device's own slots of a group, in the pieces it scans them in
+        local = [scanned_shape((s[0], s[1] // n, s[2]), self.rank)
+                 for s in (*shapes_u, *shapes_i)]
+        # what the compiled programs of the sweeps all-gathered, a chip a sweep
+        assembled = stats["assembled_bytes"] // max(1, self.max_iter)
+        return _PathRun(
+            user_sh, item_sh, t1, local, compile_s,
+            "+".join(sorted(stats["compile_sources"])) or None,
+            own={
+                "shard_mode": self.shard_mode,
+                "n_shards": n,
+                "streamed_buckets": 0,
+                "sharded_shapes": len(set(shapes_u)) + len(set(shapes_i)),
+                "pipelined": False,
+                "prefetch_wait_s": 0.0,
+                # counted from the executables the sweeps called (one assembly
+                # of each table a sweep is the plan); what of it crosses the
+                # mesh into a chip, with the two psums
+                "assembled_bytes_per_sweep": assembled,
+                "collective_bytes_per_sweep": collective_bytes_per_sweep(self.rank, n, assembled),
+                "dispatches": stats["dispatches"],
+                "shard_padded_entries": sum(math.prod(s) for s in local),
+                "mesh_events": {
+                    "losses": 0, "resumes": 0, "degradations": 0,
+                    "checkpoint_s": 0.0, "n_shards": n,
+                },
+            },
+            bucket_s=prep_split.get("bucket_s", 0.0),
+            upload_s=prep_split.get("upload_s", 0.0),
+            prep_cached=prep_cached,
         )
 
 

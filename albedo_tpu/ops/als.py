@@ -634,6 +634,48 @@ def als_half_sweep(
     return target
 
 
+def scan_group(
+    table: jax.Array,    # gather_table of the fixed side's factors
+    yty: jax.Array,      # (k, k) gramian of those factors
+    target: jax.Array,   # (n_target, k) pre-sweep factors (CG warm starts)
+    g: Bucket,           # one stacked shape group: row_ids (N, B), idx (N, B, L), ...
+    reg: jax.Array,
+    alpha: jax.Array,
+    solver: str,
+    cg_steps: int,
+    gather_dtype,
+) -> tuple[jax.Array, jax.Array]:
+    """One shape group's ``(N * B,)`` row ids and solved ``(N * B, k)`` rows,
+    slot for slot: one ``lax.scan`` over its same-shape buckets, nothing
+    landed. The body of
+    ``scan_half_sweep``, and of the row-sharded fit's per-group program
+    (``parallel.als``), whose devices each scan their own rows' buckets."""
+
+    def body(_, xs):
+        row_ids, idx, val, mask = xs
+        return None, solve_rows(
+            table, yty, target, row_ids, idx, val, mask, reg, alpha,
+            solver, cg_steps, gather_dtype,
+        )
+
+    # A slot row's solve is its own, so a bucket scanned in pieces is the
+    # bucket solved, with every piece's gather small enough for the
+    # compiler to keep a line table in VMEM (GATHER_VMEM_ROWS); the last
+    # piece's empty slot rows are cut from the solved block.
+    k = target.shape[1]
+    n, n_slots, _ = g.idx.shape
+    pieces, per = gather_pieces(*g.idx.shape[1:], packed=gather_packs_rows(k))
+    xs = (g.row_ids, g.idx, g.val, g.mask)
+    if pieces > 1:
+        grown = ((0, 0), (0, pieces * per - n_slots))
+        xs = tuple(
+            jnp.pad(a, grown + ((0, 0),) * (a.ndim - 2)).reshape(n * pieces, per, *a.shape[2:])
+            for a in xs
+        )
+    _, solved = jax.lax.scan(body, None, xs)
+    return g.row_ids.reshape(-1), solved.reshape(n, pieces * per, k)[:, :n_slots].reshape(-1, k)
+
+
 def scan_half_sweep(
     source: jax.Array,
     target: jax.Array,
@@ -668,32 +710,13 @@ def scan_half_sweep(
     # PRE-SWEEP target (CG warm starts read it), collect the solved blocks,
     # and land them in ONE gather (or scatter, without `landing`) — keeping
     # the (n_target, k) table out of the scan carry.
-    def body(_, g):
-        row_ids, idx, val, mask = g
-        return None, solve_rows(
-            table, yty, target, row_ids, idx, val, mask, reg, alpha,
-            solver, cg_steps, gather_dtype,
-        )
-
-    k = target.shape[1]
     all_rows, all_solved = [], []
     for g in groups:
-        # A slot row's solve is its own, so a bucket scanned in pieces is the
-        # bucket solved, with every piece's gather small enough for the
-        # compiler to keep a line table in VMEM (GATHER_VMEM_ROWS); the last
-        # piece's empty slot rows are cut from the solved block.
-        n, n_slots, _ = g.idx.shape
-        pieces, per = gather_pieces(*g.idx.shape[1:], packed=gather_packs_rows(k))
-        xs = (g.row_ids, g.idx, g.val, g.mask)
-        if pieces > 1:
-            grown = ((0, 0), (0, pieces * per - n_slots))
-            xs = tuple(
-                jnp.pad(a, grown + ((0, 0),) * (a.ndim - 2)).reshape(n * pieces, per, *a.shape[2:])
-                for a in xs
-            )
-        _, solved = jax.lax.scan(body, None, xs)
-        all_rows.append(g.row_ids.reshape(-1))
-        all_solved.append(solved.reshape(n, pieces * per, k)[:, :n_slots].reshape(-1, k))
+        rows, solved = scan_group(
+            table, yty, target, g, reg, alpha, solver, cg_steps, gather_dtype
+        )
+        all_rows.append(rows)
+        all_solved.append(solved)
     if landing is not None:
         with jax.named_scope("als.landing"):
             pool = jnp.concatenate(all_solved + [target])

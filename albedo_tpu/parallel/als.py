@@ -23,16 +23,19 @@ rank 50, float32) a full table is ≤ a few hundred MB — far below HBM — and
 replication makes the per-bucket arbitrary-index gather local.
 
 3. **The fully sharded fit** (`ShardedALSFit`, ALX arXiv:2112.02194) for
-   larger-than-HBM factor tables: BOTH tables row-sharded over ``data``,
-   per-device bucket blocks solved against all-gathered or ring-passed
-   source shards inside shard_map, solved rows landed shard-locally from a
-   small all-gathered block, and (optionally) interaction buckets STREAMED
-   from the host per half-sweep so the star matrix is never device-resident
-   whole. ``models.als.ImplicitALS`` dispatches here when the capacity
-   admission ladder says the replicated layout no longer fits
-   (ARCHITECTURE.md "Sharded ALS").
+   larger-than-HBM factor tables: BOTH tables row-sharded over ``data``.
+   With resident buckets under ``mode="allgather"`` every device buckets,
+   solves and lands its OWN rows against the source table assembled ONCE a
+   half-sweep ("the resident dataflow" below: `fit_local`). Otherwise —
+   interaction buckets STREAMED from the host per half-sweep so the star
+   matrix is never device-resident whole, or the ring — per-device bucket
+   blocks are solved against source shards all-gathered or ring-passed
+   inside every bucket's program, and solved rows land shard-locally from a
+   small all-gathered block (`fit`). ``models.als.ImplicitALS`` dispatches
+   here when the capacity admission ladder says the replicated layout no
+   longer fits, or ``sharded`` names a rung (ARCHITECTURE.md "Sharded ALS").
 
-The sharded dataflow is PIPELINED end to end by default (ARCHITECTURE.md
+The per-bucket dataflow is PIPELINED end to end by default (ARCHITECTURE.md
 "Pipelined sharded dataflow"; ``ShardedALSFit.fit(pipelined=False)`` is the
 synchronous one):
 a background prefetcher (`_BucketPrefetcher`) uploads bucket i+1 while
@@ -47,7 +50,10 @@ parity-pinned at 1e-5 against the synchronous path.
 from __future__ import annotations
 
 import functools
+import math
 import queue
+import re
+from concurrent.futures import ThreadPoolExecutor
 import threading
 import time
 
@@ -57,12 +63,14 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from albedo_tpu.datasets.ragged import Bucket, device_bucket
+from albedo_tpu.datasets.ragged import Bucket, device_bucket, shard_rows
 from albedo_tpu.ops.als import (
     bucket_partial_terms,
     check_solver,
     gather_table,
+    scan_group,
     scatter_solved,
+    seeded_factors,
     solve_corrected,
     solve_rows,
 )
@@ -138,7 +146,8 @@ def sharded_gramian(mesh: Mesh, axis: str = DATA_AXIS):
         out_specs=P(),
     )
     def gramian(local_factors: jax.Array) -> jax.Array:
-        return jax.lax.psum(local_factors.T @ local_factors, axis)
+        with jax.named_scope("als.shard.gramian"):
+            return jax.lax.psum(local_factors.T @ local_factors, axis)
 
     return gramian
 
@@ -195,7 +204,9 @@ def _local_bucket_solve(source, yty, row_ids, idx, val, mask, reg, alpha):
 #
 # ``mode="allgather"``  one tiled all-gather materializes the full (padded)
 #                       source table transiently per bucket — minimal FLOPs,
-#                       transient HBM = one full table.
+#                       transient HBM = one full table. (Resident buckets
+#                       assemble once a HALF-SWEEP instead: "the resident
+#                       dataflow" further down.)
 # ``mode="ring"``       the source shard rotates around the ring (ppermute);
 #                       each of the n phases accumulates the Gramian
 #                       correction and b-vector for the entries whose rows
@@ -499,6 +510,212 @@ def make_landing_flush(mesh: Mesh, axis: str = DATA_AXIS):
     return jax.jit(flush, donate_argnums=(0,))
 
 
+# --- the resident dataflow: every device solves its OWN rows -------------------
+#
+# ``mode="allgather"`` with resident buckets (``ImplicitALS(sharded="resident")``,
+# what ``train_als --mesh-devices n --sharded resident`` builds) is the ALX
+# layout proper. A half-sweep is
+#
+#   gramian   one (k, k) psum over the source shards (``sharded_gramian``);
+#   assemble  ONE all-gather of the source table, in the form the gather
+#             reads it (``ops.als.gather_table``), handed to every bucket
+#             program of the half-sweep — (n - 1) / n of the table into a
+#             chip, once, where a program that assembles inside every bucket
+#             moves it once a BUCKET (1,455 times a sweep at 10M x 1M);
+#   solve     one program a shape group: each device scans its own rows'
+#             buckets (``datasets.ragged.shard_grouped_bucket_rows``: slots
+#             ``[d * B, (d + 1) * B)`` of a bucket hold rows of shard ``d``
+#             under local row ids) with the one-chip sweep's own body
+#             (``ops.als.scan_group``) — warm starts read from its own shard
+#             of the target, no collective at all;
+#   land      one gather a half-sweep from ``concat(solved blocks, shard)``
+#             through the shard's landing permutation, as the fused one-chip
+#             fit lands (``ops.als.scan_half_sweep``): no solved row leaves
+#             its device.
+#
+# So a sweep's collective traffic into a chip is each table once and two
+# (k, k) psums (``collective_bytes_per_sweep``), and the host dispatches one
+# program a shape group and six more a sweep instead of one a bucket.
+
+
+def assembled_bytes_per_sweep(
+    n_users: int, n_items: int, rank: int, n_shards: int,
+    bucket_programs: tuple[int, int] | None = None, solver: str = "cholesky",
+) -> int:
+    """The PLAN of assembled float32 table bytes a chip holds new in one
+    sweep of a row-sharded all-gather fit, from the shapes alone. The
+    resident dataflow is to assemble each (row-padded) table ONCE — the user
+    table ahead of the item half-sweep, the item table ahead of the user
+    one: ``(users + items) * rank * 4``, the layout's need; what its
+    compiled programs did assemble is the fit report's counter of this name
+    (:func:`all_gather_bytes` of every executable a sweep called).
+    ``bucket_programs=(user buckets, item buckets)`` prices the dataflow
+    whose every bucket program assembles for itself (streamed buckets;
+    global buckets handed to ``ShardedALSFit.fit``): the source table once a
+    BUCKET, and under ``solver="cg"`` the target table beside it, for the
+    warm-start rows; there the plan is the report's counter too. The
+    benchmark's ``fit_sharded`` driver asks for it before it generates a
+    star: a program without one is refused."""
+    n_u, n_i = pad_rows(n_users, n_shards), pad_rows(n_items, n_shards)
+    if bucket_programs is None:
+        return (n_u + n_i) * rank * 4
+    user_buckets, item_buckets = bucket_programs
+    both = solver == "cg"
+    return (user_buckets * (n_i + both * n_u) + item_buckets * (n_u + both * n_i)) * rank * 4
+
+
+def collective_bytes_per_sweep(
+    rank: int, n_shards: int, table_bytes: int, landed_rows: int = 0
+) -> int:
+    """Bytes that arrive on a chip from the others in one sweep: the other
+    shards of ``table_bytes`` of assembled (or ring-passed) tables, of
+    ``landed_rows`` all-gathered solved rows with their row ids (none in the
+    resident dataflow: solved rows do not travel), and the two psums'
+    ``(k, k)`` partial Gramians."""
+    moved = table_bytes + landed_rows * (rank * 4 + 4)
+    return (moved // n_shards + 2 * rank * rank * 4) * (n_shards - 1)
+
+
+_ALL_GATHER = re.compile(r"= (.+?) all-gather(?:-start)?\(")
+_HLO_ARRAY = re.compile(r"\b([a-z]+)(\d*)\w*\[([\d,]*)\]")
+
+
+def all_gather_bytes(compiled) -> int:
+    """Bytes of all-gather results in one call of a compiled program, a
+    device: every ``all-gather`` (or asynchronous ``all-gather-start``, whose
+    result names the operand beside the gathered array: the larger counts)
+    of its optimized HLO, each counted once — so one inside a loop's body is
+    counted as a single call of it, which is enough to tell a program that
+    gathers a table from one that does not."""
+    return sum(
+        max(int(bits or 8) // 8 * math.prod(int(d) for d in dims.split(",") if d)
+            for _, bits, dims in _HLO_ARRAY.findall(result))
+        for result in _ALL_GATHER.findall(compiled.as_text())
+    )
+
+
+def _sds(shape, dtype, sharding) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def pad_rows(n_rows: int, n_shards: int) -> int:
+    """Rows of a table padded to a shard-count multiple."""
+    return shard_rows(n_rows, n_shards) * n_shards
+
+
+def _assemble_body(source_l, *, axis):
+    with jax.named_scope("als.shard.assemble"):
+        return gather_table(jax.lax.all_gather(source_l, axis, axis=0, tiled=True))
+
+
+def _local_group_body(
+    table, yty, target_l, row_ids_l, idx_l, val_l, mask_l, reg, alpha,
+    *, solver, cg_steps, gather_dtype,
+):
+    _, solved = scan_group(
+        table, yty, target_l, Bucket(row_ids_l, idx_l, val_l, mask_l),
+        reg, alpha, solver, cg_steps, gather_dtype,
+    )
+    return solved
+
+
+def _local_landing_body(target_l, landing_l, *solved_l):
+    with jax.named_scope("als.shard.land"):
+        return jnp.concatenate(list(solved_l) + [target_l])[landing_l]
+
+
+def make_assemble(mesh: Mesh, axis: str = DATA_AXIS):
+    """The half-sweep's one assembly: a row-sharded table in, the whole
+    table in the gather's form on every device out."""
+
+    def assemble(source):
+        # every device holds the same rows after a tiled all-gather, which
+        # the varying-axes check cannot see
+        return shard_map(
+            functools.partial(_assemble_body, axis=axis), mesh=mesh,
+            in_specs=P(axis, None), out_specs=P(), check_vma=False,
+        )(source)
+
+    return jax.jit(assemble)
+
+
+def make_local_solve(mesh: Mesh, axis: str = DATA_AXIS):
+    """One shape group's program: the assembled table, the Gramian and the
+    row-sharded target in, every device's solved ``(N * B, k)`` rows out
+    (row-sharded: device ``d``'s block is its own). No collective."""
+
+    def solve(table, yty, target, row_ids, idx, val, mask, reg, alpha,
+              solver="cholesky", cg_steps=3, gather_dtype=None):
+        body = functools.partial(
+            _local_group_body, solver=solver, cg_steps=cg_steps,
+            gather_dtype=gather_dtype,
+        )
+        return shard_map(
+            body, mesh=mesh,
+            in_specs=(
+                P(), P(), P(axis, None), P(None, axis), P(None, axis, None),
+                P(None, axis, None), P(None, axis, None), P(), P(),
+            ),
+            out_specs=P(axis, None),
+        )(table, yty, target, row_ids, idx, val, mask, reg, alpha)
+
+    return jax.jit(solve, static_argnames=("solver", "cg_steps", "gather_dtype"))
+
+
+def make_local_landing(mesh: Mesh, axis: str = DATA_AXIS):
+    """The half-sweep's one landing: every device gathers its new shard from
+    its own solved blocks and its old shard. No collective."""
+
+    def land(target, landing, *solved):
+        return shard_map(
+            _local_landing_body, mesh=mesh,
+            in_specs=(P(axis, None), P(axis)) + (P(axis, None),) * len(solved),
+            out_specs=P(axis, None),
+        )(target, landing, *solved)
+
+    return jax.jit(land)
+
+
+def make_relayout(mesh: Mesh, axis: str = DATA_AXIS):
+    """``table[rows]`` for a row-sharded table and a row-sharded permutation
+    of its rows, row-sharded again: between the logical row order and the
+    order the shards own rows in (``datasets.ragged.balanced_shards``). Rows
+    change shards, so this moves a table's worth across the mesh — once a
+    fit each way, never inside a sweep."""
+    rows2d = NamedSharding(mesh, P(axis, None))
+
+    def relayout(table, rows):
+        with jax.named_scope("als.shard.relayout"):
+            return table[rows]
+
+    return jax.jit(relayout, out_shardings=rows2d)
+
+
+def make_seeded_tables(mesh: Mesh, axis: str = DATA_AXIS):
+    """``ops.als.seeded_factors`` made ON the mesh: both tables row-sharded,
+    rows padded with zeros to a shard-count multiple (no bucket refers to
+    them) and laid out in the order ``user_rows`` / ``item_rows`` give
+    (``logical_of_phys`` of ``datasets.ragged.balanced_shards``). The draws
+    are the single-device path's, row for row (the threefry implementation
+    is partitionable: a value does not depend on the layout it is drawn
+    under)."""
+    n = int(mesh.shape[axis])
+    rows2d = NamedSharding(mesh, P(axis, None))
+
+    def seeded(key, user_rows, item_rows, n_users, n_items, rank):
+        tables = seeded_factors(key, n_users, n_items, rank)
+        with jax.named_scope("als.shard.relayout"):
+            return tuple(
+                jnp.pad(f, ((0, -f.shape[0] % n), (0, 0)))[rows]
+                for f, rows in zip(tables, (user_rows, item_rows))
+            )
+
+    return jax.jit(
+        seeded, static_argnames=("n_users", "n_items", "rank"),
+        out_shardings=(rows2d, rows2d),
+    )
+
+
 class _BucketPrefetcher:
     """Double-buffered background bucket uploader for the streamed pipelined
     half-sweep (the ALX host-feeding pattern, arXiv:2112.02194).
@@ -607,21 +824,25 @@ class _BucketPrefetcher:
 
 
 def _acquire_executable(
-    engine: "ShardedALSFit", fn, kind: str, args, stats: dict, shape_key: tuple
+    engine: "ShardedALSFit", fn, kind: str, args, stats: dict, shape_key: tuple,
+    statics: dict | None = None, timer=None,
 ):
     """Per-shape executable through the persistent AOT layer, memoized on
     the engine; ``kind`` names which of the sweep's programs this is
-    (update / solve / landsolve / flush) — each gets its own key space and
-    its own fingerprint-verified disk export. A module-level conduit
-    (forwards ``fn`` into ``persistent_aot_executable``) so graftlint R1
-    can prove every pipelined program reaches the AOT layer."""
+    (update / solve / landsolve / flush, and the resident dataflow's
+    assemble / local_solve / local_land / seeded) — each gets its own key
+    space and its own fingerprint-verified disk export. ``args`` may be
+    abstract (``jax.ShapeDtypeStruct`` with the argument's sharding): the
+    resident dataflow acquires every shape ahead of its first sweep, on
+    threads, before a table exists. A module-level conduit (forwards ``fn``
+    into ``persistent_aot_executable``) so graftlint R1 can prove every
+    sharded program reaches the AOT layer."""
     from albedo_tpu.utils.aot import persistent_aot_executable
 
     key = (kind,) + shape_key
     compiled = engine._executables.get(key)
     if compiled is None:
         dev = jax.devices()[0]
-        statics = None if kind == "flush" else engine._statics()
         compiled, c_s, tag = persistent_aot_executable(
             fn, args, None, statics,
             key_parts=(
@@ -630,9 +851,10 @@ def _acquire_executable(
                 repr(engine.mesh), engine.mode, engine.solver,
                 engine.cg_steps, engine.gather_dtype,
             ) + shape_key,
-            name=f"als_sharded_{kind}",
+            name=f"als_sharded_{kind}", timer=timer, span="fit.acquire",
         )
         engine._executables[key] = compiled
+        engine._gathered[key] = all_gather_bytes(compiled)
         stats["compile_s"] += c_s
         stats["compile_sources"].add(tag)
     return compiled
@@ -699,9 +921,20 @@ class ShardedALSFit:
         self._landsolve = make_pipelined_landsolve(mesh, axis, mode)
         self._flush = make_landing_flush(mesh, axis)
         self._gramian = sharded_gramian(mesh, axis)
+        self._assemble = make_assemble(mesh, axis)
+        self._local_solve = make_local_solve(mesh, axis)
+        self._local_land = make_local_landing(mesh, axis)
+        self._seeded = make_seeded_tables(mesh, axis)
+        self._relayout = make_relayout(mesh, axis)
         self._rows1d = row_sharded(mesh, axis)
         self._rows2d = NamedSharding(mesh, P(axis, None))
+        self._replicated = NamedSharding(mesh, P())
+        # a stacked shape group's arrays: the slot axis over the mesh
+        self._slots2d = NamedSharding(mesh, P(None, axis))
+        self._slots3d = NamedSharding(mesh, P(None, axis, None))
         self._executables: dict[tuple, object] = {}
+        self._gathered: dict[tuple, int] = {}   # all_gather_bytes of each of them
+        self._acquired_layouts: set[tuple] = set()   # (sizes, shapes) acquire_local has seen
 
     # ------------------------------------------------------------- layout
     def shard_table(self, factors) -> jax.Array:
@@ -732,7 +965,166 @@ class ShardedALSFit:
     def _run_bucket(self, source, yty, target, b: Bucket, reg, alpha, stats: dict):
         args = (source, yty, target, b.row_ids, b.idx, b.val, b.mask, reg, alpha)
         key = (source.shape[0], target.shape[0], tuple(b.idx.shape))
-        return _acquire_executable(self, self._update, "update", args, stats, key)(*args)
+        stats["dispatches"] += 1
+        return _acquire_executable(
+            self, self._update, "update", args, stats, key, self._statics())(*args)
+
+    # ------------------------------------------- the resident dataflow
+    def put_group(self, g: Bucket) -> tuple:
+        """Upload one own-rows shape group (``datasets.ragged.
+        shard_grouped_bucket_rows``), its slot axis over the mesh: every
+        device is sent its own rows' slots and nothing else."""
+        return (
+            jax.device_put(g.row_ids, self._slots2d),
+            jax.device_put(g.idx, self._slots3d),
+            jax.device_put(g.val, self._slots3d),
+            jax.device_put(g.mask, self._slots3d),
+        )
+
+    def put_rows(self, rows: np.ndarray) -> jax.Array:
+        """Upload one int32 entry a table row (``(n_shards * rows_per,)``: a
+        side's stacked per-shard landing permutations, or a permutation of
+        its rows), each shard's to its device."""
+        return jax.device_put(rows, self._rows1d)
+
+    def _local_programs(self, n_source: int, n_target: int, rank: int, shapes) -> list[tuple]:
+        """``(kind, program, abstract arguments, key, statics)`` of one
+        half-sweep of the resident dataflow: ``n_source`` / ``n_target``
+        padded table rows, ``shapes`` the target side's group shapes ``(N,
+        n_shards * B, L)``."""
+        f32, i32, sds = jnp.float32, jnp.int32, _sds
+        source = sds((n_source, rank), f32, self._rows2d)
+        table = sds(jax.eval_shape(gather_table, source).shape, f32, self._replicated)
+        yty = sds((rank, rank), f32, self._replicated)
+        target = sds((n_target, rank), f32, self._rows2d)
+        scalar = sds((), f32, self._replicated)
+        programs = [("assemble", self._assemble, (source,), (n_source, rank), None)]
+        solved = []
+        for shape in dict.fromkeys(tuple(shape) for shape in shapes):
+            n, slots, _ = shape
+            args = (table, yty, target, sds((n, slots), i32, self._slots2d),
+                    sds(shape, i32, self._slots3d), sds(shape, f32, self._slots3d),
+                    sds(shape, jnp.bool_, self._slots3d), scalar, scalar)
+            programs.append(("local_solve", self._local_solve, args,
+                             (n_source, n_target, rank, shape), self._statics()))
+        for n, slots, _ in shapes:
+            solved.append(sds((n * slots, rank), f32, self._rows2d))
+        landing = sds((n_target,), i32, self._rows1d)
+        programs.append(("local_land", self._local_land, (target, landing, *solved),
+                         (n_target, rank, tuple(tuple(shape) for shape in shapes)), None))
+        return programs
+
+    def acquire_local(self, n_users: int, n_items: int, rank: int, user_shapes, item_shapes,
+                      stats: dict, timer=None, workers: int = 1) -> None:
+        """Every executable of the resident dataflow for this layout, ahead
+        of the first sweep and side by side on ``workers`` threads (abstract
+        arguments: no table exists yet, so a probe's tables are the only
+        ones on the device). A second fit finds them on the engine."""
+        layout = (n_users, n_items, rank, tuple(user_shapes), tuple(item_shapes))
+        if layout in self._acquired_layouts:
+            return
+        n_u, n_i = pad_rows(n_users, self.n_shards), pad_rows(n_items, self.n_shards)
+        sds = _sds
+        rows = {n: sds((n,), jnp.int32, self._rows1d) for n in (n_u, n_i)}
+        programs = [("seeded", self._seeded,
+                     (sds((2,), jnp.uint32, self._replicated), rows[n_u], rows[n_i]),
+                     (n_users, n_items, rank), dict(n_users=n_users, n_items=n_items, rank=rank))]
+        programs += [("relayout", self._relayout,
+                      (sds((n, rank), jnp.float32, self._rows2d), rows[n]), (n, rank), None)
+                     for n in dict.fromkeys((n_u, n_i))]
+        programs += self._local_programs(n_u, n_i, rank, item_shapes)
+        programs += self._local_programs(n_i, n_u, rank, user_shapes)
+        missing = [p for p in programs if (p[0],) + p[3] not in self._executables]
+
+        def one(program) -> dict:
+            kind, fn, args, shape_key, statics = program
+            mine = {"compile_s": 0.0, "compile_sources": set()}   # a thread's own: no shared update
+            _acquire_executable(self, fn, kind, args, mine, shape_key, statics, timer)
+            return mine
+
+        with ThreadPoolExecutor(max_workers=max(1, workers)) as pool:
+            for mine in pool.map(one, missing):
+                stats["compile_s"] += mine["compile_s"]          # thread-seconds
+                stats["compile_sources"] |= mine["compile_sources"]
+        self._acquired_layouts.add(layout)
+
+    def seeded_tables(self, key, user_rows, item_rows, n_users: int, n_items: int,
+                      rank: int, stats: dict):
+        """The seeded first tables, made on the mesh under the row sharding,
+        in the row order ``user_rows`` / ``item_rows`` (:meth:`put_rows` of a
+        side's ``logical_of_phys``)."""
+        args = (jax.device_put(key, self._replicated), user_rows, item_rows)
+        return _acquire_executable(
+            self, self._seeded, "seeded", args, stats, (n_users, n_items, rank),
+            dict(n_users=n_users, n_items=n_items, rank=rank),
+        )(*args)
+
+    def relayout(self, table, rows, stats: dict):
+        """``table[rows]``, row-sharded (:func:`make_relayout`)."""
+        args = (table, rows)
+        return _acquire_executable(
+            self, self._relayout, "relayout", args, stats, tuple(table.shape),
+        )(*args)
+
+    def half_sweep_local(self, source, target, groups, landing, reg, alpha, stats, timer):
+        """One half-sweep of the resident dataflow (see the section comment
+        above): psum Gramian, ONE assembly of the source table, a program a
+        shape group, one landing. ``groups`` are device shape groups
+        (:meth:`put_group`), ``landing`` the target side's stacked per-shard
+        landing permutations. ``stats["assembled_bytes"]`` grows by what the
+        programs it calls all-gather (:func:`all_gather_bytes`, a call each)."""
+        SHARD_GATHER_FAULT.hit()
+        SHARD_COLLECTIVE_FAULT.hit()
+        rank = int(source.shape[1])
+        n_source, n_target = int(source.shape[0]), int(target.shape[0])
+        with timer.section("fit.shard"):
+            with timer.section("fit.shard.gramian"):
+                yty = self._gramian(source)
+            with timer.section("fit.shard.assemble"):
+                key = (n_source, rank)
+                table = _acquire_executable(
+                    self, self._assemble, "assemble", (source,), stats, key,
+                )(source)
+                gathered = self._gathered[("assemble", *key)]
+            with timer.section("fit.shard.dispatch"):
+                solved = []
+                for g in groups:
+                    args = (table, yty, target, *g, reg, alpha)
+                    key = (n_source, n_target, rank, tuple(g[1].shape))
+                    solved.append(_acquire_executable(
+                        self, self._local_solve, "local_solve", args, stats, key, self._statics(),
+                    )(*args))
+                    gathered += self._gathered[("local_solve", *key)]
+                args = (target, landing, *solved)
+                key = (n_target, rank, tuple(tuple(g[1].shape) for g in groups))
+                target = _acquire_executable(
+                    self, self._local_land, "local_land", args, stats, key,
+                )(*args)
+                gathered += self._gathered[("local_land", *key)]
+        stats["assembled_bytes"] += gathered
+        stats["dispatches"] += len(groups) + 3
+        return target
+
+    def fit_local(self, user_sh, item_sh, user_groups, item_groups, user_landing,
+                  item_landing, reg: float, alpha: float, n_iter: int, stats: dict,
+                  timer, after_sweep=None):
+        """``n_iter`` sweeps of the resident dataflow over row-sharded tables
+        (:meth:`seeded_tables` / :meth:`shard_table`) in the row order the
+        groups were bucketed in; returns them as they are: row-sharded, rows
+        padded to the shard count, in that order (:meth:`relayout` brings
+        the logical order back). ``after_sweep(it, user_sh, item_sh)`` runs
+        between sweeps."""
+        reg_arr = jax.device_put(np.float32(reg), self._replicated)
+        alpha_arr = jax.device_put(np.float32(alpha), self._replicated)
+        for it in range(int(n_iter)):
+            # MLlib order: item factors first (from users), then users.
+            item_sh = self.half_sweep_local(
+                user_sh, item_sh, item_groups, item_landing, reg_arr, alpha_arr, stats, timer)
+            user_sh = self.half_sweep_local(
+                item_sh, user_sh, user_groups, user_landing, reg_arr, alpha_arr, stats, timer)
+            if after_sweep is not None:
+                after_sweep(it, user_sh, item_sh)
+        return user_sh, item_sh
 
     def half_sweep(self, source, target, buckets, reg, alpha, stats,
                    streamed=False, pipelined=False):
@@ -772,12 +1164,13 @@ class ShardedALSFit:
         def run(device_buckets):
             nonlocal target, pending
             for b in device_buckets:
+                stats["dispatches"] += 1
                 if pending is None:
                     args = (source, yty, target, b.row_ids, b.idx, b.val,
                             b.mask, reg, alpha)
                     key = (source.shape[0], target.shape[0], tuple(b.idx.shape))
                     solved = _acquire_executable(
-                        self, self._solve, "solve", args, stats, key
+                        self, self._solve, "solve", args, stats, key, self._statics()
                     )(*args)
                 else:
                     prev_rows, prev_solved = pending
@@ -788,7 +1181,7 @@ class ShardedALSFit:
                         tuple(b.idx.shape), int(prev_rows.shape[0]),
                     )
                     target, solved = _acquire_executable(
-                        self, self._landsolve, "landsolve", args, stats, key
+                        self, self._landsolve, "landsolve", args, stats, key, self._statics()
                     )(*args)
                 pending = (b.row_ids, solved)
 
@@ -802,6 +1195,7 @@ class ShardedALSFit:
         else:
             run(buckets)
         if pending is not None:
+            stats["dispatches"] += 1
             rows, solved = pending
             args = (target, rows, solved)
             key = (target.shape[0], int(rows.shape[0]))
@@ -847,7 +1241,7 @@ class ShardedALSFit:
         stats = {
             "compile_s": 0.0, "compile_sources": set(),
             "streamed_buckets": 0, "upload_s": 0.0,
-            "prefetch_wait_s": 0.0, "pipelined": pipelined,
+            "prefetch_wait_s": 0.0, "pipelined": pipelined, "dispatches": 0,
         }
         user_sh = self.shard_table(user_f)
         item_sh = self.shard_table(item_f)

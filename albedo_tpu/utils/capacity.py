@@ -421,21 +421,45 @@ def plan_fit_sharded(
     """Price the fully sharded ALS fit (ALX layout), PER DEVICE.
 
     Resident: 1/n of BOTH row-sharded factor tables, plus (non-streamed)
-    1/n of every bucket slab. Streamed mode keeps only the in-flight bucket
-    slab shards on device — the star matrix is never device-resident whole:
-    under the default PIPELINED dataflow the double-buffered prefetch holds
-    **two** bucket slabs at once (the one being solved plus the one the
-    background uploader just landed), priced as the worst same-side pair of
-    slab shards — both in-flight buckets always belong to one half-sweep;
-    ``pipelined=False`` is the synchronous dataflow's single slab — which is
-    why the admission ladder can pick unpipelined-streamed as a cheaper rung
-    below pipelined-streamed. Transient, per bucket: the assembled source
-    factors — the FULL (padded) table under ``mode="allgather"``, a
-    double-buffered 1/n shard ring slot under ``mode="ring"`` — plus the
-    local gathered block, its Gramian correction, and the all-gathered
-    solved rows of the bucket. The CG solver additionally all-gathers the
-    target table for its warm-start rows, so its transient prices BOTH
-    tables under all-gather.
+    1/n of every bucket slab.
+
+    **Resident buckets under** ``mode="allgather"`` (the dataflow of
+    ``parallel/als.py``, "the resident dataflow": every device solves its
+    OWN rows) price one half-sweep's transient as it is held: the source
+    table assembled ONCE, whole, in float32 (``assembled_source_table``: the
+    larger side's, or the smaller's where the other half-sweep's landing
+    outweighs it) and beside it the largest of three things that are not
+    live at once: the copy of that table the assembly's program holds while
+    it runs (``assembly_copy``: compiled for a v5e the program is an
+    all-gather into a temporary and a copy into its result, temporary =
+    result = the table; the seeded draw and the relayouts hold a whole
+    table as a temporary too), one bucket's gathered block and Gramian
+    correction at its full slot count (``bucket_in_flight``: a device's own
+    rows fill a bucket as a chip's do), and the landing — the target side's
+    solved blocks, its shard, their concatenation and the new shard
+    (``landing_pool``). No target table is assembled for the CG warm start
+    (rows are read from the device's own shard) and no solved row travels.
+    ``bucket_shapes_*`` are the planner's global shapes: a shard's own are
+    the same tiers, a few slots apart. At 10M x 1M, rank 128, four chips the
+    price is 12.20 GB a chip; the chips' allocators peaked at 9.56 - 10.73 GB
+    (PERF.md section 4), over the 7.99 GB that a plan without the copy gave.
+
+    **Streamed buckets, and the ring:** streamed mode keeps only the
+    in-flight bucket slab shards on device — the star matrix is never
+    device-resident whole: under the default PIPELINED dataflow the
+    double-buffered prefetch holds **two** bucket slabs at once (the one
+    being solved plus the one the background uploader just landed), priced
+    as the worst same-side pair of slab shards — both in-flight buckets
+    always belong to one half-sweep; ``pipelined=False`` is the synchronous
+    dataflow's single slab — which is why the admission ladder can pick
+    unpipelined-streamed as a cheaper rung below pipelined-streamed.
+    Transient, per bucket (these programs assemble inside every bucket's
+    program): the assembled source factors — the FULL (padded) table under
+    ``mode="allgather"``, a double-buffered 1/n shard ring slot under
+    ``mode="ring"`` — plus the local gathered block, its Gramian
+    correction, and the all-gathered solved rows of the bucket. There the
+    CG solver additionally all-gathers the target table for its warm-start
+    rows, so its transient prices BOTH tables under all-gather.
     """
     gb = _dtype_bytes(gather_dtype)
     n = max(1, int(n_devices))
@@ -479,6 +503,9 @@ def plan_fit_sharded(
         "factor_table_shards": tables,
         "transient_assembly": transient,
     }
+    if not streamed and mode == "allgather":
+        items = {"factor_table_shards": tables, **_resident_sharded_transient(
+            bucket_shapes_user, bucket_shapes_item, u_pad, i_pad, rank, gb, n)}
     workload = "als_fit_sharded"
     if streamed and pipelined:
         # Double-buffered prefetch: the bucket being solved + the one the
@@ -491,6 +518,34 @@ def plan_fit_sharded(
     else:
         items["bucket_slab_shards"] = slabs
     return CapacityPlan(workload=workload, items=items)
+
+
+def _resident_sharded_transient(shapes_user, shapes_item, u_pad, i_pad, rank, gb, n) -> dict:
+    """The larger half-sweep's transient of the resident row-sharded
+    dataflow, by item (``plan_fit_sharded`` says what each is)."""
+    sides = []
+    for shapes, src_rows, tgt_rows in ((shapes_user, i_pad, u_pad), (shapes_item, u_pad, i_pad)):
+        # A shard holds 1/n of a length tier's rows, in buckets as full as
+        # the planner's: its largest has the tier's slots over n, or a whole
+        # bucket's where the tier has more.
+        tiers: dict[int, list[int]] = {}
+        for b, ln in shapes:
+            tiers.setdefault(ln, []).append(b)
+        block = max(
+            (min(max(bs), -(-sum(bs) // n)) * (ln * (rank * gb + gb) + rank * rank * 4)
+             for ln, bs in tiers.items()),
+            default=0,
+        )
+        # the solved blocks, their concatenation with the old shard (which
+        # ``factor_table_shards`` holds), and the new shard
+        pool = 2 * (sum(b for b, _ in shapes) // n + tgt_rows // n) * rank * 4
+        # the assembly's program holds the table twice while it runs
+        copy = src_rows * rank * 4
+        # (the copy, a bucket's block and the landing are not live at once)
+        beside = {"assembly_copy": copy, "bucket_in_flight": block, "landing_pool": pool}
+        largest = max(beside, key=beside.get)
+        sides.append({"assembled_source_table": src_rows * rank * 4, largest: beside[largest]})
+    return max(sides, key=lambda side: sum(side.values()))
 
 
 def plan_fit_chunked(

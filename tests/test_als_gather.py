@@ -263,12 +263,22 @@ def fit(kwargs, matrix):
 
 def share_by_hand(matrix, est, n_shards=1) -> float:
     """Padded entries of the buckets whose flat count is in the slow form and
-    has a faster one within an eighth more slots, over all padded entries."""
+    has a faster one within an eighth more slots, over all padded entries.
+    On a mesh (``n_shards``) every device buckets its own rows — dealt to
+    the shards in turn by length — and a bucket takes the slot count of the
+    shard with most rows in it."""
     reformed = total = 0
     for indptr in (matrix.csr()[0], matrix.csc()[0]):
-        for plan in plan_buckets(indptr, batch_size=est.batch_size, max_entries=est.max_entries):
-            n_slots, length = plan.shape
-            local = -(-n_slots // n_shards)
+        lengths = np.sort(np.diff(indptr), kind="stable")
+        tiers = {}
+        for d in range(n_shards):
+            mine = np.concatenate([[0], np.cumsum(lengths[d::n_shards])])
+            plans = plan_buckets(mine, batch_size=est.batch_size, max_entries=est.max_entries)
+            for j, plan in enumerate(plans):
+                nth = sum(p.shape[1] == plan.shape[1] for p in plans[:j])
+                key = (plan.shape[1], nth)
+                tiers[key] = max(tiers.get(key, 0), plan.shape[0])
+        for (length, _), local in tiers.items():
             total += local * length
             grows = not fast(local * length) and any(
                 fast(s * length) for s in range(local + 1, local + local // 8 + 1))

@@ -209,23 +209,65 @@ class TestShardedPlans:
 
     def test_ring_transient_below_allgather(self):
         # Ring never materializes a full table: at large table sizes its
-        # per-device transient is a fraction of the all-gather mode's.
+        # per-device transient is a fraction of the all-gather mode's
+        # (streamed buckets: each bucket's program assembles for itself).
         ag = capacity.plan_fit_sharded(
-            self.SHAPES, self.SHAPES, 10**6, 10**5, 32, 8, mode="allgather"
+            self.SHAPES, self.SHAPES, 10**6, 10**5, 32, 8, mode="allgather", streamed=True
         )
         ring = capacity.plan_fit_sharded(
-            self.SHAPES, self.SHAPES, 10**6, 10**5, 32, 8, mode="ring"
+            self.SHAPES, self.SHAPES, 10**6, 10**5, 32, 8, mode="ring", streamed=True
         )
         assert ring.items["transient_assembly"] < ag.items["transient_assembly"]
 
     def test_cg_prices_the_target_assembly_too(self):
+        """Where a bucket's program assembles for itself (streamed buckets)."""
         chol = capacity.plan_fit_sharded(
-            self.SHAPES, self.SHAPES, 10**5, 10**5, 32, 8, solver="cholesky"
+            self.SHAPES, self.SHAPES, 10**5, 10**5, 32, 8, solver="cholesky", streamed=True
         )
         cg = capacity.plan_fit_sharded(
-            self.SHAPES, self.SHAPES, 10**5, 10**5, 32, 8, solver="cg"
+            self.SHAPES, self.SHAPES, 10**5, 10**5, 32, 8, solver="cg", streamed=True
         )
         assert cg.items["transient_assembly"] > chol.items["transient_assembly"]
+
+    @pytest.mark.parametrize("solver", ["cholesky", "cg"])
+    def test_resident_allgather_prices_one_assembled_table_and_no_target(self, solver):
+        """The resident dataflow (every device solves its own rows): the
+        source table assembled once, whole, in float32 — the larger side's,
+        where a bucket's block outweighs the other half-sweep's landing —
+        and nothing for the CG warm start, which reads the device's own
+        shard."""
+        plan = capacity.plan_fit_sharded(
+            self.SHAPES, self.SHAPES, 10**6, 10**5, 32, 8, solver=solver)
+        assert plan.workload == "als_fit_sharded"
+        assert "transient_assembly" not in plan.items
+        assert plan.items["assembled_source_table"] == 10**6 * 32 * 4
+        # ... and once more: the assembly's program holds its result twice
+        assert plan.items["assembly_copy"] == 10**6 * 32 * 4
+        assert set(plan.items) == {"factor_table_shards", "assembled_source_table",
+                                   "assembly_copy", "bucket_slab_shards"}
+        assert plan.items["factor_table_shards"] == (10**6 + 10**5) * 32 * 4 // 8
+        other = capacity.plan_fit_sharded(
+            self.SHAPES, self.SHAPES, 10**6, 10**5, 32, 8,
+            solver="cg" if solver == "cholesky" else "cholesky")
+        assert plan.required_bytes == other.required_bytes
+        # the ring's resident buckets still go bucket by bucket
+        ring = capacity.plan_fit_sharded(self.SHAPES, self.SHAPES, 10**6, 10**5, 32, 8, mode="ring")
+        assert "transient_assembly" in ring.items
+
+    def test_the_resident_price_covers_what_the_chips_held_at_10m_by_1m(self):
+        """gh10m-r128-x4 (PERF.md section 4): four v5e peaked at 9.56 GB
+        traced and 10.21 - 10.73 GB untraced; without the assembly's copy the
+        plan read 7.99 GB. One bucket of the largest tier a side stands for
+        the shapes: the copy outweighs any bucket's block."""
+        plan = capacity.plan_fit_sharded(
+            [(8192, 256)], [(16, 131072)], 10**7, 10**6, 128, 4, solver="cg")
+        assert plan.items["assembled_source_table"] == plan.items["assembly_copy"] == 5_120_000_000
+        assert plan.items["factor_table_shards"] == 1_408_000_000
+        assert plan.required_bytes > 10_727_319_040          # the fullest chip, untraced
+        # a side whose block outweighs the table's copy is priced by the block
+        small = capacity.plan_fit_sharded(
+            [(8192, 256)], [(16, 131072)], 4000, 2000, 128, 4, solver="cg")
+        assert "bucket_in_flight" in small.items and "assembly_copy" not in small.items
 
     def test_mesh_resident_divides_slabs_not_tables(self):
         one = capacity.plan_fit(self.SHAPES, self.SHAPES, 4000, 2000, 16)

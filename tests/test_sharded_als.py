@@ -264,9 +264,13 @@ class TestAdmissionLadder:
         assert rep["capacity"]["chosen"] == "als_fit_sharded"
         _parity(model, reference)
 
-    def test_tighter_budget_degrades_to_streamed(
-        self, mesh8, matrix, reference, monkeypatch
-    ):
+    def test_tighter_budget_degrades_to_streamed(self, mesh8, monkeypatch):
+        # A matrix whose slabs outweigh a streamed bucket's transient (its
+        # program assembles the source table and all-gathers its solved
+        # rows; the resident dataflow holds every slab shard but assembles
+        # once a half-sweep and lands locally).
+        matrix = synthetic_stars(n_users=64, n_items=48, mean_stars=24, seed=3)
+        reference = ImplicitALS(**KW, chunked=False).fit(matrix)
         est = ImplicitALS(**KW, mesh=mesh8)
         _, sharded, streamed = self._plans(matrix, est)
         monkeypatch.setenv("ALBEDO_MEM_HEADROOM", "1.0")
@@ -303,3 +307,206 @@ class TestAdmissionLadder:
         assert rep["mode"] == "sharded"
         assert "injected" in rep["capacity"]["detail"]
         _parity(model, reference)
+
+
+class TestResidentDataflow:
+    """``sharded="resident"`` under ``shard_mode="allgather"`` (what ``train_als
+    --mesh-devices 4 --sharded resident`` builds), on four of the eight
+    virtual devices: every device solves its own rows against the source
+    table assembled ONCE a half-sweep."""
+
+    RANK = 16
+    # the eight numbers of benchmark/compare.py; f32 throughout on the CPU
+    LIMITS = dict.fromkeys(
+        (f"{side}_{n}" for side in ("user", "item")
+         for n in ("rows_worst", "rows_p99", "rows_median", "all_rows_worst")), 1e-4)
+
+    @pytest.fixture(scope="class")
+    def mesh4(self):
+        return make_mesh(4)
+
+    @pytest.fixture(scope="class")
+    def fitted(self, mesh4):
+        m = synthetic_stars(n_users=202, n_items=131, mean_stars=9, seed=5)
+        est = ImplicitALS(rank=self.RANK, max_iter=2, batch_size=32, seed=11, solver="cg",
+                          cg_steps=3, mesh=mesh4, sharded="resident", shard_mode="allgather")
+        return m, est, est.fit(m)
+
+    def test_matches_the_plain_reference_on_seeded_tables_and_the_bf16_control_does_not(self, fitted):
+        """Against ``benchmark/reference/als_cg.py`` from the same seed, by
+        the benchmark's own eight numbers: the program inside limits that
+        the reference computed in bfloat16 fails."""
+        import jax.numpy as jnp
+
+        from benchmark import compare
+        from benchmark.manifest import load_module
+
+        m, est, model = fitted
+        reference = load_module("reference", "als_cg")
+        stars = {"rows": m.rows, "cols": m.cols, "vals": m.vals,
+                 "n_users": m.n_users, "n_items": m.n_items}
+        config = {"rank": est.rank, "reg_param": est.reg_param, "alpha": est.alpha,
+                  "cg_steps": est.cg_steps}
+        want = reference.fit(stars, config, est.seed, est.max_iter)
+        ok, compared = compare.judge(compare.compare_fit(
+            model.user_factors, model.item_factors, *want, stars, min_stars=4), self.LIMITS)
+        assert ok, compared
+        control = reference.fit(stars, config, est.seed, est.max_iter, jnp.bfloat16)
+        ok, compared = compare.judge(compare.compare_fit(
+            np.asarray(control[0], np.float32), np.asarray(control[1], np.float32), *want,
+            stars, min_stars=4), self.LIMITS)
+        assert not ok and compared["user_rows_median"]["value"] > 10 * self.LIMITS["user_rows_median"]
+
+    def test_a_half_sweep_gathers_the_source_table_once_and_the_target_never(self, fitted, mesh4):
+        """The lowered programs of one CG half-sweep: ONE all-gather, of the
+        source table's shard, in the assembly; one all-reduce, the (k, k)
+        psum; none in any bucket program or in the landing. The per-bucket
+        program of the streamed rungs still gathers both tables in every
+        bucket — what this dataflow replaced."""
+        import re
+
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from albedo_tpu.parallel.als import (
+            assembled_bytes_per_sweep, collective_bytes_per_sweep, pad_rows, sharded_fit_engine)
+
+        m, est, _ = fitted
+        rep = est.last_fit_report
+        engine = sharded_fit_engine(mesh4, "data", "cg", 3, None, "allgather")
+        n_u, n_i = pad_rows(m.n_users, 4), pad_rows(m.n_items, 4)
+        ug, ig = est._device_groups_sharded(m, None, engine)[:2]
+
+        def collectives(fn, args, statics=None):
+            text = fn.lower(*args, **(statics or {})).as_text()
+            return {op: re.findall(rf"stablehlo\.{op}\b.*", text)
+                    for op in ("all_gather", "all_reduce", "all_to_all", "collective_permute",
+                               "reduce_scatter")}
+
+        for n_source, n_target, groups in ((n_u, n_i, ig), (n_i, n_u, ug)):
+            programs = engine._local_programs(
+                n_source, n_target, self.RANK, [tuple(g[1].shape) for g in groups])
+            assert [p[0] for p in programs] == (
+                ["assemble"] + ["local_solve"] * (len(programs) - 2) + ["local_land"])
+            for kind, fn, args, _, statics in programs:
+                found = collectives(fn, args, statics)
+                count = {op: len(lines) for op, lines in found.items()}
+                if kind == "assemble":
+                    assert count == {"all_gather": 1, "all_reduce": 0, "all_to_all": 0,
+                                     "collective_permute": 0, "reduce_scatter": 0}
+                    # the source's shard in, the whole table out
+                    assert f"tensor<{n_source // 4}x{self.RANK}xf32>" in found["all_gather"][0]
+                    assert f"tensor<{n_source}x{self.RANK}xf32>" in found["all_gather"][0]
+                else:
+                    assert not any(count.values()), (kind, count)
+            source = jax.ShapeDtypeStruct((n_source, self.RANK), jnp.float32,
+                                          sharding=NamedSharding(mesh4, P("data", None)))
+            count = {op: len(lines) for op, lines in collectives(engine._gramian, (source,)).items()}
+            assert count["all_reduce"] == 1 and sum(count.values()) == 1
+
+        # the fit's counter is read off the compiled programs its sweeps called,
+        # and meets the plan from the shapes
+        assert engine._gathered[("assemble", n_u, self.RANK)] == n_u * self.RANK * 4
+        assert {key[0]: b for key, b in engine._gathered.items() if key[0].startswith("local")} == {
+            "local_solve": 0, "local_land": 0}
+        assert rep["assembled_bytes_per_sweep"] == (n_u + n_i) * self.RANK * 4
+        assert rep["assembled_bytes_per_sweep"] == assembled_bytes_per_sweep(
+            m.n_users, m.n_items, self.RANK, 4)
+        assert assembled_bytes_per_sweep(200, 132, self.RANK, 4) == (200 + 132) * self.RANK * 4
+        assert rep["collective_bytes_per_sweep"] == collective_bytes_per_sweep(
+            self.RANK, 4, rep["assembled_bytes_per_sweep"])
+        assert rep["collective_bytes_per_sweep"] == (
+            (n_u + n_i) * self.RANK * 4 // 4 + 2 * self.RANK**2 * 4) * 3
+        assert rep["dispatches"] == est.max_iter * (len(ug) + len(ig) + 6)
+        assert rep["shard_padded_entries"] == sum(
+            g[1].shape[0] * (g[1].shape[1] // 4) * g[1].shape[2] for g in (*ug, *ig))
+
+        # the program a bucket of the streamed rungs still runs
+        b = est._host_buckets(m)[0][0]
+        rows = 4 * -(-b.row_ids.shape[0] // 4)
+        sds = jax.ShapeDtypeStruct
+        args = (sds((n_i, self.RANK), jnp.float32), sds((self.RANK, self.RANK), jnp.float32),
+                sds((n_u, self.RANK), jnp.float32), sds((rows,), jnp.int32),
+                sds((rows, b.shape[1]), jnp.int32), sds((rows, b.shape[1]), jnp.float32),
+                sds((rows, b.shape[1]), jnp.bool_), sds((), jnp.float32), sds((), jnp.float32))
+        per_bucket = collectives(engine._update, args, engine._statics())["all_gather"]
+        assert sum(f"tensor<{n}x{self.RANK}xf32>" in line for line in per_bucket
+                   for n in (n_i, n_u)) == 2      # source and, for the CG warm start, target
+        streamed = ImplicitALS(rank=self.RANK, max_iter=1, batch_size=32, seed=11, solver="cg",
+                               mesh=mesh4, sharded="streamed_sync")
+        streamed.fit(m)
+        n_buckets = sum(len(side) for side in est._host_buckets(m))
+        assert streamed.last_fit_report["assembled_bytes_per_sweep"] == assembled_bytes_per_sweep(
+            m.n_users, m.n_items, self.RANK, 4,
+            tuple(len(side) for side in est._host_buckets(m)), "cg")
+        assert streamed.last_fit_report["assembled_bytes_per_sweep"] > (
+            n_buckets // 2) * rep["assembled_bytes_per_sweep"]
+
+    @pytest.mark.parametrize("line, want", [
+        # a v5e's compiler, the assembly of gh10m-r128-x4's user table (PR 33)
+        ("  %all-gather.4 = f32[10000000,128]{1,0:T(8,128)} all-gather(%param.1), channel_id=1, "
+         "replica_groups={{0,1,2,3}}, dimensions={0}, use_global_device_ids=true", 5_120_000_000),
+        # the asynchronous pair: the start's result names operand and result
+        ("  %ags = (bf16[250,64]{1,0}, bf16[1000,64]{1,0}) all-gather-start(%p), dimensions={0}\n"
+         "  %agd = bf16[1000,64]{1,0} all-gather-done(%ags)", 128_000),
+        ("  %x = s32[8]{0} all-gather(%i), dimensions={0}\n  %y = f32[] all-reduce(%z)\n"
+         "  %m = pred[4,16]{1,0} all-gather(%b), dimensions={0}\n"
+         "  %q = f8e4m3fn[4,16]{1,0} all-gather(%c), dimensions={0}", 32 + 64 + 64),
+        ("  ROOT %copy.3 = f32[1000,128]{1,0} copy(%fusion)", 0),
+    ], ids=["tpu", "async", "narrow-types-beside-a-psum", "none"])
+    def test_all_gather_bytes_reads_the_optimized_hlo(self, line, want):
+        from albedo_tpu.parallel.als import all_gather_bytes
+
+        class Compiled:
+            def as_text(self):
+                return f"HloModule jit_x\nENTRY %main {{\n{line}\n}}\n"
+
+        assert all_gather_bytes(Compiled()) == want
+
+    def test_rows_the_shards_do_not_divide_come_back_trimmed_with_no_whole_table_gather(self, fitted):
+        """202 and 131 rows on four shards: the model's raw tables stay
+        row-sharded, rows padded (51 and 33 a device), and the host copies
+        are the logical rows."""
+        m, est, model = fitted
+        single = ImplicitALS(rank=self.RANK, max_iter=2, batch_size=32, seed=11, solver="cg",
+                             chunked=False).fit(m)
+        for raw, host, want, n in ((model._uf_raw, model.user_factors, single.user_factors, 202),
+                                   (model._vf_raw, model.item_factors, single.item_factors, 131)):
+            per = -(-n // 4)
+            assert raw.shape == (4 * per, self.RANK) and not raw.sharding.is_fully_replicated
+            assert {s.data.shape for s in raw.addressable_shards} == {(per, self.RANK)}
+            assert host.shape == (n, self.RANK)
+            np.testing.assert_allclose(host, want, atol=ATOL)
+            np.testing.assert_array_equal(np.asarray(raw)[n:], 0.0)       # the padding rows
+        assert (model.n_users, model.n_items) == (202, 131)
+        # serving cuts its device copies when it asks for them
+        assert [f.shape[0] for f in model.device_factors()] == [202, 131]
+        scores, items = model.recommend(np.array([0, 201]), k=5)
+        assert items.max() < 131 and np.isfinite(scores).all()
+        with pytest.raises(IndexError):
+            model.recommend(np.array([202]), k=5)
+
+    def test_the_layout_is_the_degree_sequences_alone(self, mesh4):
+        """Rows dealt to the shards by length: two matrices with the same
+        row lengths on other rows bucket to the same shapes (one set of
+        executables, the same work), and every shard's padded entries are
+        within one row's of the others'."""
+        from albedo_tpu.datasets.ragged import balanced_shards, shard_grouped_bucket_rows
+
+        rng = np.random.default_rng(3)
+        lengths = np.minimum(rng.zipf(1.6, 300), 90)
+        shapes, per_shard = [], []
+        for seed in (1, 2):
+            mine = np.random.default_rng(seed).permutation(lengths)
+            indptr = np.concatenate([[0], np.cumsum(mine)])
+            phys_of_logical, logical_of_phys = balanced_shards(indptr, 4)
+            np.testing.assert_array_equal(logical_of_phys[phys_of_logical], np.arange(300))
+            groups = shard_grouped_bucket_rows(
+                indptr, np.zeros(indptr[-1], np.int32), np.ones(indptr[-1], np.float32),
+                logical_of_phys, 4, batch_size=16, max_entries=512)
+            shapes.append([g.idx.shape for g in groups])
+            per_shard.append([sum(int(g.mask[:, d * (g.idx.shape[1] // 4):(d + 1) * (g.idx.shape[1] // 4)].sum())
+                                  for g in groups) for d in range(4)])
+        assert shapes[0] == shapes[1]
+        assert sum(per_shard[0]) == sum(per_shard[1]) == lengths.sum()
+        assert max(per_shard[0]) - min(per_shard[0]) <= lengths.max()
