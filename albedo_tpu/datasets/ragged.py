@@ -98,6 +98,15 @@ def _slot_tier(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
 
+def rows_allowed(length: int, batch_size: int, max_entries: int | None) -> int:
+    """Rows one bucket of padded length ``length`` may hold: ``batch_size``,
+    or as many as keep its flat count at ``max_entries`` or under. The one
+    allowance :func:`plan_buckets` and :func:`coalesce_buckets` chunk by."""
+    if max_entries is None:
+        return batch_size
+    return max(1, min(batch_size, max_entries // max(1, length)))
+
+
 def coalesce_buckets(
     buckets,
     batch_size: int = 1024,
@@ -143,9 +152,7 @@ def coalesce_buckets(
 
     for bk in buckets:
         length = int(bk.idx.shape[1])
-        allowed = batch_size
-        if max_entries is not None:
-            allowed = max(1, min(batch_size, max_entries // max(1, length)))
+        allowed = rows_allowed(length, batch_size, max_entries)
         valid = int((bk.row_ids >= 0).sum())  # fills front-pack valid rows
         if length not in pending and valid == bk.row_ids.shape[0] == allowed:
             yield bk  # already a full canonical bucket: pass through, no copy
@@ -174,10 +181,14 @@ def coalesce_buckets(
         n = parts[0].shape[0]
         if not n:
             continue
-        allowed = batch_size
-        if max_entries is not None:
-            allowed = max(1, min(batch_size, max_entries // max(1, length)))
-        yield build(parts, 0, n, length, allowed)
+        yield build(parts, 0, n, length, rows_allowed(length, batch_size, max_entries))
+
+
+def slot_tier_floor(n: int) -> int:
+    """The largest slot count up to ``n`` that :func:`_slot_tier` leaves as
+    it is (``n`` >= 1): what a bucket filled to the brim should hold, so that
+    a length tier has two shapes at most, the full bucket and the remainder."""
+    return n // 1024 * 1024 if n >= 1024 else 1 << (n.bit_length() - 1)
 
 
 def plan_buckets(
@@ -186,6 +197,7 @@ def plan_buckets(
     len_multiple: int = 8,
     max_len: int | None = None,
     max_entries: int | None = None,
+    rows_of: Callable[[int], int] | None = None,
 ) -> list[BucketPlan]:
     """Chunk CSR rows into fixed-shape bucket layouts (no fills yet).
 
@@ -195,6 +207,12 @@ def plan_buckets(
     downstream ``(B, L, rank)`` factor gather fits in device memory. Empty
     rows are skipped: ALS leaves those factors at their current value,
     matching cold-start behavior.
+
+    ``rows_of(L)`` stands in for :func:`rows_allowed` where a path has a row
+    allowance of its own for buckets of padded length ``L`` (the chunked
+    fit's, ``models.als.ImplicitALS._dispatch_rows``): the same tiers, the
+    same rows in the same order, each at the pad width it had, chunked at
+    another count.
     """
     lengths = np.diff(indptr)
     nonempty = np.nonzero(lengths > 0)[0]
@@ -221,12 +239,12 @@ def plan_buckets(
         # the rows actually present (next power of two), so a tail bucket of a
         # few very long rows doesn't burn batch_size slots of padding.
         pad_l = tier(int(eff[start]))
-        allowed = batch_size
-        if max_entries is not None:
-            allowed = max(1, min(batch_size, max_entries // pad_l))
-        end = start
-        while end < n_rows and end - start < allowed and eff[end] <= pad_l:
-            end += 1
+        allowed = (
+            rows_allowed(pad_l, batch_size, max_entries) if rows_of is None
+            else rows_of(pad_l)
+        )
+        # (eff ascends: the tier ends at the first longer row)
+        end = min(start + allowed, int(np.searchsorted(eff, pad_l, side="right")))
         n_take = end - start
         # Slot-count tiers (`_slot_tier`, ONE definition — the streaming
         # coalescer re-quantizes merged buckets through the same rule):
@@ -288,6 +306,7 @@ def bucket_rows(
     max_len: int | None = None,
     max_entries: int | None = None,
     workers: int | None = None,
+    rows_of: Callable[[int], int] | None = None,
 ) -> list[Bucket]:
     """Chunk CSR rows into fixed-shape padded batches (plan + fill).
 
@@ -298,11 +317,12 @@ def bucket_rows(
     With ``workers`` > 1 the per-bucket scatter fills run on a thread pool
     (they are pure NumPy and release the GIL); the bucket list is returned in
     plan order either way, so the output is byte-identical to the sequential
-    path — enforced by the parity test.
+    path — enforced by the parity test. ``rows_of`` is the planner's
+    (:func:`plan_buckets`: a path's own row allowance).
     """
     plans = plan_buckets(
         indptr, batch_size=batch_size, len_multiple=len_multiple,
-        max_len=max_len, max_entries=max_entries,
+        max_len=max_len, max_entries=max_entries, rows_of=rows_of,
     )
 
     def fill(p: BucketPlan) -> Bucket:

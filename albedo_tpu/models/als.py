@@ -33,8 +33,10 @@ from albedo_tpu.datasets.ragged import (
     balanced_shards,
     group_buckets,
     grouped_bucket_rows,
+    rows_allowed,
     shard_grouped_bucket_rows,
     shard_rows,
+    slot_tier_floor,
 )
 from albedo_tpu.datasets.star_matrix import StarMatrix
 from albedo_tpu.ops.als import (
@@ -70,6 +72,27 @@ CHUNKED_SPANS = (
     "fit.stream", "fit.stream.gramian", "fit.stream.upload",
     "fit.stream.acquire", "fit.stream.dispatch", "fit.wait",
 )
+
+# The one length tier whose rows the chunked fit hands over more than
+# ``batch_size`` at a time (``ImplicitALS._dispatch_rows``): rows of one padded
+# entry. A MEASURED constant, not a derived rule - set on one v5e at rank 128,
+# float32 gathers, ``batch_size`` 8192 and CG (10M x 1M x 100M stars; PERF.md
+# section 6, PR 34), and to be measured again where one of those changes:
+# ``ops.als.chunked_bucket_update`` alone, device ns a row at 8,192 rows a
+# call -> at the priced cap, read 99 -> 107 at ``L`` = 1 but 160 -> 193 at
+# ``L`` = 2, 167 -> 206 at 4, 186 -> 216 at 8, 230 -> 299 at 16, 485 -> 534 at
+# 40 (already +7% at twice the rows), and a whole sweep 3,471 ms unmerged,
+# 3,373-3,380 with this tier merged, 3,775 with every tier merged. What the
+# compiled programs show beside it: at 8,192 rows the temporaries of an
+# ``L`` <= 16 bucket are 0.7 MB (the block and the CG's row state stay in
+# VMEM) and hundreds of MB at the cap (they stream from HBM) - at ``L`` = 1
+# too, where it costs nothing because 70 of a row's 99 ns are the landing
+# scatter, a cost a ROW at any call size. So no test of bytes against VMEM
+# that the code could make separates the tier that gains from those that
+# lose: (16384, 2) would pass it and is 7% slower a row. The 363 one-entry
+# dispatches of a sweep's 1,455 (0.8 ms of device work each behind a
+# millisecond of upload and dispatch) were where the chip waited.
+STREAM_MERGED_LEN = 1
 
 # The spans of a resident row-sharded fit (``sharded="resident"``,
 # ``shard_mode="allgather"``; ``ImplicitALS._fit_sharded_resident`` says what
@@ -342,7 +365,7 @@ class ImplicitALS:
             max_len=self.max_len,
         )
 
-    def _host_buckets(self, matrix: StarMatrix) -> tuple[list, list]:
+    def _host_buckets(self, matrix: StarMatrix, stream: bool = False) -> tuple[list, list]:
         """(user, item) bucket lists — the exact layouts ``fit`` trains on.
 
         Memoized per matrix (see ``_matrix_cache``): bucketing is a pure
@@ -350,25 +373,69 @@ class ImplicitALS:
         leaves the layout warm for the timed fit. The CSR (user) and CSC
         (item) sides run concurrently and each side's per-bucket scatter
         fills shard across a thread pool (``_bucket_workers``) — output is
-        byte-identical to the sequential build."""
+        byte-identical to the sequential build.
+
+        ``stream`` asks for the chunked fit's own layout, where a bucket is
+        an upload and a dispatch: the same length tiers, rows, order and pad
+        widths under that path's row allowance (:meth:`_dispatch_rows`, from
+        the planner's shapes that admission priced: no side is planned
+        again for it), under a key that holds what the allowance depends on."""
         key = ("host", self.batch_size, self.max_entries, self.max_len)
+        rows_of = (None, None)
+        if stream:
+            key += ("stream", self.rank, self.gather_dtype, self.solver)
         cache = _matrix_cache(matrix)
         if key not in cache:
+            if stream:
+                rows_of = tuple(self._dispatch_rows(side) for side in self._plan_shapes(matrix))
             workers = _bucket_workers()
             if workers:
                 # Split the worker budget across the two concurrent sides so
                 # the total fill-thread count stays at the host budget.
                 kw = dict(self._layout_kwargs(), workers=max(1, workers // 2))
                 with ThreadPoolExecutor(max_workers=2) as sides:
-                    user_f = sides.submit(lambda: bucket_rows(*matrix.csr(), **kw))
-                    item_f = sides.submit(lambda: bucket_rows(*matrix.csc(), **kw))
+                    user_f = sides.submit(
+                        lambda: bucket_rows(*matrix.csr(), rows_of=rows_of[0], **kw))
+                    item_f = sides.submit(
+                        lambda: bucket_rows(*matrix.csc(), rows_of=rows_of[1], **kw))
                     cache[key] = (user_f.result(), item_f.result())
             else:
                 cache[key] = tuple(
-                    bucket_rows(*csx, **self._layout_kwargs())
-                    for csx in (matrix.csr(), matrix.csc())
+                    bucket_rows(*csx, rows_of=allowance, **self._layout_kwargs())
+                    for csx, allowance in zip((matrix.csr(), matrix.csc()), rows_of)
                 )
         return cache[key]
+
+    def _dispatch_rows(self, shapes: list[tuple[int, int]]):
+        """``L -> rows`` that one dispatch of the chunked fit carries of a
+        side's rows of padded length ``L`` (``ragged.plan_buckets``:
+        ``rows_of``), given the side's planner shapes at ``batch_size`` rows
+        (:meth:`_plan_shapes`): ``batch_size`` rows, or ``max_entries`` flat,
+        as in every layout - but a dispatch of rows of ``STREAM_MERGED_LEN``
+        entries is filled until the bytes it holds in flight
+        (``capacity.chunked_row_bytes``) reach those of the side's worst
+        bucket at ``batch_size`` rows, in whole slot tiers. So no dispatch is
+        priced over that layout's worst, which admission's price of the rung
+        covers (``capacity.plan_fit_chunked``), and HOW MANY rows the merged
+        tier carries follows from the rank, the solver and the priced bytes
+        alone (a row of the exact solve holds a ``(k, k)`` system: a tenth as
+        many fit). WHICH tier merges does not: see ``STREAM_MERGED_LEN``.
+        """
+        def price(ln: int) -> int:
+            return capacity_mod.chunked_row_bytes(ln, self.rank, self.gather_dtype, self.solver)
+
+        worst = max((b * price(ln) for b, ln in shapes), default=0)
+
+        def rows_of(pad_l: int) -> int:
+            rows = rows_allowed(pad_l, self.batch_size, self.max_entries)
+            if pad_l != STREAM_MERGED_LEN:
+                return rows
+            most = worst // price(pad_l)
+            if self.max_entries is not None:
+                most = min(most, self.max_entries // pad_l)
+            return max(rows, slot_tier_floor(max(1, most)))
+
+        return rows_of
 
     def _groups_cache_key(self) -> tuple:
         """Cache key for the uploaded device groups. ``Mesh`` is hashable and
@@ -549,23 +616,26 @@ class ImplicitALS:
         uploaded, and none of the O(nnz log nnz) argsorts a full csr()/csc()
         view would redundantly pay before the real bucketing pays them."""
         kw = self._layout_kwargs()
-        return (
-            capacity_mod.bucket_plan_shapes(
-                capacity_mod.counts_indptr(matrix.rows, matrix.n_users), **kw
-            ),
-            capacity_mod.bucket_plan_shapes(
-                capacity_mod.counts_indptr(matrix.cols, matrix.n_items), **kw
-            ),
-        )
+        cache, key = _matrix_cache(matrix), ("plan_shapes", *kw.values())
+        if key not in cache:
+            cache[key] = (
+                capacity_mod.bucket_plan_shapes(
+                    capacity_mod.counts_indptr(matrix.rows, matrix.n_users), **kw
+                ),
+                capacity_mod.bucket_plan_shapes(
+                    capacity_mod.counts_indptr(matrix.cols, matrix.n_items), **kw
+                ),
+            )
+        return cache[key]
 
     def capacity_plan(self, matrix: StarMatrix, chunked: bool = False):
         """Static byte pricing of this fit's layout (``utils.capacity``)."""
         shapes_u, shapes_i = self._plan_shapes(matrix)
-        fn = capacity_mod.plan_fit_chunked if chunked else capacity_mod.plan_fit
-        return fn(
-            shapes_u, shapes_i, matrix.n_users, matrix.n_items,
-            self.rank, self.gather_dtype,
-        )
+        args = (shapes_u, shapes_i, matrix.n_users, matrix.n_items,
+                self.rank, self.gather_dtype)
+        if chunked:
+            return capacity_mod.plan_fit_chunked(*args, self.solver)
+        return capacity_mod.plan_fit(*args)
 
     def admission(self, matrix: StarMatrix):
         """Admission verdict for fitting ``matrix`` on this estimator's
@@ -580,7 +650,7 @@ class ImplicitALS:
                 self.rank, self.gather_dtype)
         verdict = capacity_mod.admit(
             capacity_mod.plan_fit(*args), degradable=True,
-            fallback_plan=capacity_mod.plan_fit_chunked(*args),
+            fallback_plan=capacity_mod.plan_fit_chunked(*args, self.solver),
         )
         if verdict.verdict == "refuse":
             raise capacity_mod.CapacityExceeded(verdict)
@@ -902,14 +972,17 @@ class ImplicitALS:
         the fused path (``ops.als.chunked_bucket_update`` wraps
         ``ops.als.solve_rows``), so the result is
         numerics-parity with the resident path (pinned by
-        ``tests/test_als_chunked.py``) — slower, never dead. Measured on one
-        v5e at 10M x 1M x 100M stars, rank 128 (``gh10m-r128.fit-streamed``,
-        PERF.md section 5, PR 29): 4,320 ms a sweep over 1,455 buckets
-        against a resident plan that does not fit, the chip idle 3.1% of a
-        fit — at that size the device sets the pace (gather 51%, CG 27%, the
-        per-bucket landing scatter 19%) and the host's uploads (1.1 ms a
-        bucket) and dispatches hide behind it. Per-shape executables are
-        acquired through the
+        ``tests/test_als_chunked.py``) — slower, never dead. The layout is
+        the path's own (``_host_buckets(matrix, stream=True)``): a bucket is
+        a dispatch here. Measured on one v5e at 10M x 1M x 100M stars, rank 128
+        (``gh10m-r128.fit-streamed``, PERF.md sections 5 and 6, PR 34):
+        3,375 ms a sweep over 1,107 dispatches against a resident plan that
+        does not fit; the device's programs are 3,315 of them (gather 38%, CG
+        36%, the landing scatter 22%: 70 ns a row landed in the 10M-row
+        table) and set the pace, the chip idle 0.3% of a fit; the host's
+        uploads and dispatches (a millisecond a bucket between them when
+        nothing is in their way) run ahead of it. Per-shape
+        executables are acquired through the
         persistent AOT layer, NOT bare jit: chunked fits run in exactly the
         kill-resume chaos that exposed the PR 4 XLA-cache custom-call
         corruption, so their cross-process executable reuse must stay
@@ -933,7 +1006,7 @@ class ImplicitALS:
         device still owes is in ``fit.wait``.
         """
         with timer.section("fit.prep"):
-            user_buckets, item_buckets = self._host_buckets(matrix)
+            user_buckets, item_buckets = self._host_buckets(matrix, stream=True)
         t1 = time.perf_counter()
 
         statics = dict(
@@ -1023,16 +1096,25 @@ class ImplicitALS:
                 callback(it, np.asarray(user_f), np.asarray(item_f))
 
         n_buckets = {"user": len(user_buckets), "item": len(item_buckets)}
+        shapes = [b.shape for b in (*user_buckets, *item_buckets)]
+        rows = sorted(b for b, _ in shapes) or [0]
         return _PathRun(
-            user_f, item_f, t1, [b.shape for b in (*user_buckets, *item_buckets)],
+            user_f, item_f, t1, shapes,
             compile_s, "+".join(sorted(compile_sources)) or None,
             own={
                 "chunked_shapes": len(executables),
                 "dispatches": self.max_iter * sum(n_buckets.values()),
                 "buckets": n_buckets,
                 "streamed_bytes_per_sweep": sum(
-                    capacity_mod.bucket_slab_bytes(*b.shape)
-                    for b in (*user_buckets, *item_buckets)
+                    capacity_mod.bucket_slab_bytes(*shape) for shape in shapes
+                ),
+                # slot rows a dispatch carries, and the share of the padded
+                # entries that travel in a dispatch of more than batch_size
+                # rows (_dispatch_rows: none does in the resident layout)
+                "rows_per_dispatch": {"median": rows[len(rows) // 2], "largest": rows[-1]},
+                "merged_entry_share": (
+                    sum(b * ln for b, ln in shapes if b > self.batch_size)
+                    / max(1, sum(b * ln for b, ln in shapes))
                 ),
             },
             # Host seconds in the per-bucket uploads (inside device_s).

@@ -548,27 +548,80 @@ def _resident_sharded_transient(shapes_user, shapes_item, u_pad, i_pad, rank, gb
     return max(sides, key=lambda side: sum(side.values()))
 
 
+# The (B, rank) float32 arrays that ``ops.als.chunked_bucket_update`` holds
+# beside a bucket's gathered block while it solves it under ``solver="cg"``:
+# the warm start, the right-hand side, the preconditioner, the iterate, the
+# residual, the search direction, its product with the system, and the solved
+# block ahead of the landing. Counted in the programs' memory analysis,
+# compiled on the host for a described v5e (``jax.experimental.topologies``,
+# "v5e:2x2": no chip call) at rank 128, as temporaries less the ``(B, L, k)``
+# block over ``B * rank * 4``: 5.0 and 6.0 for the merged one-entry dispatches
+# of 10M x 1M x 100M stars ((169984, 1) into the 10M-row table, (240640, 1)
+# into the 1M-row one), 3.2 at (8192, 64), 6.8 at (8192, 176); at 8,192 rows
+# of ``L`` <= 16 the whole program's temporaries are 0.7 MB (VMEM). Slab +
+# temporaries of every shape that layout dispatches come to 0.02...0.994 of
+# the price below (the most: (8192, 176) and the items' (239, 8768)). A
+# longer tier merged to the same cap would hold more - 8.1 at (95232, 8), 8.2
+# at (62464, 16), slab + temporaries 1.002 of the price - so raise this to 9
+# before ``models.als.STREAM_MERGED_LEN`` grows (PERF.md section 6, PR 34).
+# The exact solve's row state is small beside its (B, rank, rank) systems; it
+# is priced the same eight.
+CHUNKED_ROW_ARRAYS = 8
+
+
+def chunked_row_bytes(ln: int, rank: int, gather_dtype: str | None, solver: str) -> int:
+    """Bytes one slot row of a padded length-``ln`` bucket holds on the device
+    while the chunked fit solves it: its share of the slab, its gathered
+    ``(ln, rank)`` block, its ``(rank, rank)`` system where the solve builds
+    one (the exact solve always; the CG on rows of ``ops.als.cg_uses_gramian``
+    length), and ``CHUNKED_ROW_ARRAYS`` rank-vectors. What the chunked fit
+    fills a dispatch of one-entry rows by
+    (``models.als.ImplicitALS._dispatch_rows``), and what
+    :func:`plan_fit_chunked` prices a bucket by."""
+    from albedo_tpu.ops.als import cg_uses_gramian
+
+    gb = _dtype_bytes(gather_dtype)
+    system = rank * rank * 4 if solver != "cg" or cg_uses_gramian(ln, rank) else 0
+    return (
+        bucket_slab_bytes(1, ln) + ln * (rank * gb + gb) + system
+        + CHUNKED_ROW_ARRAYS * rank * 4
+    )
+
+
 def plan_fit_chunked(
     bucket_shapes_user: list[tuple[int, int]],
     bucket_shapes_item: list[tuple[int, int]],
     n_users: int,
     n_items: int,
     rank: int,
-    gather_dtype: str | None = None,
+    gather_dtype: str | None,
+    solver: str,
 ) -> CapacityPlan:
     """Price the chunked host-streamed fallback: only the factor tables stay
-    resident; one bucket's slab + gather block is in flight at a time."""
+    resident; one bucket's slab, gather block and solve
+    (:func:`chunked_row_bytes` a slot row) is in flight at a time. The shapes
+    are the planner's at ``batch_size`` rows: the fit itself fills a dispatch
+    of one-entry rows up to its side's worst of them and no further, so their
+    worst bounds its own.
+
+    An upper bound, and it stays one: a row is never priced under a
+    ``(rank, rank)`` system and one rank-vector beside its slab and block,
+    what this rung was admitted at before the solve was priced by what it
+    builds. Under CG that holds the short rows' buckets (no system) at the old
+    price, so the rung's total does not fall while the chip's peak has not
+    (10M x 1M x 100M stars at rank 128: 6,930,038,784 B before and after,
+    against a measured peak of 11.70 -> 11.72 GB; PERF.md section 6, PR 34)."""
     gb = _dtype_bytes(gather_dtype)
     tables = (n_users + n_items) * rank * 4
-    worst = 0
-    for shapes in (bucket_shapes_user, bucket_shapes_item):
-        for b, ln in shapes:
-            worst = max(
-                worst,
-                bucket_slab_bytes(b, ln)
-                + b * ln * (rank * gb + gb) + b * rank * rank * 4
-                + b * rank * 4,
-            )
+
+    def row(ln: int) -> int:
+        held = bucket_slab_bytes(1, ln) + ln * (rank * gb + gb) + rank * rank * 4 + rank * 4
+        return max(chunked_row_bytes(ln, rank, gather_dtype, solver), held)
+
+    worst = max(
+        (b * row(ln) for shapes in (bucket_shapes_user, bucket_shapes_item) for b, ln in shapes),
+        default=0,
+    )
     return CapacityPlan(
         workload="als_fit_chunked",
         items={"factor_tables": tables, "worst_bucket_in_flight": worst},

@@ -138,6 +138,9 @@ class TestAdmissionWiring:
         assert report["chunked_shapes"] >= 1
         assert report["health"]["nonfinite"] == 0
         assert report["device_s"] >= 0
+        rows = report["rows_per_dispatch"]
+        assert 1 <= rows["median"] <= rows["largest"]
+        assert 0.0 <= report["merged_entry_share"] <= 1.0
 
     def test_mesh_path_never_reroutes_to_single_device_chunked(self, monkeypatch):
         """Mesh fits run their OWN admission ladder (replicated -> sharded
@@ -184,9 +187,14 @@ class TestStreamObservability:
         assert report["mode"] == "chunked" and report["capacity"]["verdict"] == "degrade"
         totals, counts = report["spans"]["totals"], report["spans"]["counts"]
         assert set(CHUNKED_SPANS) <= set(totals)
-        user_buckets, item_buckets = est._host_buckets(m)
+        user_buckets, item_buckets = est._host_buckets(m, stream=True)
         n_user, n_item = len(user_buckets), len(item_buckets)
         assert report["buckets"] == {"user": n_user, "item": n_item}
+        slots = sorted(b.shape[0] for b in (*user_buckets, *item_buckets))
+        assert report["rows_per_dispatch"] == {"median": slots[len(slots) // 2], "largest": slots[-1]}
+        entries = [b.idx.size for b in (*user_buckets, *item_buckets)]
+        merged = [b.idx.size for b in (*user_buckets, *item_buckets) if b.shape[0] > KW["batch_size"]]
+        assert report["merged_entry_share"] == pytest.approx(sum(merged) / sum(entries))
         assert report["dispatches"] == KW["max_iter"] * (n_user + n_item)
         assert counts["fit.stream.upload"] == counts["fit.stream.dispatch"] == report["dispatches"]
         # a cold estimator: one more fit.stream holding the one acquisition of every shape
@@ -256,6 +264,122 @@ class TestStreamObservability:
             jnp.float32(0.5), jnp.float32(40.0),
         )
         assert target.is_deleted()
+
+
+MERGE_KW = dict(rank=8, max_iter=2, seed=0, batch_size=16, max_entries=1 << 11)
+
+
+def _wide_matrix():
+    """Enough rows of one, two and four stars on either side for a length
+    tier to fill many ``batch_size``-row buckets, and a head of long rows
+    whose bucket sets the price."""
+    return synthetic_stars(n_users=900, n_items=2000, mean_stars=5, seed=21)
+
+
+class TestMergedLayout:
+    """The chunked fit's own layout (``_host_buckets(m, stream=True)``): a bucket is a
+    dispatch, and one of one-entry rows is filled up to what the
+    ``batch_size``-row layout's worst bucket of the side is priced at."""
+
+    @pytest.mark.parametrize("side", ["user", "item"])
+    @pytest.mark.parametrize("solver", ["cg", "cholesky"])
+    def test_every_row_once_at_its_own_tier_under_both_caps(self, solver, side):
+        from albedo_tpu.datasets.ragged import _pad_len
+
+        m = _wide_matrix()
+        est = ImplicitALS(**MERGE_KW, solver=solver)
+        at = ("user", "item").index(side)
+        indptr = (m.csr(), m.csc())[at][0]
+        base, merged = est._host_buckets(m)[at], est._host_buckets(m, stream=True)[at]
+        lengths = np.diff(indptr)
+        seen = np.concatenate([b.row_ids[b.row_ids >= 0] for b in merged])
+        assert sorted(seen) == list(np.nonzero(lengths)[0])          # once each, no empty row
+        assert list(seen) == list(np.concatenate([b.row_ids[b.row_ids >= 0] for b in base]))
+
+        def price(b):
+            return b.shape[0] * capacity.chunked_row_bytes(b.shape[1], est.rank, None, solver)
+
+        worst = max(price(b) for b in base)
+        # what admission prices the rung's bucket in flight at covers it
+        admitted = est.capacity_plan(m, chunked=True).items["worst_bucket_in_flight"]
+        assert worst <= admitted
+        for b in merged:
+            valid = b.row_ids >= 0
+            assert b.mask[~valid].sum() == 0
+            # its own length tier, every entry kept
+            assert {_pad_len(int(n), 8) for n in lengths[b.row_ids[valid]]} == {b.shape[1]}
+            np.testing.assert_array_equal(b.mask[valid].sum(axis=1), lengths[b.row_ids[valid]])
+            assert b.idx.size <= est.max_entries and price(b) <= worst
+        assert len(merged) < len(base) and max(b.shape[0] for b in merged) > est.batch_size
+        # every longer tier as the resident layout has it
+        assert [b.shape for b in merged if b.shape[1] > 1] == [b.shape for b in base if b.shape[1] > 1]
+        # (a tier's remainder rounds up to one slot tier where it was several buckets' worth)
+        assert sum(b.idx.size for b in merged) <= 1.05 * sum(b.idx.size for b in base)
+        # full and remainder: two shapes a length at most
+        shapes = {b.shape for b in merged}
+        assert all(sum(ln == l for _, ln in shapes) <= 2 for _, l in shapes)
+
+    @pytest.mark.parametrize("solver", ["cg", "cholesky"])
+    def test_fit_on_the_merged_layout_equals_the_unmerged(self, solver, monkeypatch):
+        m = _wide_matrix()
+        merged = ImplicitALS(**MERGE_KW, solver=solver, chunked=True)
+        model = merged.fit(m)
+        buckets = [b for side in merged._host_buckets(m, stream=True) for b in side]
+        wide = [b for b in buckets if b.shape[0] > MERGE_KW["batch_size"]]
+        assert wide and {b.shape[1] for b in wide} == {1}       # the one-entry tier, and only it
+        assert merged.last_fit_report["merged_entry_share"] == pytest.approx(
+            sum(b.idx.size for b in wide) / sum(b.idx.size for b in buckets))
+        assert merged.last_fit_report["rows_per_dispatch"]["largest"] == max(b.shape[0] for b in wide)
+        # no length tier is the merged one: the resident layout, a bucket a dispatch
+        monkeypatch.setattr("albedo_tpu.models.als.STREAM_MERGED_LEN", 0)
+        plain = ImplicitALS(**MERGE_KW, solver=solver, chunked=True)
+        want = plain.fit(_wide_matrix())    # (a layout stays with its matrix: a fresh one)
+        assert plain.last_fit_report["merged_entry_share"] == 0.0
+        assert plain.last_fit_report["dispatches"] > merged.last_fit_report["dispatches"]
+        np.testing.assert_allclose(model.user_factors, want.user_factors, atol=1e-4)
+        np.testing.assert_allclose(model.item_factors, want.item_factors, atol=1e-4)
+
+    def test_the_exact_solve_merges_by_its_systems_price(self):
+        """Every row of the exact solve builds a ``(k, k)`` system, the CG's
+        short rows none: the same rule carries fewer of them a dispatch."""
+        m = _wide_matrix()
+        rank = 32   # a system (4 KB) outweighs a short row's block and state
+
+        def one_star_rows(solver):
+            est = ImplicitALS(**dict(MERGE_KW, rank=rank), solver=solver)
+            base, merged = est._host_buckets(m)[0], est._host_buckets(m, stream=True)[0]
+            worst = max(b.shape[0] * capacity.chunked_row_bytes(b.shape[1], rank, None, solver)
+                        for b in base)
+            most = max(b.shape[0] for b in merged if b.shape[1] == 1)
+            # as many as the price allows, in whole slot tiers, and no more
+            assert most <= worst // capacity.chunked_row_bytes(1, rank, None, solver) < 2 * most + 16
+            return most
+
+        assert one_star_rows("cholesky") < one_star_rows("cg")
+
+    @pytest.mark.parametrize("forced", [None, True])
+    def test_a_side_is_planned_once_for_admission_and_the_allowance(self, forced, monkeypatch):
+        """The row allowance is read off the planner's shapes that admission
+        priced (``_plan_shapes``, kept with the matrix): a chunked fit plans
+        each side once for them, chosen by admission or forced, and the
+        layout itself once (``bucket_rows``), as the resident fit does."""
+        if forced:
+            est, m = ImplicitALS(**MERGE_KW, solver="cg", chunked=True), _wide_matrix()
+        else:
+            est, m = _degraded(monkeypatch, 8)
+        planned = []
+        real = capacity.bucket_plan_shapes
+        monkeypatch.setattr(
+            capacity, "bucket_plan_shapes",
+            lambda indptr, **kw: planned.append(len(indptr)) or real(indptr, **kw))
+        est.fit(m)
+        assert est.last_fit_report["mode"] == "chunked"
+        assert sorted(planned) == sorted([m.n_users + 1, m.n_items + 1])
+        est.fit(m)          # warm: the verdict and the layout stay with the matrix
+        assert len(planned) == 2
+        # the streamed layout has its own key beside the resident one's
+        assert est._host_buckets(m, stream=True) is est._host_buckets(m, stream=True)
+        assert est._host_buckets(m, stream=True) is not est._host_buckets(m)
 
 
 def test_chunked_fit_against_the_plain_reference(monkeypatch):

@@ -102,8 +102,74 @@ class TestPlans:
     def test_chunked_plan_is_cheaper_than_resident(self):
         shapes = [(64, 64), (32, 128), (128, 16)]
         resident = capacity.plan_fit(shapes, shapes, 500, 300, 16)
-        chunked = capacity.plan_fit_chunked(shapes, shapes, 500, 300, 16)
+        chunked = capacity.plan_fit_chunked(shapes, shapes, 500, 300, 16, None, "cholesky")
         assert chunked.required_bytes < resident.required_bytes
+
+    @pytest.mark.parametrize("solver,ln,builds_system", [
+        ("cholesky", 1, True), ("cholesky", 300, True),      # the exact solve: every row
+        ("cg", 1, False), ("cg", 31, False),                 # matrix-free CG: none
+        ("cg", 32, True), ("cg", 300, True),                 # cg_uses_gramian: L >= 2k
+    ])
+    def test_chunked_row_prices_what_the_solve_builds(self, solver, ln, builds_system):
+        """A slot row of the chunked fit: its slab, its gathered block (and
+        one (L,) array beside it), its (k, k) system only where the kernel
+        builds one, and the CG's row state everywhere."""
+        from albedo_tpu.ops.als import cg_uses_gramian
+
+        rank = 16
+        assert cg_uses_gramian(ln, rank) == (ln >= 32)
+        by_hand = (
+            4 + ln * 9                          # row id; index, value, mask
+            + ln * (rank * 4 + 4)
+            + (rank * rank * 4 if builds_system else 0)
+            + capacity.CHUNKED_ROW_ARRAYS * rank * 4
+        )
+        assert capacity.chunked_row_bytes(ln, rank, None, solver) == by_hand
+        # admission's price of the rung: never under a system and one
+        # rank-vector a row, what it was admitted at before (the short rows
+        # under CG: 1024 + 64 B against the eight rank-vectors' 512)
+        held = 4 + ln * 9 + ln * (rank * 4 + 4) + rank * rank * 4 + rank * 4
+        assert (held > by_hand) == (not builds_system)
+        plan = capacity.plan_fit_chunked([(64, ln)], [(8, 1)], 500, 300, rank, None, solver)
+        assert plan.items == {
+            "factor_tables": 800 * rank * 4, "worst_bucket_in_flight": 64 * max(by_hand, held),
+        }
+        # bf16 gathers halve the block, not the f32 row state
+        assert by_hand - capacity.chunked_row_bytes(ln, rank, "bfloat16", solver) == ln * (rank * 2 + 2)
+
+    @pytest.mark.parametrize("solver,total,moved", [
+        # the parent priced a (k, k) system a row whatever the solver and one
+        # (B, k) array: 6,930,038,784 B, its worst bucket the users' (8192, 176)
+        ("cholesky", 6_959_398_912, +0.0043),   # + seven more row arrays on that bucket
+        # that bucket builds no system under CG and would price at 790,528,000
+        # (the items' (239, 8768) the worst, the total 6,748,807,804, -2.6%):
+        ("cg", 6_930_038_784, 0.0),             # held at what the rung was admitted at
+    ])
+    def test_the_streamed_cells_plan_and_verdict(self, solver, total, moved):
+        """``gh10m-r128`` (10M x 1M x 100M stars, rank 128): the heaviest
+        shapes of either side of the planner's 8192-row layout (from the
+        configuration's degree laws, ``benchmark.stars.degree_sequence``)."""
+        user = [(8192, 176), (6144, 208), (8192, 152), (3072, 280), (8192, 128), (8192, 1)]
+        item = [(239, 8768), (207, 10088), (180, 11608), (78, 26880), (8192, 1)]
+        plan = capacity.plan_fit_chunked(user, item, 10_000_000, 1_000_000, 128, None, solver)
+        assert plan.required_bytes == total
+        assert plan.required_bytes / 6_930_038_784 - 1 == pytest.approx(moved, abs=1e-4)
+        # one v5e: 16,909,336,064 B at the default headroom; the resident plan 20,418,549,304
+        resident = capacity.CapacityPlan("als_fit", {"resident": 20_418_549_304})
+        verdict = capacity.admit(
+            resident, degradable=True, budget=14_372_935_654, fallback_plan=plan)
+        assert verdict.verdict == "degrade"
+
+    @pytest.mark.parametrize("price,args", [
+        (capacity.chunked_row_bytes, (8, 16)),
+        (capacity.plan_fit_chunked, ([(64, 8)], [(8, 1)], 500, 300, 16)),
+    ])
+    def test_the_chunked_prices_have_no_default_solver(self, price, args):
+        """A forgotten solver would price the wrong kernel without a word."""
+        with pytest.raises(TypeError, match="solver"):
+            price(*args, None)
+        cg, exact = (price(*args, None, solver) for solver in ("cg", "cholesky"))
+        assert getattr(cg, "required_bytes", cg) <= getattr(exact, "required_bytes", exact)
 
     def test_plan_serve_scales_with_generations(self):
         one = capacity.plan_serve(1000, 500, 16, excl_entries=100, generations=1)

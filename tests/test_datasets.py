@@ -66,6 +66,65 @@ def test_bucket_rows_covers_all_nonzeros():
     assert len(bucket_shapes(buckets)) <= 20
 
 
+def _plan_by_row_loop(indptr, batch_size, len_multiple=8, max_len=None, max_entries=None):
+    """The planner as it chunked before its tier ends came from a
+    ``searchsorted``: one step a row. The reference ``plan_buckets`` must
+    equal, chunk for chunk."""
+    from albedo_tpu.datasets.ragged import _pad_len, _slot_tier
+
+    lengths = np.diff(indptr)
+    nonempty = np.nonzero(lengths > 0)[0]
+    order = nonempty[np.argsort(lengths[nonempty], kind="stable")]
+    eff = lengths[order] if max_len is None else np.minimum(lengths[order], max_len)
+    out, start = [], 0
+    while start < order.shape[0]:
+        pad_l = _pad_len(int(eff[start]), len_multiple)
+        if max_len is not None:
+            pad_l = max(min(pad_l, -(-max_len // len_multiple) * len_multiple), int(eff[start]))
+        allowed = batch_size
+        if max_entries is not None:
+            allowed = max(1, min(batch_size, max_entries // pad_l))
+        end = start
+        while end < order.shape[0] and end - start < allowed and eff[end] <= pad_l:
+            end += 1
+        b = max(end - start, min(_slot_tier(end - start), allowed))
+        out.append((order[start:end].tolist(), (b, pad_l),
+                    pad_l if max_len is None else min(pad_l, max_len)))
+        start = end
+    return out
+
+
+@pytest.mark.parametrize("layout", [
+    dict(batch_size=64), dict(batch_size=16, max_entries=256),
+    dict(batch_size=1024, max_entries=1 << 12), dict(batch_size=32, max_len=20),
+    dict(batch_size=8, max_len=13, len_multiple=2, max_entries=64),
+])
+@pytest.mark.parametrize("side", ["csr", "csc"])
+def test_plan_buckets_equals_the_row_by_row_planner(layout, side):
+    from albedo_tpu.datasets.ragged import plan_buckets
+
+    m = synthetic_stars(n_users=700, n_items=150, mean_stars=9, seed=4)
+    indptr = getattr(m, side)()[0]
+    got = [(p.rows.tolist(), p.shape, p.cap) for p in plan_buckets(indptr, **layout)]
+    assert got == _plan_by_row_loop(indptr, **layout)
+
+
+def test_plan_buckets_takes_a_paths_own_row_allowance():
+    """``rows_of``: the same tiers and rows in the same order, chunked at
+    the count the path names for each padded length."""
+    from albedo_tpu.datasets.ragged import plan_buckets
+
+    m = synthetic_stars(n_users=700, n_items=150, mean_stars=9, seed=4)
+    indptr = m.csr()[0]
+    base = plan_buckets(indptr, batch_size=16)
+    wide = plan_buckets(indptr, batch_size=16, rows_of=lambda ln: 64 if ln == 1 else 16)
+    assert np.concatenate([p.rows for p in wide]).tolist() == \
+        np.concatenate([p.rows for p in base]).tolist()
+    assert [p.shape for p in wide if p.shape[1] > 1] == [p.shape for p in base if p.shape[1] > 1]
+    ones = [p.shape[0] for p in wide if p.shape[1] == 1]
+    assert max(ones) == 64 and len(ones) < sum(p.shape[1] == 1 for p in base)
+
+
 def test_bucket_rows_max_len_truncates_to_tail():
     indptr = np.array([0, 5])
     cols = np.arange(5, dtype=np.int32)
