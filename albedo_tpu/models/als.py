@@ -45,6 +45,7 @@ from albedo_tpu.ops.als import (
     cg_gram_entry_share,
     check_solver,
     chunked_bucket_update,
+    exact_systems,
     gather_packed_entry_share,
     gather_reformed_entry_share,
     gather_table,
@@ -635,7 +636,7 @@ class ImplicitALS:
                 self.rank, self.gather_dtype)
         if chunked:
             return capacity_mod.plan_fit_chunked(*args, self.solver)
-        return capacity_mod.plan_fit(*args)
+        return capacity_mod.plan_fit(*args, solver=self.solver)
 
     def admission(self, matrix: StarMatrix):
         """Admission verdict for fitting ``matrix`` on this estimator's
@@ -649,7 +650,7 @@ class ImplicitALS:
         args = (shapes_u, shapes_i, matrix.n_users, matrix.n_items,
                 self.rank, self.gather_dtype)
         verdict = capacity_mod.admit(
-            capacity_mod.plan_fit(*args), degradable=True,
+            capacity_mod.plan_fit(*args, solver=self.solver), degradable=True,
             fallback_plan=capacity_mod.plan_fit_chunked(*args, self.solver),
         )
         if verdict.verdict == "refuse":
@@ -680,7 +681,8 @@ class ImplicitALS:
         )
         verdict = capacity_mod.admit_ladder([
             capacity_mod.plan_fit(
-                *args, gather_dtype=self.gather_dtype, n_devices=n_dev
+                *args, gather_dtype=self.gather_dtype, n_devices=n_dev,
+                solver=self.solver,
             ),
             capacity_mod.plan_fit_sharded(*args, n_dev, streamed=False, **shard_kw),
             capacity_mod.plan_fit_sharded(
@@ -726,7 +728,11 @@ class ImplicitALS:
         Cholesky), ``gather_reformed_entry_share`` (the share in buckets
         gathered at a grown slot count, ``ops.als.gather_slots``) and
         ``gather_packed_entry_share`` (the share in buckets whose gather read
-        a line table, ``ops.als.gather_packs_rows``). ``spans`` is the
+        a line table, ``ops.als.gather_packs_rows``),
+        ``exact_systems_per_sweep`` (systems the exact solve factorises a
+        sweep, ``ops.als.exact_systems``: every slot row at the count it is
+        solved at, empty ones too; 0 under CG) and ``exact_system_share``
+        (that over the logical rows of both tables). ``spans`` is the
         same call as a per-fit ``Timer`` snapshot (``{"totals", "counts"}``;
         each also an ``albedo.<name>`` host span in a profiler trace):
         ``fit`` > ``fit.admission``, ``fit.prep`` (children: see
@@ -752,7 +758,7 @@ class ImplicitALS:
                     streamed=path != "sharded",
                     pipelined=path != "sharded_streamed_sync",
                 )
-            self.last_fit_report = self._finish(run, path, admission, t0, timer)
+            self.last_fit_report = self._finish(run, path, admission, t0, timer, matrix)
         self.last_fit_report["spans"] = timer.snapshot()
         return ALSModel(
             user_factors=run.user_f, item_factors=run.item_f, rank=self.rank,
@@ -810,7 +816,10 @@ class ImplicitALS:
             return f"sharded_{self.sharded}", None
         return "sharded", None
 
-    def _finish(self, run: "_PathRun", path: str, admission, t0: float, timer: Timer) -> dict:
+    def _finish(
+        self, run: "_PathRun", path: str, admission, t0: float, timer: Timer,
+        matrix: StarMatrix,
+    ) -> dict:
         """The completion barrier and ``last_fit_report`` (less ``spans``) of
         every path: the keys the benchmark's readers take from whichever path
         ran, then the keys that are the path's own (``run.own``)."""
@@ -827,6 +836,14 @@ class ImplicitALS:
             health = health_dict(factor_health(run.user_f, run.item_f))
         t2 = time.perf_counter()
         prep_s = round(run.t1 - t0, 4)
+        # Systems the exact solve factorises a sweep, over every device: each
+        # slot row of each bucket at the count it is solved at, empty slots
+        # among them (the ring mode, whose shapes are not handed back, says
+        # its own).
+        systems = 0
+        if self.solver == "cholesky":
+            systems = run.systems if run.systems is not None else (
+                run.own.get("n_shards", 1) * exact_systems(run.shapes))
         return {
             "prep_s": prep_s,
             "bucket_s": prep_s if run.bucket_s is None else run.bucket_s,
@@ -845,6 +862,8 @@ class ImplicitALS:
             ),
             "gather_reformed_entry_share": gather_reformed_entry_share(run.shapes),
             "gather_packed_entry_share": gather_packed_entry_share(run.shapes, self.rank),
+            "exact_systems_per_sweep": systems,
+            "exact_system_share": systems / max(1, matrix.n_users + matrix.n_items),
             **run.own,
         }
 
@@ -1211,6 +1230,8 @@ class ImplicitALS:
                 },
             },
             upload_s=stats["upload_s"],
+            # a device's own slots of every bucket, at the count handed over
+            systems=n * sum(b for b, _ in local) if ring else None,
         )
 
     def _sharded_groups_cache_key(self) -> tuple:
@@ -1390,3 +1411,4 @@ class _PathRun:
     bucket_s: float | None = None  # None: all of prep_s (no upload in prep)
     upload_s: float = 0.0
     prep_cached: bool = False
+    systems: int | None = None    # exact systems a sweep, where ``shapes`` do not say

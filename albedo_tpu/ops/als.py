@@ -45,7 +45,8 @@ does not move) so a profiler trace splits the one fused program by what the
 source says and not by what kind of fusion XLA emitted: ``als.init``,
 ``als.gramian``, ``als.gather``, ``als.warm_start``, ``als.cg`` (``.rhs``,
 ``.gram`` — long rows only —, ``.precond``, ``.matvec``, ``.update`` inside
-it), ``als.cholesky``, ``als.landing``. They sit in the shared bodies, so the
+it), ``als.cholesky`` (``.build``, ``.factor``, ``.solve`` inside it),
+``als.landing``. They sit in the shared bodies, so the
 chunked and sharded paths inherit them; the chunked path's per-bucket landing
 scatter into the donated table is ``als.chunk.scatter`` (around its
 ``als.landing``).
@@ -129,6 +130,14 @@ def gather_reformed_entry_share(shapes) -> float:
         total += entries
         reformed += entries * (gather_slots(*shape[-2:]) != shape[-2])
     return reformed / total if total else 0.0
+
+
+def exact_systems(shapes) -> int:
+    """Systems the exact solve factorises over the bucket shapes
+    ``(..., B, L)`` of both sides: every slot row of every bucket at the slot
+    count it is gathered and solved at (``gather_slots``), the planner's empty
+    slots and a piece's among them."""
+    return sum(math.prod(shape[:-2]) * gather_slots(*shape[-2:]) for shape in shapes)
 
 
 # A factor row takes a whole 128-lane line of the (8, 128) tiling whatever its
@@ -301,7 +310,23 @@ def bucket_solve_body(
 ) -> jax.Array:
     """The normal-equation solve for a padded bucket: gather → fused Gramian
     correction → batched Cholesky. Shared by the single-device and shard_map'd
-    paths (``parallel.als``), so a parity fix lands in both."""
+    paths (``parallel.als``), so a parity fix lands in both.
+
+    Scopes: the gather (and the padding of ``idx`` to the block's slot count)
+    is ``als.gather``; everything after it is ``als.cholesky``, in three
+    parts - ``als.cholesky.build`` holds the correction and the b-vector
+    (``bucket_partial_terms``) and, from a line table, the folds of its
+    ``(B', LANES, LANES)`` / ``(B', LANES)`` results back to ``k``;
+    ``als.cholesky.factor`` the regularised systems and their factorisation,
+    ``als.cholesky.solve`` the two triangular solves (``solve_corrected``).
+    The padding of ``val`` and ``mask`` and the weights carry no scope; the
+    compiler fuses them into the contractions that read them.
+
+    The block is solved at the gather's slot count ``B' >= B``
+    (``gather_slots``): the empty slot rows beyond ``B`` are systems like any
+    other (``YtY`` alone: positive definite) that are factorised and solved
+    too, and cut from the result here, AFTER the solve. The fit report's
+    ``exact_systems_per_sweep`` counts them."""
     n_slots, k = idx.shape[0], yty.shape[0]
     gathered, _ = _gather(source, idx, gather_dtype, k)  # (B', L, k or LANES), B' >= B
     val, mask = (_with_slots(a, gathered.shape[0]) for a in (val, mask))
@@ -309,7 +334,8 @@ def bucket_solve_body(
     w = jnp.where(mask, 1.0 + c1, 0.0)          # b-vector weights
 
     corr, b_vec = bucket_partial_terms(gathered, c1, w)
-    corr, b_vec = _fold(corr, k, axes=(-2, -1)), _fold(b_vec, k)
+    with jax.named_scope("als.cholesky"), jax.named_scope("als.cholesky.build"):
+        corr, b_vec = _fold(corr, k, axes=(-2, -1)), _fold(b_vec, k)
     n_b = mask.sum(axis=1).astype(jnp.float32)
     return solve_corrected(yty, corr, b_vec, n_b, reg)[:n_slots]
 
@@ -328,7 +354,7 @@ def bucket_partial_terms(
     full-gather terms. Factored out so the ring path's math IS
     ``bucket_solve_body``'s math, not a reimplementation.
     """
-    with jax.named_scope("als.cholesky"):
+    with jax.named_scope("als.cholesky"), jax.named_scope("als.cholesky.build"):
         # A_b correction = sum_l c1 * y y^T
         corr = jnp.einsum(
             "blk,bl,blm->bkm", gathered, c1.astype(gathered.dtype), gathered,
@@ -354,13 +380,19 @@ def solve_corrected(
     reg: jax.Array,    # () float32
 ) -> jax.Array:
     """Batched Cholesky solve of ``(YtY + corr + reg n_b I) x = b`` — the
-    shared tail of the full-gather and ring-accumulated bucket solves."""
+    shared tail of the full-gather and ring-accumulated bucket solves, under
+    ``als.cholesky``: the systems and their factorisation are
+    ``als.cholesky.factor``, the two triangular solves ``als.cholesky.solve``.
+    Every system handed in is solved, a bucket's empty slot rows too (their
+    ``corr`` and ``b_vec`` are zero): the caller cuts them afterwards."""
     with jax.named_scope("als.cholesky"):
-        k = yty.shape[0]
-        eye = jnp.eye(k, dtype=jnp.float32)
-        a_mat = yty[None] + corr + (reg * n_b)[:, None, None] * eye
-        chol = jnp.linalg.cholesky(a_mat)
-        return jax.scipy.linalg.cho_solve((chol, True), b_vec[..., None])[..., 0]
+        with jax.named_scope("als.cholesky.factor"):
+            k = yty.shape[0]
+            eye = jnp.eye(k, dtype=jnp.float32)
+            a_mat = yty[None] + corr + (reg * n_b)[:, None, None] * eye
+            chol = jnp.linalg.cholesky(a_mat)
+        with jax.named_scope("als.cholesky.solve"):
+            return jax.scipy.linalg.cho_solve((chol, True), b_vec[..., None])[..., 0]
 
 
 # A bucket's CG runs on its explicit (B, k, k) Gramian once its padded length

@@ -351,6 +351,23 @@ def bucket_slab_bytes(b: int, ln: int) -> int:
     return b * 4 + b * ln * (4 + 4 + 1)
 
 
+def exact_system_bytes(rank: int) -> int:
+    """Bytes one system of a bucket holds on the device while
+    ``ops.als.bucket_solve_body`` solves it exactly: the correction as the
+    contraction leaves it (from a line table ``(LANES, LANES)``, folded to
+    ``(rank, rank)`` afterwards) and TWO ``(rank, rank)`` float32 systems at
+    the size the (8, 128) tiling pads them to (the regularised system, which
+    the factorisation overwrites, and the factor's inverse of the triangular
+    solves). Read off the memory analysis of one bucket's program compiled for
+    a described v5e at 8,193 systems (PERF.md section 6, PR 35): 131.6 KB a
+    system at rank 128 (this gives 131.1), 131.4 at 64 (131.1), 123.2 at 50
+    (122.9), 98.5 at 32 (98.3), 73.8 at 16 (81.9)."""
+    from albedo_tpu.ops.als import LANES, gather_packs_rows
+
+    padded = -(-rank // 8) * 8 * -(-rank // LANES) * LANES * 4
+    return (LANES * LANES * 4 if gather_packs_rows(rank) else 0) + 2 * padded
+
+
 def plan_fit(
     bucket_shapes_user: list[tuple[int, int]],
     bucket_shapes_item: list[tuple[int, int]],
@@ -359,6 +376,7 @@ def plan_fit(
     rank: int,
     gather_dtype: str | None = None,
     n_devices: int = 1,
+    solver: str = "cg",
 ) -> CapacityPlan:
     """Price the device-resident fused ALS fit, PER DEVICE.
 
@@ -367,7 +385,11 @@ def plan_fit(
     that ratings stay on device across sweeps), and the landing pools
     (``concat(solved_blocks..., target)`` materializes ``n_slots + n_target``
     rank-vectors per half-sweep). Transient: the largest bucket's gathered
-    ``(B, L, rank)`` block plus its ``(B, rank, rank)`` Gramian correction.
+    ``(B, L, rank)`` block plus, under ``solver="cg"``, its ``(B, rank,
+    rank)`` Gramian correction, and under the exact solve what its
+    ``gather_slots(B, L)`` systems hold together (:func:`exact_system_bytes`:
+    12 times the one correction at rank 50). ``solver`` defaults to the
+    price this plan had before it took one.
 
     ``n_devices > 1`` prices the GSPMD mesh-resident path: factor tables
     (and the landing pool's target segment) stay REPLICATED per device,
@@ -375,9 +397,18 @@ def plan_fit(
     axis — the replicated tables are exactly why this path stops scaling
     and the fully sharded plan (:func:`plan_fit_sharded`) takes over.
     """
+    from albedo_tpu.ops.als import check_solver, gather_slots
+
+    check_solver(solver)
     gb = _dtype_bytes(gather_dtype)
     n = max(1, int(n_devices))
     tables = (n_users + n_items) * rank * 4
+
+    def systems(b: int, ln: int) -> int:
+        if solver == "cg":
+            return b * rank * rank * 4
+        return gather_slots(b, ln) * exact_system_bytes(rank)
+
     slabs = 0
     slots_u = slots_i = 0
     transient = 0
@@ -388,7 +419,7 @@ def plan_fit(
                 slots_u += b
             else:
                 slots_i += b
-            transient = max(transient, b * ln * (rank * gb + gb) + b * rank * rank * 4)
+            transient = max(transient, b * ln * (rank * gb + gb) + systems(b, ln))
     landing = ((slots_u + slots_i) // n + n_users + n_items) * rank * 4
     return CapacityPlan(
         workload="als_fit",

@@ -348,7 +348,8 @@ SHARED_REPORT_KEYS = {
     "compile_source": (str, type(None)), "device_s": float, "prep_cached": bool,
     "health": dict, "mode": str, "capacity": (dict, type(None)),
     "cg_gram_entry_share": float, "gather_reformed_entry_share": float,
-    "gather_packed_entry_share": float, "spans": dict,
+    "gather_packed_entry_share": float, "exact_systems_per_sweep": int,
+    "exact_system_share": float, "spans": dict,
 }
 OWN_REPORT_KEYS = {
     "resident": {"capacity_cross_check"},
