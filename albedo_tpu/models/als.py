@@ -45,6 +45,7 @@ from albedo_tpu.ops.als import (
     cg_gram_entry_share,
     check_solver,
     chunked_bucket_update,
+    exact_lane_systems,
     exact_systems,
     gather_packed_entry_share,
     gather_reformed_entry_share,
@@ -730,9 +731,12 @@ class ImplicitALS:
         ``gather_packed_entry_share`` (the share in buckets whose gather read
         a line table, ``ops.als.gather_packs_rows``),
         ``exact_systems_per_sweep`` (systems the exact solve factorises a
-        sweep, ``ops.als.exact_systems``: every slot row at the count it is
-        solved at, empty ones too; 0 under CG) and ``exact_system_share``
-        (that over the logical rows of both tables). ``spans`` is the
+        sweep, ``ops.als.exact_systems``: every slot row, empty ones too; 0
+        under CG) and ``exact_system_share`` (that over the logical rows of
+        both tables), ``exact_lane_systems_per_sweep`` (the lanes those
+        systems are solved at, a bucket's padded to whole lane tiles:
+        ``ops.als.exact_lane_systems``) and ``exact_lane_share`` (that over
+        the same rows). ``spans`` is the
         same call as a per-fit ``Timer`` snapshot (``{"totals", "counts"}``;
         each also an ``albedo.<name>`` host span in a profiler trace):
         ``fit`` > ``fit.admission``, ``fit.prep`` (children: see
@@ -836,14 +840,16 @@ class ImplicitALS:
             health = health_dict(factor_health(run.user_f, run.item_f))
         t2 = time.perf_counter()
         prep_s = round(run.t1 - t0, 4)
-        # Systems the exact solve factorises a sweep, over every device: each
-        # slot row of each bucket at the count it is solved at, empty slots
-        # among them (the ring mode, whose shapes are not handed back, says
-        # its own).
-        systems = 0
+        # Systems the exact solve factorises a sweep, and the lanes it solves
+        # them at, over every device: each slot row of each bucket, empty
+        # slots among them (the ring mode hands back no shapes for the
+        # gather's counters, and says the ones it solved apart).
+        systems = lane_systems = 0
         if self.solver == "cholesky":
-            systems = run.systems if run.systems is not None else (
-                run.own.get("n_shards", 1) * exact_systems(run.shapes))
+            solved = run.shapes if run.exact_shapes is None else run.exact_shapes
+            devices = run.own.get("n_shards", 1)
+            systems, lane_systems = devices * exact_systems(solved), devices * exact_lane_systems(solved)
+        rows = max(1, matrix.n_users + matrix.n_items)
         return {
             "prep_s": prep_s,
             "bucket_s": prep_s if run.bucket_s is None else run.bucket_s,
@@ -863,7 +869,9 @@ class ImplicitALS:
             "gather_reformed_entry_share": gather_reformed_entry_share(run.shapes),
             "gather_packed_entry_share": gather_packed_entry_share(run.shapes, self.rank),
             "exact_systems_per_sweep": systems,
-            "exact_system_share": systems / max(1, matrix.n_users + matrix.n_items),
+            "exact_system_share": systems / rows,
+            "exact_lane_systems_per_sweep": lane_systems,
+            "exact_lane_share": lane_systems / rows,
             **run.own,
         }
 
@@ -1230,8 +1238,7 @@ class ImplicitALS:
                 },
             },
             upload_s=stats["upload_s"],
-            # a device's own slots of every bucket, at the count handed over
-            systems=n * sum(b for b, _ in local) if ring else None,
+            exact_shapes=local if ring else None,
         )
 
     def _sharded_groups_cache_key(self) -> tuple:
@@ -1411,4 +1418,4 @@ class _PathRun:
     bucket_s: float | None = None  # None: all of prep_s (no upload in prep)
     upload_s: float = 0.0
     prep_cached: bool = False
-    systems: int | None = None    # exact systems a sweep, where ``shapes`` do not say
+    exact_shapes: list | None = None  # what the exact solve ran at, where ``shapes`` do not say

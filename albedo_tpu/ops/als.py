@@ -15,8 +15,9 @@ with per-block LAPACK Cholesky on executors. Here each half-sweep is a set of
 fixed-shape bucket solves: gather ``Y[idx] -> (B, L, k)``, one fused einsum for
 the Gramian correction (on the MXU: the Cholesky solver's every bucket, the
 CG's long rows, ``cg_uses_gramian``; the CG's short rows stay matrix-free
-multiply-reduce passes on the vector unit), batched solve, land solved rows by
-an inverse-permutation gather — no shuffle. Buckets come from
+multiply-reduce passes on the vector unit), batched solve (the exact one with
+the bucket's rows in the lanes, a system a lane: ``solve_corrected``), land
+solved rows by an inverse-permutation gather — no shuffle. Buckets come from
 ``albedo_tpu.datasets.bucket_rows``. The layout is the same family as ALX's
 TPU matrix factorization (arXiv:2112.02194 — padded
 dense gather blocks over sharded factor tables), and the warm-started-CG fast
@@ -134,10 +135,23 @@ def gather_reformed_entry_share(shapes) -> float:
 
 def exact_systems(shapes) -> int:
     """Systems the exact solve factorises over the bucket shapes
-    ``(..., B, L)`` of both sides: every slot row of every bucket at the slot
-    count it is gathered and solved at (``gather_slots``), the planner's empty
-    slots and a piece's among them."""
-    return sum(math.prod(shape[:-2]) * gather_slots(*shape[-2:]) for shape in shapes)
+    ``(..., B, L)`` of both sides: every slot row of every bucket, the
+    planner's empty slots and a piece's among them (the rows ``gather_slots``
+    grows a bucket by are cut before the solve)."""
+    return sum(math.prod(shape[:-1]) for shape in shapes)
+
+
+def exact_lanes(n_systems: int) -> int:
+    """Lanes at which ``solve_corrected`` solves a block of ``n_systems``
+    systems: one system a lane, whole ``LANES``-lane tiles. Static shapes
+    only: the kernel and the fit report's counter share this rule."""
+    return -(-n_systems // LANES) * LANES
+
+
+def exact_lane_systems(shapes) -> int:
+    """Lanes the exact solve pays for over the bucket shapes ``(..., B, L)``
+    of both sides: each bucket's ``B`` systems at ``exact_lanes(B)``."""
+    return sum(math.prod(shape[:-2]) * exact_lanes(shape[-2]) for shape in shapes)
 
 
 # A factor row takes a whole 128-lane line of the (8, 128) tiling whatever its
@@ -309,26 +323,32 @@ def bucket_solve_body(
     gather_dtype=None,   # None = f32 gathers; "bfloat16" halves streamed bytes
 ) -> jax.Array:
     """The normal-equation solve for a padded bucket: gather → fused Gramian
-    correction → batched Cholesky. Shared by the single-device and shard_map'd
-    paths (``parallel.als``), so a parity fix lands in both.
+    correction → the exact solve, a system a lane (``solve_corrected``).
+    Shared by the single-device and shard_map'd paths (``parallel.als``), so a
+    parity fix lands in both.
 
     Scopes: the gather (and the padding of ``idx`` to the block's slot count)
     is ``als.gather``; everything after it is ``als.cholesky``, in three
     parts - ``als.cholesky.build`` holds the correction and the b-vector
-    (``bucket_partial_terms``) and, from a line table, the folds of its
-    ``(B', LANES, LANES)`` / ``(B', LANES)`` results back to ``k``;
-    ``als.cholesky.factor`` the regularised systems and their factorisation,
-    ``als.cholesky.solve`` the two triangular solves (``solve_corrected``).
-    The padding of ``val`` and ``mask`` and the weights carry no scope; the
-    compiler fuses them into the contractions that read them.
+    (``bucket_partial_terms``), from a line table the folds of its
+    ``(B', LANES, LANES)`` / ``(B', LANES)`` results back to ``k``, and the
+    regularised systems in the solve's ``(k, k + 1, lanes)`` order;
+    ``als.cholesky.factor`` and ``als.cholesky.solve`` are the solve's two
+    loops (``solve_corrected``). The padding of ``val`` and ``mask`` and the
+    weights carry no scope; the compiler fuses them into the contractions
+    that read them.
 
-    The block is solved at the gather's slot count ``B' >= B``
-    (``gather_slots``): the empty slot rows beyond ``B`` are systems like any
-    other (``YtY`` alone: positive definite) that are factorised and solved
-    too, and cut from the result here, AFTER the solve. The fit report's
-    ``exact_systems_per_sweep`` counts them."""
-    n_slots, k = idx.shape[0], yty.shape[0]
+    The block is gathered and contracted at the gather's slot count ``B' >=
+    B`` (``gather_slots``); the few rows beyond ``B`` are statically empty and
+    ``solve_corrected`` drops them where it puts the systems into lanes, so
+    ``B`` systems are solved - the planner's empty slots among them (``YtY``
+    alone: positive definite) -, which the fit report's
+    ``exact_systems_per_sweep`` counts. (Cutting the contraction's operand or
+    its result by that odd row instead makes the TPU compiler emit code it
+    takes minutes to generate and seconds to run: PERF.md section 6, PR 36.)"""
+    k = yty.shape[0]
     gathered, _ = _gather(source, idx, gather_dtype, k)  # (B', L, k or LANES), B' >= B
+    n_b = mask.sum(axis=1).astype(jnp.float32)  # (B,)
     val, mask = (_with_slots(a, gathered.shape[0]) for a in (val, mask))
     c1 = alpha * val                            # (B', L); 0 on padding
     w = jnp.where(mask, 1.0 + c1, 0.0)          # b-vector weights
@@ -336,8 +356,7 @@ def bucket_solve_body(
     corr, b_vec = bucket_partial_terms(gathered, c1, w)
     with jax.named_scope("als.cholesky"), jax.named_scope("als.cholesky.build"):
         corr, b_vec = _fold(corr, k, axes=(-2, -1)), _fold(b_vec, k)
-    n_b = mask.sum(axis=1).astype(jnp.float32)
-    return solve_corrected(yty, corr, b_vec, n_b, reg)[:n_slots]
+    return solve_corrected(yty, corr, b_vec, n_b, reg)
 
 
 def bucket_partial_terms(
@@ -372,27 +391,124 @@ def bucket_partial_terms(
         return corr, b_vec
 
 
+# The exact solve works through a block's lanes a chunk at a time: a chunk of
+# systems and its factor, 2 * k * ceil((k + 1) / 8) * 8 * LANES * 4 B a lane
+# tile, as many tiles as fit EXACT_CHUNK_BYTES. Compiled for a v5e such a
+# chunk and its factor are kept in VMEM (``S(1)``) through the k column
+# steps, where a whole block of 8,192 systems (92 MB at rank 50, twice) is
+# read from HBM at every column. One bucket's whole program, 8,192 x 8 rows
+# at rank 50 on a v5e: 4.81 ms at 12 MiB (512 lanes), 4.67 at 24 (1,024),
+# 4.92 at 48 (2,176), 5.57 unchunked, the library's 53.1; at rank 128 19.4 at
+# 24 MiB (one tile), 19.5 at 36 (two), 23.5 at 72 (four). Inside the fused
+# fit the chunk competes for VMEM with the gather's line table: albedo-r50-chol
+# reads 962 ms a sweep at 24 MiB, 1,194 at 12 (the factorisation falls out of
+# VMEM) and 1,303 at 48 (the table does) - PERF.md sections 5 and 6, PR 36.
+EXACT_CHUNK_BYTES = 24 << 20
+
+
+def exact_chunk_tiles(rank: int) -> int:
+    """Lane tiles of systems a chunk of the exact solve holds at ``rank``
+    (at least one). Static shapes only."""
+    tile = 2 * rank * -(-(rank + 1) // 8) * 8 * LANES * 4
+    return max(1, EXACT_CHUNK_BYTES // tile)
+
+
+def _factor_and_solve(aug: jax.Array) -> jax.Array:
+    """``x (k, R)`` of ``A x = b`` for ``R`` symmetric positive definite
+    systems, one a lane: ``aug (k, k + 1, R)`` holds ``A`` (``aug[j, i] =
+    A[i, j]``) with ``b`` as row ``k`` (``aug[j, k] = b[j]``).
+
+    ``als.cholesky.factor``: the lower factor a column a step, left-looking
+    (column ``j`` is ``A[:, j]`` less the earlier columns times their row
+    ``j`` entries, over ``sqrt`` of its diagonal), kept transposed -
+    ``up[m, i] = L[i, m]`` - so that every sum runs over the leading axis and
+    every step is an elementwise float32 pass over whole lanes. Row ``k``
+    rides along: the factor of ``[[A, b], [b^T, .]]`` has ``z = L^-1 b`` as
+    its last row, so the forward substitution is the factorisation's own.
+    ``als.cholesky.solve``: ``L^T x = z`` from the last row up, a row of
+    ``up`` a step. Both loops are rolled (``fori_loop``): the fused fit
+    instantiates this once a shape group."""
+    k = aug.shape[0]
+    at = functools.partial(jax.lax.dynamic_index_in_dim, keepdims=False)
+    put = jax.lax.dynamic_update_index_in_dim
+
+    with jax.named_scope("als.cholesky.factor"):
+        below = jnp.arange(k + 1)[:, None]
+
+        def column(j, carry):
+            up, inv_diag = carry        # up[m] is final for m < j and zero from j on
+            s = at(aug, j, 0) - jnp.sum(up * at(up, j, 1)[:, None], axis=0)  # (k + 1, R)
+            inv = 1.0 / jnp.sqrt(at(s, j, 0))                                # (R,)
+            col = jnp.where(below >= j, s * inv[None], 0.0)
+            return put(up, col, j, 0), put(inv_diag, inv, j, 0)
+
+        up, inv_diag = jax.lax.fori_loop(
+            0, k, column, (jnp.zeros_like(aug), jnp.zeros_like(aug[:, 0])))
+
+    with jax.named_scope("als.cholesky.solve"):
+        lower, z = up[:, :k], up[:, k]
+
+        def backward(i, x):             # x[m] is final for m > j and zero up to j
+            j = k - 1 - i
+            xj = (at(z, j, 0) - jnp.sum(at(lower, j, 0) * x, axis=0)) * at(inv_diag, j, 0)
+            return put(x, xj, j, 0)
+
+        return jax.lax.fori_loop(0, k, backward, jnp.zeros_like(z))
+
+
 def solve_corrected(
     yty: jax.Array,    # (k, k)
-    corr: jax.Array,   # (B, k, k) accumulated Gramian correction
-    b_vec: jax.Array,  # (B, k)
+    corr: jax.Array,   # (B', k, k) accumulated Gramian correction, B' >= B
+    b_vec: jax.Array,  # (B', k)
     n_b: jax.Array,    # (B,) float32 per-row nonzero counts
     reg: jax.Array,    # () float32
 ) -> jax.Array:
-    """Batched Cholesky solve of ``(YtY + corr + reg n_b I) x = b`` — the
-    shared tail of the full-gather and ring-accumulated bucket solves, under
-    ``als.cholesky``: the systems and their factorisation are
-    ``als.cholesky.factor``, the two triangular solves ``als.cholesky.solve``.
-    Every system handed in is solved, a bucket's empty slot rows too (their
-    ``corr`` and ``b_vec`` are zero): the caller cuts them afterwards."""
+    """The exact solve of ``(YtY + corr + reg n_b I) x = b`` for the ``B``
+    rows ``n_b`` counts, by Cholesky with the BATCH IN THE LANES - the shared
+    tail of the full-gather and ring-accumulated bucket solves, under
+    ``als.cholesky``. ``corr`` and ``b_vec`` may carry rows beyond ``B`` (a
+    bucket's statically empty growth rows): they never reach a lane.
+
+    ``als.cholesky.build``: the systems are transposed once to ``(k, k + 1,
+    rows)``, the block's row LAST and the b-vector as row ``k``
+    (``_factor_and_solve``), cut to ``B`` and padded to ``exact_lanes(B)``
+    lanes, and regularised there; a lane past ``B`` holds ``I x = 0``.
+    ``als.cholesky.factor`` / ``.solve``: a chunk of ``exact_chunk_tiles(k)``
+    lane tiles at a time, so that a chunk and its factor stay on chip through
+    the ``k`` steps; the tiles left over are one more, smaller chunk. Float32
+    elementwise throughout: no library factorisation, no matmul, no
+    iteration. Every one of the ``B`` systems is solved, a bucket's empty
+    slots too (their ``corr`` and ``b_vec`` are zero). Returns ``(B, k)``."""
+    n, k = n_b.shape[0], yty.shape[0]
+    lanes = exact_lanes(n)
+    chunk = min(exact_chunk_tiles(k) * LANES, lanes)
     with jax.named_scope("als.cholesky"):
-        with jax.named_scope("als.cholesky.factor"):
-            k = yty.shape[0]
-            eye = jnp.eye(k, dtype=jnp.float32)
-            a_mat = yty[None] + corr + (reg * n_b)[:, None, None] * eye
-            chol = jnp.linalg.cholesky(a_mat)
+        with jax.named_scope("als.cholesky.build"):
+            # (k, k + 1, B'): aug[j, i] = corr[i, j], row k the b-vector
+            aug = jnp.concatenate([corr, b_vec[:, None, :]], axis=1).transpose(2, 1, 0)
+            aug = jnp.pad(aug[:, :, :n], ((0, 0), (0, 0), (0, lanes - n)))
+            live = jnp.arange(lanes) < n
+            ridge = jnp.pad(reg * n_b, (0, lanes - n))
+            shared, eye = (
+                jnp.pad(m, ((0, 0), (0, 1)))[:, :, None] for m in (yty, jnp.eye(k, dtype=jnp.float32)))
+            aug = aug + jnp.where(live, shared + eye * ridge, eye)
+
+        def solve_chunk(start, size):
+            return _factor_and_solve(jax.lax.dynamic_slice_in_dim(aug, start, size, axis=2))
+
+        solved = []
+        whole = lanes // chunk
+        if whole:
+            with jax.named_scope("als.cholesky.factor"):                 # the loop over chunks
+                x = jax.lax.map(lambda c: solve_chunk(c * chunk, chunk), jnp.arange(whole))
+            with jax.named_scope("als.cholesky.solve"):
+                solved.append(x.transpose(0, 2, 1).reshape(whole * chunk, k))
+        if lanes > whole * chunk:
+            x = solve_chunk(whole * chunk, lanes - whole * chunk)
+            with jax.named_scope("als.cholesky.solve"):
+                solved.append(x.T)
         with jax.named_scope("als.cholesky.solve"):
-            return jax.scipy.linalg.cho_solve((chol, True), b_vec[..., None])[..., 0]
+            return jnp.concatenate(solved)[:n]
 
 
 # A bucket's CG runs on its explicit (B, k, k) Gramian once its padded length
@@ -444,10 +560,9 @@ def bucket_cg_body(
     block, and iterate on it: the same iterates in exact arithmetic, without
     streaming the block from HBM for every matvec.
 
-    Either way each CG step is cheap next to the Cholesky path's k^3-shaped
-    factorization, which XLA executes as ~k
-    sequential panel steps at a few GF/s on TPU (measured 6 GF/s; the einsum
-    phases of the same sweep hit ~1 TF/s). Warm-starting from the previous
+    Either way each CG step is cheap next to the exact path's k^3-shaped
+    factorization (``solve_corrected``: k column steps over the systems of a
+    block, a system a lane). Warm-starting from the previous
     sweep's factors makes a few CG steps per half-sweep converge to the same
     fixed point — the established fast implicit-ALS practice (e.g. the
     ``implicit`` package's conjugate-gradient solver, default 3 steps), while

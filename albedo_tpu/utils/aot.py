@@ -27,9 +27,10 @@ verification can make that reuse safe). Three scoped defenses:
 
 1. **Custom-call programs never reuse serialized executables at ANY
    layer**: already excluded from the ``jax.export`` disk cache, they now
-   also compile with the persistent XLA cache bypassed. TPU lowers the
-   same solves to pure HLO and keeps the full cache stack; CPU Cholesky
-   pays a per-process compile — correctness over warmth.
+   also compile with the persistent XLA cache bypassed. Until PR 36 the
+   exact ALS solve was such a program on the CPU (``jnp.linalg.cholesky``);
+   it is the program's own HLO now and keeps the full cache stack on every
+   backend, so the rule guards whatever library call comes next.
 2. **Output-fingerprint self-check on export round-trips**: at export time
    the fresh-compiled executable runs once on a deterministic probe input
    (derived from argument shapes/dtypes; varied index patterns — an
@@ -394,11 +395,11 @@ def _custom_call_targets(exported) -> list[str]:
 
     Custom calls are the unstable part of ``jax.export``: their backend
     configs are not guaranteed to survive a cross-process round trip (the
-    CPU LAPACK ``lapack_spotrf`` of the Cholesky solver segfaults when a
+    CPU LAPACK ``lapack_spotrf`` of a library Cholesky segfaults when a
     deserialized module executes in a fresh process), so any module
-    containing one stays memory-cached only. TPU lowers the same solves to
-    pure HLO — no custom calls — and the CG fast path has none on any
-    backend, so the disk layer still covers the paths that matter.
+    containing one stays memory-cached only. Both ALS solvers are pure HLO
+    on every backend — no custom calls — so the disk layer covers the paths
+    that matter.
     """
     text = exported.mlir_module()
     targets = re.findall(r"stablehlo\.custom_call\s*@([\w.$-]+)", text)
@@ -639,7 +640,7 @@ def persistent_aot_executable(
                     except OSError:
                         pass
         elif targets and fingerprint_enabled() and _xla_persistent_cache_engaged():
-            # Known custom-call program (the CPU Cholesky fit). Custom calls
+            # Known custom-call program (a library factorisation on the CPU). Custom calls
             # are the unstable part of EVERY serialization layer, not just
             # jax.export: the persistent XLA cache's deserialized executables
             # for this program class corrupted numerics NONDETERMINISTICALLY

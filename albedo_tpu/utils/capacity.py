@@ -353,19 +353,49 @@ def bucket_slab_bytes(b: int, ln: int) -> int:
 
 def exact_system_bytes(rank: int) -> int:
     """Bytes one system of a bucket holds on the device while
-    ``ops.als.bucket_solve_body`` solves it exactly: the correction as the
-    contraction leaves it (from a line table ``(LANES, LANES)``, folded to
-    ``(rank, rank)`` afterwards) and TWO ``(rank, rank)`` float32 systems at
-    the size the (8, 128) tiling pads them to (the regularised system, which
-    the factorisation overwrites, and the factor's inverse of the triangular
-    solves). Read off the memory analysis of one bucket's program compiled for
-    a described v5e at 8,193 systems (PERF.md section 6, PR 35): 131.6 KB a
-    system at rank 128 (this gives 131.1), 131.4 at 64 (131.1), 123.2 at 50
-    (122.9), 98.5 at 32 (98.3), 73.8 at 16 (81.9)."""
+    ``ops.als.bucket_solve_body`` solves it exactly: the three arrays
+    ``als.cholesky.build`` makes of it, priced as if none took another's
+    place - the correction as the contraction leaves it (from a line table
+    ``(LANES, LANES)``, folded to ``(rank, rank)`` afterwards; else ``(rank,
+    rank)`` at the size the (8, 128) tiling pads it to), the augmented system
+    ``(rank + 1, rank)`` at that padding, and the same again for its transpose
+    into the lanes (``(rank, rank + 1, .)``: no more a lane than that). The
+    solve's own working set (a chunk of systems and its factor,
+    ``ops.als.EXACT_CHUNK_BYTES``, in VMEM on a v5e) is inside that room.
+    Read off the memory analysis of one bucket's program compiled for a
+    described v5e (PERF.md section 6, PR 36): at 8,192 x 8 the compiler holds
+    the gathered block, the correction and ONE augmented system at once -
+    139.3 KB a slot row at rank 128 (block 4.1 + 65.5 + 69.6), 94.5 at rank 50
+    (4.1 + 65.5 + 28.7, less what it overlays), 65.6 at rank 16 - so the third
+    array is the room: a bucket's compiled temporaries are 0.53-0.73 of its
+    price (:func:`exact_bucket_bytes`) at rank 128, 0.63-0.81 at 50 and
+    0.67-0.86 at 16 over 8,192 x 8 / 16 / 32, 4,096 x 64, 2,048 x 176 and
+    512 x 1,064, and 0.98-0.99 for 16 rows of 125,104 entries, which are all
+    block. The library's batched factorisation held 131.6 / 123.2 / 73.8 KB a
+    system (PR 35)."""
     from albedo_tpu.ops.als import LANES, gather_packs_rows
 
-    padded = -(-rank // 8) * 8 * -(-rank // LANES) * LANES * 4
-    return (LANES * LANES * 4 if gather_packs_rows(rank) else 0) + 2 * padded
+    wide = -(-rank // LANES) * LANES * 4
+
+    def rows(n: int) -> int:
+        return -(-n // 8) * 8
+
+    correction = LANES * LANES * 4 if gather_packs_rows(rank) else rows(rank) * wide
+    return correction + 2 * rows(rank + 1) * wide
+
+
+def exact_bucket_bytes(b: int, ln: int, rank: int, gather_bytes: int = 4) -> int:
+    """Bytes a ``(b, ln)`` bucket's exact solve holds at once: its gathered
+    block at the width it is gathered at (a whole ``LANES``-lane line an entry
+    from a line table, whatever the rank) with the entries' weights, and its
+    systems (:func:`exact_system_bytes`) - one a slot row of the gathered
+    block, and no fewer than the whole lane tiles the solve pads a small
+    block's to."""
+    from albedo_tpu.ops.als import LANES, exact_lanes, gather_packs_rows, gather_slots
+
+    width = LANES if gather_packs_rows(rank) else rank
+    systems = max(gather_slots(b, ln), exact_lanes(b))
+    return b * ln * (width * gather_bytes + gather_bytes) + systems * exact_system_bytes(rank)
 
 
 def plan_fit(
@@ -388,7 +418,8 @@ def plan_fit(
     ``(B, L, rank)`` block plus, under ``solver="cg"``, its ``(B, rank,
     rank)`` Gramian correction, and under the exact solve what its
     ``gather_slots(B, L)`` systems hold together (:func:`exact_system_bytes`:
-    12 times the one correction at rank 50). ``solver`` defaults to the
+    12.3 times the one correction at rank 50) beside the block at the width
+    it is gathered at (:func:`exact_bucket_bytes`). ``solver`` defaults to the
     price this plan had before it took one.
 
     ``n_devices > 1`` prices the GSPMD mesh-resident path: factor tables
@@ -397,17 +428,17 @@ def plan_fit(
     axis — the replicated tables are exactly why this path stops scaling
     and the fully sharded plan (:func:`plan_fit_sharded`) takes over.
     """
-    from albedo_tpu.ops.als import check_solver, gather_slots
+    from albedo_tpu.ops.als import check_solver
 
     check_solver(solver)
     gb = _dtype_bytes(gather_dtype)
     n = max(1, int(n_devices))
     tables = (n_users + n_items) * rank * 4
 
-    def systems(b: int, ln: int) -> int:
+    def in_flight(b: int, ln: int) -> int:
         if solver == "cg":
-            return b * rank * rank * 4
-        return gather_slots(b, ln) * exact_system_bytes(rank)
+            return b * ln * (rank * gb + gb) + b * rank * rank * 4
+        return exact_bucket_bytes(b, ln, rank, gb)
 
     slabs = 0
     slots_u = slots_i = 0
@@ -419,7 +450,7 @@ def plan_fit(
                 slots_u += b
             else:
                 slots_i += b
-            transient = max(transient, b * ln * (rank * gb + gb) + systems(b, ln))
+            transient = max(transient, in_flight(b, ln))
     landing = ((slots_u + slots_i) // n + n_users + n_items) * rank * 4
     return CapacityPlan(
         workload="als_fit",

@@ -159,22 +159,42 @@ def test_aot_fingerprint_mismatch_discards_export_and_recompiles():
 
 
 def test_aot_skips_disk_for_custom_call_programs():
-    """On CPU the Cholesky solve lowers to a LAPACK custom call, which is not
-    round-trip-safe (executing a deserialized copy in a fresh process can
+    """On CPU a library factorisation lowers to a LAPACK custom call, which is
+    not round-trip-safe (executing a deserialized copy in a fresh process can
     crash): such programs must stay memory-cached only — a second cold
     acquisition recompiles instead of reading a blob."""
+    import jax.numpy as jnp
+
+    from albedo_tpu.utils.aot import export_dir, persistent_aot_executable
+
+    def acquire():
+        return persistent_aot_executable(
+            jax.jit(jnp.linalg.cholesky), (jnp.eye(4, dtype=jnp.float32),), None, None,
+            key_parts=("test_cold_path", "library_cholesky"), name="library_cholesky")[2]
+
+    assert acquire() == "compile"
+    assert not list(export_dir().glob("library_cholesky-*.jaxexport"))
+    reset_memory_cache()
+    assert acquire() == "compile"
+
+
+def test_the_exact_fit_round_trips_from_disk_like_the_cg_fit():
+    """The exact solve is the program's own loops (no library factorisation,
+    so no custom call on any backend): its fused fit is exported, and a
+    second cold acquisition reads the blob and reproduces the factors."""
     from albedo_tpu.utils.aot import export_dir
 
     m = synthetic_stars(n_users=90, n_items=60, mean_stars=6, seed=19)
     als = ImplicitALS(rank=4, max_iter=2, seed=1, solver="cholesky")
-    als.fit(m)
+    first = als.fit(m)
     assert als.last_fit_report["compile_source"] == "compile"
-    assert not list(export_dir().glob("als_init_fit_fused-*.jaxexport"))
+    assert list(export_dir().glob("als_init_fit_fused-*.jaxexport"))
 
     reset_memory_cache()
     als2 = ImplicitALS(rank=4, max_iter=2, seed=1, solver="cholesky")
-    als2.fit(m)
-    assert als2.last_fit_report["compile_source"] == "compile"
+    second = als2.fit(m)
+    assert als2.last_fit_report["compile_source"] == "disk"
+    np.testing.assert_array_equal(first.user_factors, second.user_factors)
 
 
 def test_lru_cache_bounds_and_recency():

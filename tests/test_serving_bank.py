@@ -138,8 +138,9 @@ def test_generation_snapshot_als_wins_over_bank_als(world):
     import pandas as pd
 
     _tables, matrix, _model, als, tfidf, pop = world
-    stage = _stage(world)
-    pipe = TwoStagePipeline({"popularity": pop}, bank_stage=stage)
+    # asserts an undegraded answer, and its first request may compile
+    stage = _stage(world, timeout_s=PATIENT_S)
+    pipe = TwoStagePipeline({"popularity": pop}, bank_stage=stage, deadlines=PATIENT_DEADLINES)
 
     calls = {"n": 0}
     marker_repo = int(matrix.item_ids[0])
