@@ -448,6 +448,44 @@ def _report(job: str, metric_name: str, value: float, t0: float) -> None:
     print(f"[{job}] wall-clock = {time.time() - t0:.1f}s")
 
 
+# The counters of ``ImplicitALS.last_fit_report`` the operator's table prints
+# beside the spans, with every ``*_share`` key the path's report carries.
+_FIT_COUNTERS = (
+    "mode", "compile_source", "prep_cached", "dispatches", "rows_per_dispatch",
+    "exact_systems_per_sweep", "exact_lane_systems_per_sweep",
+)
+
+
+def _log_fit_table(job: str, estimators: list) -> None:
+    """The operator's table (ARCHITECTURE.md "Observability"), on standard
+    error after the job's REAL fits: where their seconds went, by span (each
+    estimator's ``last_fit_report["spans"]`` in one ``Timer``; of a
+    checkpointed fit, its last chunk's), then the counters of the last
+    fit's report. An estimator that ran no fit (a checkpoint that was
+    already complete) has no report and adds nothing."""
+    import sys
+
+    from albedo_tpu.utils.profiling import Timer
+
+    reports = [r for r in (getattr(e, "last_fit_report", None) for e in estimators)
+               if r and "spans" in r]
+    if not reports:
+        return
+
+    def log(row: str) -> None:
+        print(f"[{job}] {row}", file=sys.stderr)
+
+    spans = Timer()
+    for r in reports:
+        spans.absorb(r["spans"])
+    log(f"fit spans of {len(reports)} fit(s): seconds, calls, self seconds")
+    spans.report(log)
+    report = reports[-1]
+    counters = {k: report[k] for k in _FIT_COUNTERS if k in report}
+    counters.update((k, round(v, 4)) for k, v in report.items() if k.endswith("_share"))
+    log("fit counters: " + ", ".join(f"{k}={v}" for k, v in counters.items()))
+
+
 @register_job("popularity")
 def popularity_job(args) -> None:
     """``PopularityRecommenderBuilder`` (NDCG@30 gate 0.00202)."""
@@ -508,7 +546,8 @@ def train_als_job(args) -> None:
     # Sparsity print: the PySpark track's calculate_sparsity parity
     # (albedo_toolkit/common.py).
     print(f"[train_als] star-matrix sparsity = {ctx.matrix().sparsity():.6f}")
-    model = ctx.als_model()
+    # the span table of the real fit; nothing on an artifact-store hit
+    model = ctx.als_model(on_fit=lambda est, _model: _log_fit_table("train_als", [est]))
     rec = ALSRecommender(model, ctx.matrix(), top_k=TOP_K)
     users = ctx.matrix().user_ids[ctx.test_user_dense()]
     ndcg = ctx.evaluate_topk(rec.recommend_for_users(users))
@@ -524,6 +563,7 @@ def cv_als_job(args) -> None:
 
     t0 = time.time()
     ctx = JobContext(args)
+    fitted = []   # every estimator of the grid, for one span table
     grid = (
         param_grid(rank=[8, 16], reg_param=[0.1, 0.5], alpha=[1.0, 40.0])
         if ctx.small or not getattr(args, "tables", None)
@@ -537,6 +577,7 @@ def cv_als_job(args) -> None:
 
     def fit(params, train):
         est = ImplicitALS(max_iter=iters, solver=solver, cg_steps=cg_steps, **params)
+        fitted.append(est)
         every, _, _ = ctx.checkpoint_opts()
         if every > 0:
             # Per-(params, fold) checkpoint identity. cross_validate iterates
@@ -568,6 +609,7 @@ def cv_als_job(args) -> None:
 
     results = cross_validate(fit, evaluate, ctx.matrix(), grid, n_folds=2, verbose=True)
     best = results[0]
+    _log_fit_table("cv_als", fitted)
     print(f"[cv_als] best params = {best.params}")
     _report("cv_als", "NDCG@30", best.mean_metric, t0)
 

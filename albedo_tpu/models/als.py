@@ -65,14 +65,17 @@ from albedo_tpu.utils.profiling import Timer
 # exactly like they kill the resident path mid-checkpoint.
 _CHUNKED_FAULT = faults.site("als.chunked")
 
-# The spans a chunked (host-streamed) fit publishes in
+# The spans EVERY chunked (host-streamed) fit publishes in
 # ``last_fit_report["spans"]`` and, as ``albedo.<name>``, in a profiler trace
 # (``ImplicitALS._fit_chunked`` says what each is around). Tests and the
-# benchmark's ``fit_streamed`` driver import this tuple.
+# benchmark's ``fit_streamed`` driver import this tuple. Optional beside
+# them: ``fit.stream.acquire`` with the AOT layer's branches under it (a cold
+# estimator's one acquisition of every shape; the steady loop's look-up
+# carries no span) and ``fit.gc`` (absent when no full collection ran).
 CHUNKED_SPANS = (
     "fit", "fit.admission", "fit.prep", "fit.init",
     "fit.stream", "fit.stream.gramian", "fit.stream.upload",
-    "fit.stream.acquire", "fit.stream.dispatch", "fit.wait",
+    "fit.stream.dispatch", "fit.wait", "fit.report",
 )
 
 # The one length tier whose rows the chunked fit hands over more than
@@ -99,10 +102,11 @@ STREAM_MERGED_LEN = 1
 # The spans of a resident row-sharded fit (``sharded="resident"``,
 # ``shard_mode="allgather"``; ``ImplicitALS._fit_sharded_resident`` says what
 # each is around), for the tests and the benchmark's ``fit_sharded`` driver.
+# Optional beside them: ``fit.gc`` (absent when no full collection ran).
 SHARDED_SPANS = (
     "fit", "fit.prep", "fit.acquire", "fit.init",
     "fit.shard", "fit.shard.gramian", "fit.shard.assemble",
-    "fit.shard.dispatch", "fit.relayout", "fit.wait",
+    "fit.shard.dispatch", "fit.relayout", "fit.wait", "fit.report",
 )
 
 
@@ -742,14 +746,19 @@ class ImplicitALS:
         ``fit`` > ``fit.admission``, ``fit.prep`` (children: see
         :meth:`device_groups`), ``fit.acquire`` (children: see
         ``utils.aot.persistent_aot_executable``), ``fit.dispatch`` (scalars,
-        key and the compiled call until it returns) and ``fit.wait`` (the
-        health read that is the completion barrier).
+        key and the compiled call until it returns), ``fit.wait`` (the
+        health read that is the completion barrier), ``fit.report`` (this
+        report's own making, after the barrier) and, only where one ran,
+        ``fit.gc`` (the interpreter's full garbage collections inside this
+        call, ``Timer.collections``: each also inside whichever span it
+        interrupted). A span or a report key stays only with a reader
+        (PERF.md section 3 names each one's).
         """
         # Before admission, bucketing and the upload are paid for a fit that
         # no kernel can run.
         check_solver(self.solver)
         timer = Timer()
-        with timer.section("fit"):
+        with timer.section("fit"), timer.collections("fit"):
             t0 = time.perf_counter()
             path, admission = self._choose_path(matrix, timer)
             if path == "chunked":
@@ -824,9 +833,10 @@ class ImplicitALS:
         self, run: "_PathRun", path: str, admission, t0: float, timer: Timer,
         matrix: StarMatrix,
     ) -> dict:
-        """The completion barrier and ``last_fit_report`` (less ``spans``) of
-        every path: the keys the benchmark's readers take from whichever path
-        ran, then the keys that are the path's own (``run.own``)."""
+        """The completion barrier (``fit.wait``) and ``last_fit_report`` (less
+        ``spans``; its making is ``fit.report``) of every path: the keys the
+        benchmark's readers take from whichever path ran, then the keys that
+        are the path's own (``run.own``)."""
         # Completion barrier: one ~12-byte device->host read of the
         # divergence watchdog's on-device health vector (nonfinite count /
         # max-abs / RMS over BOTH factor tables, utils.watchdog). It depends
@@ -839,41 +849,45 @@ class ImplicitALS:
         with timer.section("fit.wait"):
             health = health_dict(factor_health(run.user_f, run.item_f))
         t2 = time.perf_counter()
-        prep_s = round(run.t1 - t0, 4)
-        # Systems the exact solve factorises a sweep, and the lanes it solves
-        # them at, over every device: each slot row of each bucket, empty
-        # slots among them (the ring mode hands back no shapes for the
-        # gather's counters, and says the ones it solved apart).
-        systems = lane_systems = 0
-        if self.solver == "cholesky":
-            solved = run.shapes if run.exact_shapes is None else run.exact_shapes
-            devices = run.own.get("n_shards", 1)
-            systems, lane_systems = devices * exact_systems(solved), devices * exact_lane_systems(solved)
-        rows = max(1, matrix.n_users + matrix.n_items)
-        return {
-            "prep_s": prep_s,
-            "bucket_s": prep_s if run.bucket_s is None else run.bucket_s,
-            "upload_s": run.upload_s,
-            "compile_s": round(run.compile_s, 4),
-            "compile_source": run.compile_source,
-            "device_s": round(t2 - run.t1 - run.compile_s, 4),
-            "prep_cached": run.prep_cached,
-            "health": health,
-            # the synchronous dataflow is the streamed mode's, told apart by
-            # the report's own ``pipelined``
-            "mode": "sharded_streamed" if path == "sharded_streamed_sync" else path,
-            "capacity": None if admission is None else admission.to_dict(),
-            "cg_gram_entry_share": (
-                cg_gram_entry_share(run.shapes, self.rank) if self.solver == "cg" else 0.0
-            ),
-            "gather_reformed_entry_share": gather_reformed_entry_share(run.shapes),
-            "gather_packed_entry_share": gather_packed_entry_share(run.shapes, self.rank),
-            "exact_systems_per_sweep": systems,
-            "exact_system_share": systems / rows,
-            "exact_lane_systems_per_sweep": lane_systems,
-            "exact_lane_share": lane_systems / rows,
-            **run.own,
-        }
+        # What is left is host arithmetic over the bucket shapes (milliseconds
+        # where a layout has a thousand of them), after the device is done.
+        with timer.section("fit.report"):
+            prep_s = round(run.t1 - t0, 4)
+            # Systems the exact solve factorises a sweep, and the lanes it solves
+            # them at, over every device: each slot row of each bucket, empty
+            # slots among them (the ring mode hands back no shapes for the
+            # gather's counters, and says the ones it solved apart).
+            systems = lane_systems = 0
+            if self.solver == "cholesky":
+                solved = run.shapes if run.exact_shapes is None else run.exact_shapes
+                devices = run.own.get("n_shards", 1)
+                systems = devices * exact_systems(solved)
+                lane_systems = devices * exact_lane_systems(solved)
+            rows = max(1, matrix.n_users + matrix.n_items)
+            return {
+                "prep_s": prep_s,
+                "bucket_s": prep_s if run.bucket_s is None else run.bucket_s,
+                "upload_s": run.upload_s,
+                "compile_s": round(run.compile_s, 4),
+                "compile_source": run.compile_source,
+                "device_s": round(t2 - run.t1 - run.compile_s, 4),
+                "prep_cached": run.prep_cached,
+                "health": health,
+                # the synchronous dataflow is the streamed mode's, told apart by
+                # the report's own ``pipelined``
+                "mode": "sharded_streamed" if path == "sharded_streamed_sync" else path,
+                "capacity": None if admission is None else admission.to_dict(),
+                "cg_gram_entry_share": (
+                    cg_gram_entry_share(run.shapes, self.rank) if self.solver == "cg" else 0.0
+                ),
+                "gather_reformed_entry_share": gather_reformed_entry_share(run.shapes),
+                "gather_packed_entry_share": gather_packed_entry_share(run.shapes, self.rank),
+                "exact_systems_per_sweep": systems,
+                "exact_system_share": systems / rows,
+                "exact_lane_systems_per_sweep": lane_systems,
+                "exact_lane_share": lane_systems / rows,
+                **run.own,
+            }
 
     def _fit_resident(
         self, matrix: StarMatrix, callback: Any | None, timer: Timer, admission
@@ -1022,8 +1036,8 @@ class ImplicitALS:
         Spans (``CHUNKED_SPANS``): ``fit.prep`` (host bucketing),
         ``fit.init`` (the seeded tables), one ``fit.stream`` a half-sweep
         with one ``fit.stream.gramian`` and, a bucket, ``fit.stream.upload``
-        (the slab's four arrays), ``fit.stream.acquire`` (the executable's
-        look-up) and ``fit.stream.dispatch`` (the compiled call);
+        (the slab's four arrays) and ``fit.stream.dispatch`` (the compiled
+        call; the executable's look-up between them carries no span);
         ``fit.wait`` is the health read that ends the fit. On a cold
         estimator one more ``fit.stream`` comes first, holding one
         ``fit.stream.acquire`` around the acquisition of every shape
@@ -1107,8 +1121,7 @@ class ImplicitALS:
                     with timer.section("fit.stream.upload"):
                         slab = (jnp.asarray(b.row_ids), jnp.asarray(b.idx),
                                 jnp.asarray(b.val), jnp.asarray(b.mask))
-                    with timer.section("fit.stream.acquire"):
-                        compiled = executables[source.shape[0], target.shape[0], b.shape]
+                    compiled = executables[source.shape[0], target.shape[0], b.shape]
                     with timer.section("fit.stream.dispatch"):
                         target = compiled(table, yty, target, *slab, reg, alpha)
             return target

@@ -1,0 +1,12 @@
+"""Milliseconds per sweep in which the device ran nothing while the host was
+uploading a bucket's slab: the idle gaps of the traced window whose innermost
+covering span is ``albedo.fit.stream.upload``, / sweeps, by the swept
+reduction (``benchmark/span_reads.py``; layer: host stream). 0.0 where the
+span ran and no gap fell under it; nothing where the trace holds no
+``albedo.*`` span."""
+
+from benchmark.span_reads import idle_ms_per_sweep
+
+
+def read(ctx):
+    return idle_ms_per_sweep(ctx, "fit.stream.upload", swept=True)

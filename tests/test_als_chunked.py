@@ -199,7 +199,8 @@ class TestStreamObservability:
         assert counts["fit.stream.upload"] == counts["fit.stream.dispatch"] == report["dispatches"]
         # a cold estimator: one more fit.stream holding the one acquisition of every shape
         assert counts["fit.stream"] == 2 * KW["max_iter"] + 1
-        assert counts["fit.stream.acquire"] == report["dispatches"] + 1
+        # (one span around it; the steady loop's look-up a bucket opens none)
+        assert counts["fit.stream.acquire"] == 1
         assert counts["fit.stream.gramian"] == 2 * KW["max_iter"]
         assert counts["fit.stream.acquire.lower_compile"] == report["chunked_shapes"]
         assert counts["fit.admission"] == counts["fit.init"] == counts["fit.wait"] == 1
@@ -239,8 +240,8 @@ class TestStreamObservability:
         assert report["chunked_shapes"] == shapes
         counts = report["spans"]["counts"]
         assert counts["fit.stream"] == 2 * KW["max_iter"]
-        assert counts["fit.stream.acquire"] == report["dispatches"]
-        assert not any(k.startswith("fit.stream.acquire.") for k in counts)
+        # the steady loop opens no fit.stream.acquire: a warm fit has none at all
+        assert not any(k.startswith("fit.stream.acquire") for k in counts)
         # nor is the matrix priced again: the verdict stays with its layout
         assert "fit.admission" not in counts
         np.testing.assert_array_equal(first.user_factors, second.user_factors)

@@ -87,6 +87,116 @@ def test_section_without_a_profiler_and_on_an_exception_still_counts():
     assert timer.totals["elsewhere"] == 2.0
 
 
+def test_report_prints_parents_first_with_self_time_and_marks_threaded_children():
+    timer = Timer()
+    timer.absorb({"totals": {"fit": 10.0, "fit.prep": 4.0, "fit.prep.upload": 6.0, "fit.wait": 5.0,
+                             "fit.gc": 3.0, "fit.acquire": 0.5},
+                  "counts": {"fit": 1, "fit.prep": 1, "fit.prep.upload": 32, "fit.wait": 1, "fit.gc": 2}})
+    timer.absorb({"totals": {"fit": 2.0, "fit.wait": 1.0}, "counts": {"fit": 1, "fit.wait": 1}})
+    rows = []
+    assert timer.report(rows.append) == timer.totals
+    assert rows[0].split() == ["span", "total", "s", "calls", "self", "s"]
+    body = [row.split() for row in rows[1:]]
+    assert [r[0] for r in body] == ["fit", "fit.acquire", "fit.gc", "fit.prep", "fit.prep.upload", "fit.wait"]
+    assert rows[1].startswith("fit ") and rows[2].startswith("  fit.acquire") and rows[5].startswith("    fit.prep.upload")
+    by_name = {r[0]: r[1:] for r in body}
+    # two fits absorbed; a missing count reads as one call; self = total less the direct children,
+    # the collections apart (they lie inside the other spans and are counted there too)
+    assert by_name["fit"] == ["12.000000", "2", f"{12.0 - 0.5 - 4.0 - 6.0:.6f}"]
+    assert by_name["fit.acquire"][1] == "1" and by_name["fit.gc"] == ["3.000000", "2", "3.000000"]
+    assert by_name["fit.wait"] == ["6.000000", "2", "6.000000"]
+    # children summed over threads past their parent's wall-clock: nought, and marked
+    assert by_name["fit.prep"] == ["4.000000", "1", "0.000000*"]
+
+
+@pytest.fixture
+def no_automatic_collections():
+    """Only a ``gc.collect()`` the test makes is a full collection."""
+    import gc
+
+    gc.collect()
+    gc.disable()
+    yield
+    gc.enable()
+
+
+def test_collections_are_a_span_only_while_the_block_runs_and_only_full_ones(tmp_path, no_automatic_collections):
+    import gc
+
+    before = list(gc.callbacks)
+    timer = Timer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with timer.section("job"), timer.collections("job"):
+            assert len(gc.callbacks) == len(before) + 1
+            gc.collect(0)
+            gc.collect(1)
+            with timer.section("job.part"):
+                gc.collect()
+            gc.collect()
+            assert "job.gc" not in timer.totals          # the hook takes no lock: added as the block ends
+        gc.collect()                                     # after the block: nobody listens
+        with pytest.raises(RuntimeError), timer.collections("job"):
+            raise RuntimeError("inside")
+    finally:
+        jax.profiler.stop_trace()
+    assert gc.callbacks == before
+    # two full collections; the younger generations' returned at once
+    assert timer.counts["job.gc"] == 2 and 0 < timer.totals["job.gc"] <= timer.totals["job"]
+    events = host_events(tmp_path)
+    assert len(events["albedo.job.gc"]) == 2
+    assert sum(events["albedo.job.gc"]) == pytest.approx(timer.totals["job.gc"], abs=MS)
+
+
+@pytest.mark.parametrize("path", ["resident", "chunked"])
+def test_fit_gc_is_published_when_a_collection_ran_inside_the_fit_and_only_then(
+        path, tmp_path, no_automatic_collections):
+    import gc
+
+    before = list(gc.callbacks)
+    m = stars()
+    als = ImplicitALS(rank=4, max_iter=2, seed=3, solver="cg", chunked=path == "chunked")
+    als.fit(m)                                            # no collection: no span
+    assert "fit.gc" not in als.last_fit_report["spans"]["totals"]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        als.fit(m, callback=lambda *_: gc.collect())      # one a sweep
+    finally:
+        jax.profiler.stop_trace()
+    totals, counts = als.last_fit_report["spans"]["totals"], als.last_fit_report["spans"]["counts"]
+    assert counts["fit.gc"] == 2 and 0 < totals["fit.gc"] < totals["fit"]
+    assert_children_within_parents(totals)
+    events = host_events(tmp_path)
+    assert len(events["albedo.fit.gc"]) == 2 and len(events["albedo.fit"]) == 1
+    assert sum(events["albedo.fit.gc"]) == pytest.approx(totals["fit.gc"], abs=MS)
+
+    def boom(*_):
+        raise RuntimeError("inside the fit")
+
+    with pytest.raises(RuntimeError, match="inside the fit"):
+        als.fit(m, callback=boom)
+    assert gc.callbacks == before                         # gone after a fit returns or raises
+
+
+def test_train_als_logs_the_span_table_after_a_real_fit_and_not_on_an_artifact_hit(capsys):
+    from albedo_tpu.cli import main
+
+    assert main(["train_als", "--small", "--solver", "cg"]) == 0
+    out, err = capsys.readouterr()
+    assert "[train_als] NDCG@30" in out and "fit spans" not in out      # the table is standard error's
+    table = [row for row in err.splitlines() if row.startswith("[train_als] ")]
+    assert table[0] == "[train_als] fit spans of 1 fit(s): seconds, calls, self seconds"
+    spans = [row.split()[1] for row in table[2:-1]]
+    assert spans[0] == "fit" and {"fit.prep", "fit.acquire", "fit.dispatch", "fit.wait"} <= set(spans)
+    assert spans == sorted(spans, key=lambda n: n.split("."))           # parents first
+    counters = table[-1]
+    assert counters.startswith("[train_als] fit counters: mode=resident, compile_source=compile")
+    assert "cg_gram_entry_share=" in counters and "exact_lane_share=0.0" in counters
+    assert main(["train_als", "--small", "--solver", "cg"]) == 0        # today's artifact: no fit
+    out, err = capsys.readouterr()
+    assert "[train_als] NDCG@30" in out and "fit spans" not in err and "fit counters" not in err
+
+
 def assert_children_within_parents(
     totals: dict[str, float], threaded: tuple[str, ...] = ("fit.stream.acquire.",)
 ) -> None:
@@ -117,11 +227,11 @@ def test_cold_fit_publishes_its_spans_beside_the_keys_they_refine(counted_clock)
     report = als.last_fit_report
     totals, counts = report["spans"]["totals"], report["spans"]["counts"]
     assert report["compile_source"] == "compile"
-    assert set(totals) == {
+    assert set(totals) - {"fit.gc"} == {     # (a full collection may fall into any fit)
         "fit", "fit.admission", "fit.prep", "fit.prep.index", "fit.prep.index.csr",
         "fit.prep.index.csc", "fit.prep.fill", "fit.prep.fill.user", "fit.prep.fill.item",
         "fit.prep.upload", "fit.acquire", "fit.acquire.export", "fit.acquire.lower_compile",
-        "fit.acquire.serialize", "fit.acquire.probe", "fit.dispatch", "fit.wait",
+        "fit.acquire.serialize", "fit.acquire.probe", "fit.dispatch", "fit.wait", "fit.report",
     }
     assert counts["fit"] == counts["fit.wait"] == counts["fit.acquire.probe"] == 1
     assert_children_within_parents(totals)
@@ -156,7 +266,7 @@ def test_warm_fit_in_one_process_has_no_children_to_report():
     als.fit(m)
     report = als.last_fit_report
     totals = report["spans"]["totals"]
-    assert set(totals) == {"fit", "fit.prep", "fit.acquire", "fit.dispatch", "fit.wait"}
+    assert set(totals) - {"fit.gc"} == {"fit", "fit.prep", "fit.acquire", "fit.dispatch", "fit.wait", "fit.report"}
     assert report["compile_s"] == 0.0 and totals["fit.acquire"] < MS
     assert totals["fit"] - totals["fit.wait"] < 50 * MS   # what fit_host_ms reads
 

@@ -53,7 +53,9 @@ def test_timer_sections(capsys):
     totals = t.report()
     assert t.counts["a"] == 2 and t.counts["b"] == 1
     assert set(totals) == {"a", "b"}
-    assert "a:" in capsys.readouterr().out
+    out = capsys.readouterr().out.splitlines()
+    assert out[0].split() == ["span", "total", "s", "calls", "self", "s"]
+    assert [row.split()[0] for row in out[1:]] == ["a", "b"] and out[1].split()[2] == "2"
 
 
 def test_schema_helpers():
