@@ -51,6 +51,7 @@ from albedo_tpu.ops.als import (
     gather_reformed_entry_share,
     gather_table,
     gramian,
+    relayout_rows,
     scanned_shape,
     seeded_factors,
 )
@@ -73,7 +74,7 @@ _CHUNKED_FAULT = faults.site("als.chunked")
 # estimator's one acquisition of every shape; the steady loop's look-up
 # carries no span) and ``fit.gc`` (absent when no full collection ran).
 CHUNKED_SPANS = (
-    "fit", "fit.admission", "fit.prep", "fit.init",
+    "fit", "fit.admission", "fit.prep", "fit.init", "fit.relayout",
     "fit.stream", "fit.stream.gramian", "fit.stream.upload",
     "fit.stream.dispatch", "fit.wait", "fit.report",
 )
@@ -250,6 +251,95 @@ def _landing_perm(buckets: list[Bucket], n_target: int) -> np.ndarray:
 
 
 
+def _dispatch_order(buckets: list[Bucket], n_rows: int) -> tuple[np.ndarray, np.ndarray]:
+    """One side's dispatch order for the chunked fit: ``(order, pos)``.
+    ``order[p]`` is the logical row at position ``p`` - every bucket's slots
+    in half-sweep order, -1 on a padding slot (its own row, holding zeros),
+    then the rows no bucket holds (a row with no stars keeps its factor) -
+    and ``pos[r]`` the position of logical row ``r``."""
+    slots = np.concatenate([b.row_ids for b in buckets] or [np.zeros(0, np.int32)])
+    held = np.zeros(n_rows, bool)
+    held[slots[slots >= 0]] = True
+    order = np.concatenate([slots, np.flatnonzero(~held)]).astype(np.int32)
+    valid = order >= 0
+    pos = np.empty(n_rows, np.int32)
+    pos[order[valid]] = np.flatnonzero(valid)
+    return order, pos
+
+
+@dataclasses.dataclass
+class StreamLayout:
+    """The chunked fit's layout with both factor tables held in DISPATCH
+    ORDER (:func:`_dispatch_order`): a bucket's slots are one contiguous
+    block of its target table, so ``ops.als.chunked_bucket_update`` warm
+    starts from it with one slice and lands it with one block write. The
+    buckets are ``_host_buckets(stream=True)``'s relabelled once: ``idx``
+    into the source side's positions, ``row_ids`` into the target's (the
+    block's offset + slot, -1 still on padding slots); ``val`` and ``mask``
+    are the same arrays. ``rows`` holds the relayouts' index vectors on the
+    device, per side ``(order, pos)``, uploaded once with the layout."""
+
+    user_buckets: list[Bucket]
+    item_buckets: list[Bucket]
+    user_order: np.ndarray
+    user_pos: np.ndarray
+    item_order: np.ndarray
+    item_pos: np.ndarray
+    landed_in_place_share: float  # rows whose bucket is its block ÷ all rows landed
+    rows: dict | None = None
+
+    @classmethod
+    def build(cls, user: list[Bucket], item: list[Bucket], n_users: int, n_items: int,
+              workers: int | None) -> "StreamLayout":
+        (u_order, u_pos), (i_order, i_pos) = (
+            _dispatch_order(user, n_users), _dispatch_order(item, n_items))
+
+        def relabel(buckets, source_pos):
+            offsets = np.cumsum([0] + [b.shape[0] for b in buckets])
+
+            def one(j: int) -> Bucket:
+                b = buckets[j]
+                block = offsets[j] + np.arange(b.shape[0], dtype=np.int32)
+                return Bucket(row_ids=np.where(b.row_ids >= 0, block, -1).astype(np.int32),
+                              idx=np.take(source_pos, b.idx), val=b.val, mask=b.mask)
+
+            if workers and len(buckets) > 1:
+                with ThreadPoolExecutor(max_workers=workers) as pool:
+                    return list(pool.map(one, range(len(buckets))))
+            return [one(j) for j in range(len(buckets))]
+
+        user_d, item_d = relabel(user, i_pos), relabel(item, u_pos)
+
+        def in_place(buckets, n_table):
+            """Rows a bucket lands as the block its slots are."""
+            rows = 0
+            for b in buckets:
+                valid = b.row_ids >= 0
+                start = int(b.row_ids[0])
+                if (valid[0] and start + b.shape[0] <= n_table
+                        and np.array_equal(b.row_ids[valid], start + np.flatnonzero(valid))):
+                    rows += int(valid.sum())
+            return rows
+
+        landed = sum(int((b.row_ids >= 0).sum()) for b in (*user, *item))
+        share = (in_place(user_d, u_order.size) + in_place(item_d, i_order.size)) / max(1, landed)
+        return cls(user_d, item_d, u_order, u_pos, i_order, i_pos, share)
+
+    def slot_row_share(self) -> float:
+        """Rows the dispatch-order tables hold ÷ logical rows."""
+        return (self.user_order.size + self.item_order.size) / max(
+            1, self.user_pos.size + self.item_pos.size)
+
+    def device_rows(self) -> dict:
+        """``{"user": (order, pos), "item": (order, pos)}`` on the device."""
+        if self.rows is None:
+            self.rows = {
+                "user": (jnp.asarray(self.user_order), jnp.asarray(self.user_pos)),
+                "item": (jnp.asarray(self.item_order), jnp.asarray(self.item_pos)),
+            }
+        return self.rows
+
+
 def _shard_landing_perm(groups: list[Bucket], n_shards: int, rows_per: int) -> np.ndarray:
     """``_landing_perm`` of every shard of own-rows shape groups
     (``ragged.shard_grouped_bucket_rows``: shard ``d``'s slots of a bucket
@@ -410,6 +500,19 @@ class ImplicitALS:
                     bucket_rows(*csx, rows_of=allowance, **self._layout_kwargs())
                     for csx, allowance in zip((matrix.csr(), matrix.csc()), rows_of)
                 )
+        return cache[key]
+
+    def _stream_layout(self, matrix: StarMatrix) -> StreamLayout:
+        """The chunked fit's layout in dispatch order (:class:`StreamLayout`),
+        built from ``_host_buckets(matrix, stream=True)`` once and kept with
+        it, under the same settings."""
+        key = ("stream_layout", self.batch_size, self.max_entries, self.max_len,
+               self.rank, self.gather_dtype, self.solver)
+        cache = _matrix_cache(matrix)
+        if key not in cache:
+            user, item = self._host_buckets(matrix, stream=True)
+            cache[key] = StreamLayout.build(
+                user, item, matrix.n_users, matrix.n_items, _bucket_workers())
         return cache[key]
 
     def _dispatch_rows(self, shapes: list[tuple[int, int]]):
@@ -886,6 +989,10 @@ class ImplicitALS:
                 "exact_system_share": systems / rows,
                 "exact_lane_systems_per_sweep": lane_systems,
                 "exact_lane_share": lane_systems / rows,
+                # the chunked fit's own, where its tables are held in
+                # dispatch order (StreamLayout); no other path lands a block
+                "landed_in_place_share": 0.0,
+                "stream_slot_row_share": 0.0,
                 **run.own,
             }
 
@@ -991,14 +1098,16 @@ class ImplicitALS:
             jax.random.PRNGKey(self.seed), matrix.n_users, matrix.n_items, self.rank
         )
 
-    def _chunked_executables(self, matrix: StarMatrix) -> dict:
+    def _chunked_executables(self, matrix: StarMatrix, kind: str = "shapes") -> dict:
         """The chunked path's per-shape executables, kept with the matrix's
         layout (``_matrix_cache``) under this estimator's compile-time
         settings and keyed by ``(n_source, n_target, bucket shape)``: a
         second fit of the same estimator and matrix dispatches them as they
-        are, as the resident path finds its one program."""
+        are, as the resident path finds its one program. ``kind=
+        "relayouts"``: the tables' relayouts, keyed by ``(rows in, rows
+        out)``."""
         key = (
-            "chunked_executables", self.solver, self.cg_steps, self.gather_dtype,
+            "chunked_executables", kind, self.solver, self.cg_steps, self.gather_dtype,
             self.rank, jax.default_backend(),
         )
         return _matrix_cache(matrix).setdefault(key, {})
@@ -1015,39 +1124,50 @@ class ImplicitALS:
         numerics-parity with the resident path (pinned by
         ``tests/test_als_chunked.py``) — slower, never dead. The layout is
         the path's own (``_host_buckets(matrix, stream=True)``): a bucket is
-        a dispatch here. Measured on one v5e at 10M x 1M x 100M stars, rank 128
-        (``gh10m-r128.fit-streamed``, PERF.md sections 5 and 6, PR 34):
-        3,375 ms a sweep over 1,107 dispatches against a resident plan that
-        does not fit; the device's programs are 3,315 of them (gather 38%, CG
-        36%, the landing scatter 22%: 70 ns a row landed in the 10M-row
-        table) and set the pace, the chip idle 0.3% of a fit; the host's
-        uploads and dispatches (a millisecond a bucket between them when
-        nothing is in their way) run ahead of it. Per-shape
+        a dispatch here, and both tables are held in the layout's DISPATCH
+        ORDER (:meth:`_stream_layout`) from after the seeded draw to before
+        the health read, so a bucket's rows are one block of its target
+        table: the warm start is one slice and the landing one block write.
+        Measured on one v5e at 10M x 1M x 100M stars, rank 128
+        (``gh10m-r128.fit-streamed``, PERF.md sections 5 and 6, PR 38):
+        2,797 ms a sweep over 1,107 dispatches against a resident plan that
+        does not fit, where a row scatter landing 70 ns a row (736 ms a
+        sweep) held it at 3,372; the block write lands a row in 4 ns (44 ms
+        a sweep) and the relayouts cost 25 ms a sweep. The device's programs
+        are 2,551 ms of a sweep, and the host now sets the pace: its uploads
+        (1.56 s a sweep, 1.4 GB/s through ``jnp.asarray``) and calls (1.31
+        s) come to more, and the chip waits 14% of a fit under the uploads. Per-shape
         executables are acquired through the
         persistent AOT layer, NOT bare jit: chunked fits run in exactly the
         kill-resume chaos that exposed the PR 4 XLA-cache custom-call
         corruption, so their cross-process executable reuse must stay
-        fingerprint-verified too. Every shape of the layout is acquired
+        fingerprint-verified too. Every shape of the layout, and the
+        relayout of each table each way, is acquired
         ahead of the first sweep, side by side on a thread pool (abstract
         arguments: no table exists yet, so a probe's tables are the only
         ones on the device), and kept with the matrix for the estimator's
         later fits (:meth:`_chunked_executables`).
 
-        Spans (``CHUNKED_SPANS``): ``fit.prep`` (host bucketing),
-        ``fit.init`` (the seeded tables), one ``fit.stream`` a half-sweep
+        Spans (``CHUNKED_SPANS``): ``fit.prep`` (host bucketing, the
+        relabelling into dispatch order and the relayouts' index vectors'
+        upload), ``fit.init`` (the seeded tables), ``fit.relayout`` (twice:
+        the tables into dispatch order, and back into logical order after
+        the last half-sweep), one ``fit.stream`` a half-sweep
         with one ``fit.stream.gramian`` and, a bucket, ``fit.stream.upload``
         (the slab's four arrays) and ``fit.stream.dispatch`` (the compiled
         call; the executable's look-up between them carries no span);
         ``fit.wait`` is the health read that ends the fit. On a cold
         estimator one more ``fit.stream`` comes first, holding one
-        ``fit.stream.acquire`` around the acquisition of every shape
+        ``fit.stream.acquire`` around the acquisition of every program
         (``fit.acquire`` = ``compile_s`` repeats its wall-clock; the AOT
         layer's branches under it are thread-seconds). The host runs ahead
         of the device: a span is the host's time in the call, and what the
         device still owes is in ``fit.wait``.
         """
         with timer.section("fit.prep"):
-            user_buckets, item_buckets = self._host_buckets(matrix, stream=True)
+            layout = self._stream_layout(matrix)
+            rows = layout.device_rows()
+        user_buckets, item_buckets = layout.user_buckets, layout.item_buckets
         t1 = time.perf_counter()
 
         statics = dict(
@@ -1055,14 +1175,17 @@ class ImplicitALS:
             gather_dtype=self.gather_dtype,
         )
         executables = self._chunked_executables(matrix)
+        relayouts = self._chunked_executables(matrix, "relayouts")
         compile_sources: set[str] = set()
         dev = jax.devices()[0]
+        f32, i32 = jnp.float32, jnp.int32
+        sds = jax.ShapeDtypeStruct
+        key_parts = ("als_chunked", jax.__version__, jax.default_backend(),
+                     getattr(dev, "device_kind", "?"))
 
         def acquire(n_source: int, n_target: int, shape: tuple):
             """One shape's executable and where it came from, from abstract
             arguments."""
-            f32, i32 = jnp.float32, jnp.int32
-            sds = jax.ShapeDtypeStruct
             args = (
                 # the fixed side's table in the form the gather reads it
                 jax.eval_shape(gather_table, sds((n_source, self.rank), f32)),
@@ -1074,9 +1197,7 @@ class ImplicitALS:
             compiled, _, source_tag = persistent_aot_executable(
                 chunked_bucket_update, args, None, statics,
                 key_parts=(
-                    "als_chunked", jax.__version__, jax.default_backend(),
-                    getattr(dev, "device_kind", "?"),
-                    self.solver, self.cg_steps, self.gather_dtype,
+                    *key_parts, self.solver, self.cg_steps, self.gather_dtype,
                     self.rank, n_source, n_target, shape,
                 ),
                 name="als_chunked", timer=timer, span="fit.stream.acquire",
@@ -1084,21 +1205,35 @@ class ImplicitALS:
             )
             return compiled, source_tag
 
+        def acquire_relayout(n_rows: int, n_out: int):
+            """A table of ``n_rows`` rows into ``n_out`` rows of the other order."""
+            args = (sds((n_rows, self.rank), f32), sds((n_out,), i32))
+            compiled, _, source_tag = persistent_aot_executable(
+                relayout_rows, args, None, None,
+                key_parts=(*key_parts, "relayout", self.rank, n_rows, n_out),
+                name="als_chunked_relayout", timer=timer, span="fit.stream.acquire",
+            )
+            return compiled, source_tag
+
+        n_user, n_item = layout.user_order.size, layout.item_order.size
         sides = (
-            (matrix.n_users, matrix.n_items, item_buckets),
-            (matrix.n_items, matrix.n_users, user_buckets),
+            (n_user, n_item, item_buckets),
+            (n_item, n_user, user_buckets),
         )
-        missing = list(dict.fromkeys(
-            key for n_source, n_target, buckets in sides for b in buckets
-            if (key := (n_source, n_target, b.shape)) not in executables
-        ))
+        missing = [(acquire, key) for key in dict.fromkeys(
+            (n_source, n_target, b.shape) for n_source, n_target, buckets in sides
+            for b in buckets) if key not in executables]
+        missing += [(acquire_relayout, key) for key in dict.fromkeys((
+            (matrix.n_users, n_user), (n_user, matrix.n_users),
+            (matrix.n_items, n_item), (n_item, matrix.n_items),
+        )) if key not in relayouts]
         compile_s = 0.0
         if missing:
             with timer.section("fit.stream"), timer.section("fit.stream.acquire"), \
                     ThreadPoolExecutor(max_workers=_bucket_workers() or 1) as pool:
-                for key, (compiled, source_tag) in zip(
-                        missing, pool.map(lambda k: acquire(*k), missing)):
-                    executables[key] = compiled
+                for (how, key), (compiled, source_tag) in zip(
+                        missing, pool.map(lambda task: task[0](*task[1]), missing)):
+                    (executables if how is acquire else relayouts)[key] = compiled
                     compile_sources.add(source_tag)
             compile_s = time.perf_counter() - t1
         timer.add("fit.acquire", compile_s)
@@ -1107,6 +1242,15 @@ class ImplicitALS:
             user_f, item_f = self._initial_factors(matrix)
             reg = jnp.float32(self.reg_param)
             alpha = jnp.float32(self.alpha)
+
+        def relayout(table, side: str, back: bool = False):
+            """``table`` into dispatch order, or ``back`` into logical order."""
+            index = rows[side][1 if back else 0]
+            return relayouts[table.shape[0], index.shape[0]](table, index)
+
+        with timer.section("fit.relayout"):
+            user_f = relayout(user_f, "user")
+            item_f = relayout(item_f, "item")
 
         def half_sweep(source, target, buckets):
             # The chaos hook: an armed kill dies genuinely mid-stream; an
@@ -1131,13 +1275,19 @@ class ImplicitALS:
             item_f = half_sweep(user_f, item_f, item_buckets)
             user_f = half_sweep(item_f, user_f, user_buckets)
             if callback is not None:
-                # Checkpoint-callback host copies, by contract (see fit()).
+                # Checkpoint-callback host copies, by contract (see fit()),
+                # of the tables in logical order.
+                user_l, item_l = relayout(user_f, "user", True), relayout(item_f, "item", True)
                 # albedo: noqa[hidden-host-sync]
-                callback(it, np.asarray(user_f), np.asarray(item_f))
+                callback(it, np.asarray(user_l), np.asarray(item_l))
+
+        with timer.section("fit.relayout"):
+            user_f = relayout(user_f, "user", back=True)
+            item_f = relayout(item_f, "item", back=True)
 
         n_buckets = {"user": len(user_buckets), "item": len(item_buckets)}
         shapes = [b.shape for b in (*user_buckets, *item_buckets)]
-        rows = sorted(b for b, _ in shapes) or [0]
+        slots = sorted(b for b, _ in shapes) or [0]
         return _PathRun(
             user_f, item_f, t1, shapes,
             compile_s, "+".join(sorted(compile_sources)) or None,
@@ -1151,11 +1301,13 @@ class ImplicitALS:
                 # slot rows a dispatch carries, and the share of the padded
                 # entries that travel in a dispatch of more than batch_size
                 # rows (_dispatch_rows: none does in the resident layout)
-                "rows_per_dispatch": {"median": rows[len(rows) // 2], "largest": rows[-1]},
+                "rows_per_dispatch": {"median": slots[len(slots) // 2], "largest": slots[-1]},
                 "merged_entry_share": (
                     sum(b * ln for b, ln in shapes if b > self.batch_size)
                     / max(1, sum(b * ln for b, ln in shapes))
                 ),
+                "landed_in_place_share": layout.landed_in_place_share,
+                "stream_slot_row_share": layout.slot_row_share(),
             },
             # Host seconds in the per-bucket uploads (inside device_s).
             upload_s=round(timer.totals.get("fit.stream.upload", 0.0), 4),

@@ -49,8 +49,9 @@ source says and not by what kind of fusion XLA emitted: ``als.init``,
 it), ``als.cholesky`` (``.build``, ``.factor``, ``.solve`` inside it),
 ``als.landing``. They sit in the shared bodies, so the
 chunked and sharded paths inherit them; the chunked path's per-bucket landing
-scatter into the donated table is ``als.chunk.scatter`` (around its
-``als.landing``).
+into the donated table - one block write since PR 38, its tables held in
+dispatch order - is ``als.chunk.scatter`` (around its ``als.landing``), and
+its relayout of a table into that order and back ``als.chunk.relayout``.
 """
 
 from __future__ import annotations
@@ -75,9 +76,10 @@ def scatter_solved(
 ) -> jax.Array:
     """Land a solved block into ``target``: padding slots (``row_ids == -1``)
     scatter out of bounds and drop. One definition of the landing contract —
-    shared by the per-bucket reference path, the chunked host-streamed path,
-    and the scan fallback; the sharded landing (``parallel.als.
-    _landing_scatter``) is the owner-shard variant of the same rule."""
+    shared by the per-bucket reference path and the scan fallback; the
+    sharded landing (``parallel.als._landing_scatter``) is the owner-shard
+    variant of the same rule, the chunked path's block write
+    (``chunked_bucket_update``) the dispatch-order one."""
     with jax.named_scope("als.landing"):
         safe_rows = jnp.where(row_ids < 0, target.shape[0], row_ids)
         return target.at[safe_rows].set(solved, mode="drop")
@@ -671,7 +673,7 @@ def check_solver(solver: str) -> None:
 
 def solve_rows(
     source, yty, target, row_ids, idx, val, mask, reg, alpha,
-    solver: str, cg_steps: int, gather_dtype,
+    solver: str, cg_steps: int, gather_dtype, x0=None,
 ) -> jax.Array:
     """One bucket's solved ``(B, k)`` block, by the kernel ``solver`` names:
     ``"cholesky"`` the exact MLlib-parity solve (``bucket_solve_body``),
@@ -679,14 +681,17 @@ def solve_rows(
     (``bucket_cg_body``). Arguments as ``chunked_bucket_update``'s: ``source``
     serves the gather alone, so it comes in the gather's form
     (``gather_table``, built where ``yty`` is); ``target`` and ``row_ids`` are
-    read under ``"cg"`` only. The one place a
+    read under ``"cg"`` only, by ``warm_start``, unless ``x0`` hands in the
+    bucket's current rows already read (the chunked program reads them as
+    one block). The one place a
     kernel is chosen: the fused sweep, the chunked per-bucket program, the
     eager reference and the mesh's assembled solve (``parallel.als``, which
     hands in its all-gathered tables) all trace this, so a change to either
     body reaches every path."""
     check_solver(solver)
     if solver == "cg":
-        x0 = warm_start(target, row_ids)
+        if x0 is None:
+            x0 = warm_start(target, row_ids)
         return bucket_cg_body(
             source, yty, idx, val, mask, x0, reg, alpha, cg_steps,
             gather_dtype=gather_dtype,
@@ -727,11 +732,11 @@ def solve_bucket(
     static_argnames=("solver", "cg_steps", "gather_dtype"),
 )
 def chunked_bucket_update(
-    source: jax.Array,   # gather_table of the (n_source, k) fixed side's factors
+    source: jax.Array,   # gather_table of the fixed side's factors, dispatch order
     yty: jax.Array,      # (k, k) gramian of those factors
-    target: jax.Array,   # (n_target, k) factors being updated (donated)
-    row_ids: jax.Array,  # (B,) int32 target rows, -1 on padding slots
-    idx: jax.Array,      # (B, L) int32 indices into `source`
+    target: jax.Array,   # (n_target, k) factors being updated, dispatch order (donated)
+    row_ids: jax.Array,  # (B,) int32 target positions offset + slot, -1 on padding slots
+    idx: jax.Array,      # (B, L) int32 positions in `source`
     val: jax.Array,      # (B, L) float32 ratings, 0 on padding
     mask: jax.Array,     # (B, L) bool
     reg: jax.Array,      # () float32 regParam
@@ -744,16 +749,46 @@ def chunked_bucket_update(
     (``models.als`` under a ``degrade`` capacity verdict): the bucket slab
     arrives fresh from the host per call, only the factor tables stay
     device-resident. Same kernels as the fused sweep (``solve_rows``) so the
-    fallback is numerics-parity with the resident path; each target row
-    appears in exactly one bucket, so the sequential scatters land exactly
-    what the fused landing gather lands.
+    fallback is numerics-parity with the resident path.
+
+    The landing contract is this path's alone: both tables are held in the
+    chunked fit's dispatch order (``models.als.StreamLayout``), where the
+    bucket's ``B`` slots ARE the contiguous block ``[row_ids[0],
+    row_ids[0] + B)`` of ``target`` (slot 0 always holds a row; a padding
+    slot has a row of its own, holding zeros). So the warm start reads the
+    block with one slice and the landing writes it back with one
+    ``dynamic_update_slice`` into the donated table: 1.7-2.9 ns a row alone
+    where a row scatter took 72-76 ns at any call size, and a bucket's whole
+    program 27-31 ns a row where it took 105-107 at one entry a row (one
+    v5e, rank 128, 10M-row table: PERF.md section 6, PR 38). A padding slot lands
+    zeros, which keeps ``gramian`` of the table equal to the logical table's
+    and no empty row's 0/0 in it. The block never reaches past the table's
+    end, so the slice is never clamped.
     """
+    n_slots = row_ids.shape[0]
+    offset = row_ids[0]
+    x0 = None
+    if solver == "cg":
+        with jax.named_scope("als.warm_start"):
+            x0 = jax.lax.dynamic_slice_in_dim(target, offset, n_slots)
     solved = solve_rows(
         source, yty, target, row_ids, idx, val, mask, reg, alpha,
-        solver, cg_steps, gather_dtype,
+        solver, cg_steps, gather_dtype, x0=x0,
     )
-    with jax.named_scope("als.chunk.scatter"):
-        return scatter_solved(target, row_ids, solved)
+    with jax.named_scope("als.chunk.scatter"), jax.named_scope("als.landing"):
+        block = jnp.where((row_ids >= 0)[:, None], solved, 0.0)
+        return jax.lax.dynamic_update_slice_in_dim(target, block, offset, 0)
+
+
+@jax.jit
+def relayout_rows(table: jax.Array, rows: jax.Array) -> jax.Array:
+    """``table[rows]`` with zeros where ``rows < 0``: a factor table into the
+    chunked fit's dispatch order (``rows`` = the logical row at each
+    position, -1 on a padding slot's), and back (``rows`` = each logical
+    row's position). Once a table a fit, each way (span ``fit.relayout``)."""
+    with jax.named_scope("als.chunk.relayout"):
+        picked = table[jnp.maximum(rows, 0)]
+        return jnp.where((rows >= 0)[:, None], picked, 0.0)
 
 
 def als_half_sweep(

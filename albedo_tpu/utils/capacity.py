@@ -650,6 +650,20 @@ def chunked_row_bytes(ln: int, rank: int, gather_dtype: str | None, solver: str)
     )
 
 
+def stream_table_rows(shapes: list[tuple[int, int]], n_rows: int) -> int:
+    """Rows of one factor table held in the chunked fit's dispatch order
+    (``models.als.StreamLayout``), at most: every logical row once, and a
+    row of its own for each padding slot. Only a length tier's last bucket
+    has padding slots, fewer than 1,024 and fewer than the tier's rows
+    (``ragged.plan_buckets``' slot tiers), whatever rows a dispatch of the
+    tier carries, so the planner's shapes at ``batch_size`` rows bound the
+    streamed layout's too."""
+    tiers: dict[int, int] = {}
+    for b, ln in shapes:
+        tiers[ln] = tiers.get(ln, 0) + b
+    return n_rows + sum(min(1023, slots) for slots in tiers.values())
+
+
 def plan_fit_chunked(
     bucket_shapes_user: list[tuple[int, int]],
     bucket_shapes_item: list[tuple[int, int]],
@@ -660,21 +674,28 @@ def plan_fit_chunked(
     solver: str,
 ) -> CapacityPlan:
     """Price the chunked host-streamed fallback: only the factor tables stay
-    resident; one bucket's slab, gather block and solve
-    (:func:`chunked_row_bytes` a slot row) is in flight at a time. The shapes
+    resident, in dispatch order (:func:`stream_table_rows`), with the
+    relayouts' index vectors (a position a row each way); one bucket's
+    slab, gather block and solve (:func:`chunked_row_bytes` a slot row) is
+    in flight at a time. The shapes
     are the planner's at ``batch_size`` rows: the fit itself fills a dispatch
     of one-entry rows up to its side's worst of them and no further, so their
-    worst bounds its own.
+    worst bounds its own. Once a fit each way, a relayout holds the larger
+    table in its other order beside both (``relayout_copy``).
 
     An upper bound, and it stays one: a row is never priced under a
     ``(rank, rank)`` system and one rank-vector beside its slab and block,
     what this rung was admitted at before the solve was priced by what it
     builds. Under CG that holds the short rows' buckets (no system) at the old
     price, so the rung's total does not fall while the chip's peak has not
-    (10M x 1M x 100M stars at rank 128: 6,930,038,784 B before and after,
-    against a measured peak of 11.70 -> 11.72 GB; PERF.md section 6, PR 34)."""
+    (10M x 1M x 100M stars at rank 128: 6,930,038,784 B before and after PR
+    34; PERF.md section 6). The relayout's copy and a bucket in flight are
+    priced side by side: the host runs ahead, so the relayout back into
+    logical order is placed while the last buckets still run - the fit's
+    measured peak, 11.94 GB against tables of 5.63 (one v5e, PR 38)."""
     gb = _dtype_bytes(gather_dtype)
-    tables = (n_users + n_items) * rank * 4
+    user_rows = stream_table_rows(bucket_shapes_user, n_users)
+    item_rows = stream_table_rows(bucket_shapes_item, n_items)
 
     def row(ln: int) -> int:
         held = bucket_slab_bytes(1, ln) + ln * (rank * gb + gb) + rank * rank * 4 + rank * 4
@@ -686,7 +707,12 @@ def plan_fit_chunked(
     )
     return CapacityPlan(
         workload="als_fit_chunked",
-        items={"factor_tables": tables, "worst_bucket_in_flight": worst},
+        items={
+            "factor_tables": (user_rows + item_rows) * rank * 4,
+            "relayout_rows": (user_rows + n_users + item_rows + n_items) * 4,
+            "worst_bucket_in_flight": worst,
+            "relayout_copy": max(n_users, n_items) * rank * 4,
+        },
     )
 
 

@@ -350,7 +350,8 @@ SHARED_REPORT_KEYS = {
     "cg_gram_entry_share": float, "gather_reformed_entry_share": float,
     "gather_packed_entry_share": float, "exact_systems_per_sweep": int,
     "exact_system_share": float, "exact_lane_systems_per_sweep": int,
-    "exact_lane_share": float, "spans": dict,
+    "exact_lane_share": float, "landed_in_place_share": float,
+    "stream_slot_row_share": float, "spans": dict,
 }
 OWN_REPORT_KEYS = {
     "resident": {"capacity_cross_check"},

@@ -202,7 +202,9 @@ class TestStreamObservability:
         # (one span around it; the steady loop's look-up a bucket opens none)
         assert counts["fit.stream.acquire"] == 1
         assert counts["fit.stream.gramian"] == 2 * KW["max_iter"]
-        assert counts["fit.stream.acquire.lower_compile"] == report["chunked_shapes"]
+        # every bucket shape's program, and each table's relayout each way
+        assert counts["fit.stream.acquire.lower_compile"] == report["chunked_shapes"] + 4
+        assert counts["fit.relayout"] == 2
         assert counts["fit.admission"] == counts["fit.init"] == counts["fit.wait"] == 1
         # by hand: int32 row ids, int32 indices, float32 values, one-byte mask
         by_hand = sum(
@@ -416,3 +418,143 @@ def test_chunked_fit_against_the_plain_reference(monkeypatch):
         assert err.max() < 2e-4, err.max()
     init = reference.init_factors(13, m.n_users, m.n_items, est.rank)
     assert np.abs(model.user_factors - np.asarray(init[0])).max() > 0.05   # it moved
+
+
+def _holed_matrix():
+    """``_wide_matrix`` with one user and one repository that have no star
+    (the tables' last rows and a row in the middle of each): rows that no
+    bucket holds, so they sit after every slot in dispatch order."""
+    import dataclasses
+
+    m = _wide_matrix()
+    keep = (m.rows != 17) & (m.cols != 40)
+    return dataclasses.replace(
+        m, user_ids=np.arange(m.n_users + 1), item_ids=np.arange(m.n_items + 1),
+        rows=m.rows[keep], cols=m.cols[keep], vals=m.vals[keep])
+
+
+class TestDispatchOrder:
+    """Both tables held in the chunked fit's dispatch order
+    (``models.als.StreamLayout``): a bucket's slots are one block of its
+    target table, landed by one block write."""
+
+    @pytest.mark.parametrize("warm", [False, True])
+    @pytest.mark.parametrize("solver", ["cg", "cholesky"])
+    def test_the_dispatch_order_fit_equals_the_resident_fit(self, solver, warm):
+        m = _holed_matrix()
+        init = None
+        if warm:
+            rng = np.random.default_rng(5)
+            init = (rng.normal(0, 0.3, (m.n_users, 8)).astype(np.float32),
+                    rng.normal(0, 0.3, (m.n_items, 8)).astype(np.float32))
+        kw = dict(MERGE_KW, solver=solver, init_factors=init)
+        resident = ImplicitALS(**kw, chunked=False).fit(m)
+        est = ImplicitALS(**kw, chunked=True)
+        chunked = est.fit(m)
+        layout = est._stream_layout(m)
+        # what the layout has to show: padding slots, a merged one-entry
+        # dispatch, and a row on either side that no bucket holds
+        buckets = (*layout.user_buckets, *layout.item_buckets)
+        assert any((b.row_ids < 0).any() for b in buckets)
+        assert any(b.shape[0] > MERGE_KW["batch_size"] and b.shape[1] == 1 for b in buckets)
+        for order, n_rows, empty in ((layout.user_order, m.n_users, 17), (layout.item_order, m.n_items, 40)):
+            assert sorted(order[-2:]) == [empty, n_rows - 1]        # after every slot
+        np.testing.assert_allclose(chunked.user_factors, resident.user_factors, atol=1e-4)
+        np.testing.assert_allclose(chunked.item_factors, resident.item_factors, atol=1e-4)
+        # a row with no stars keeps its factor, through both relayouts
+        start_user, start_item = (np.asarray(t) for t in est._initial_factors(m))
+        for got, start, empty in ((chunked.user_factors, start_user, 17), (chunked.item_factors, start_item, 40)):
+            np.testing.assert_array_equal(got[[empty, -1]], start[[empty, -1]])
+
+    def test_the_callback_sees_logical_order_at_every_iteration(self):
+        m = _holed_matrix()
+        seen, want = [], []
+        ImplicitALS(**MERGE_KW, solver="cg", chunked=True).fit(
+            m, callback=lambda it, uf, vf: seen.append((it, uf, vf)))
+        ImplicitALS(**MERGE_KW, solver="cg", chunked=False).fit(
+            m, callback=lambda it, uf, vf: want.append((it, uf, vf)))
+        assert [it for it, *_ in seen] == [it for it, *_ in want] == [0, 1]
+        for (_, uf, vf), (_, u_want, v_want) in zip(seen, want):
+            assert uf.shape == (m.n_users, 8) and vf.shape == (m.n_items, 8)
+            np.testing.assert_allclose(uf, u_want, atol=1e-4)
+            np.testing.assert_allclose(vf, v_want, atol=1e-4)
+
+    def test_every_row_lands_in_place_and_the_tables_hold_their_slots(self):
+        from albedo_tpu.utils.capacity import stream_table_rows
+
+        m = _holed_matrix()
+        est = ImplicitALS(**MERGE_KW, solver="cg", chunked=True)
+        est.fit(m)
+        report = est.last_fit_report
+        assert report["landed_in_place_share"] == 1.0
+        user, item = est._host_buckets(m, stream=True)
+        by_hand = 0
+        for buckets, n_rows in ((user, m.n_users), (item, m.n_items)):
+            held = {int(r) for b in buckets for r in b.row_ids if r >= 0}
+            rows = sum(b.shape[0] for b in buckets) + n_rows - len(held)
+            by_hand += rows
+            # the planner's price of the table covers it
+            assert rows <= stream_table_rows(est._plan_shapes(m)[buckets is item], n_rows)
+        assert report["stream_slot_row_share"] == pytest.approx(by_hand / (m.n_users + m.n_items))
+        assert report["stream_slot_row_share"] > 1.0       # padding slots hold rows of their own
+        # no other path lands a block
+        resident = ImplicitALS(**MERGE_KW, solver="cg", chunked=False)
+        resident.fit(m)
+        assert resident.last_fit_report["landed_in_place_share"] == 0.0
+        assert resident.last_fit_report["stream_slot_row_share"] == 0.0
+
+    def test_the_relabelled_slabs_map_back_to_the_layout_entry_for_entry(self):
+        m = _holed_matrix()
+        est = ImplicitALS(**MERGE_KW, solver="cholesky")
+        layout = est._stream_layout(m)
+        assert layout is est._stream_layout(m)           # kept with the layout
+        user, item = est._host_buckets(m, stream=True)
+        sides = ((user, layout.user_buckets, layout.user_order, layout.item_order),
+                 (item, layout.item_buckets, layout.item_order, layout.user_order))
+        for base, relabelled, target_order, source_order in sides:
+            assert [b.shape for b in base] == [b.shape for b in relabelled]
+            offset = 0
+            for b, r in zip(base, relabelled):
+                valid = b.row_ids >= 0
+                np.testing.assert_array_equal(r.row_ids >= 0, valid)
+                # the block at its offset, a padding slot's row holding no row
+                np.testing.assert_array_equal(r.row_ids[valid], offset + np.flatnonzero(valid))
+                np.testing.assert_array_equal(target_order[offset:offset + b.shape[0]], b.row_ids)
+                np.testing.assert_array_equal(source_order[r.idx], b.idx)
+                assert r.val is b.val and r.mask is b.mask
+                offset += b.shape[0]
+        # an order and its inverse
+        for order, pos in ((layout.user_order, layout.user_pos), (layout.item_order, layout.item_pos)):
+            np.testing.assert_array_equal(order[pos], np.arange(pos.size))
+            assert sorted(order[order >= 0]) == list(range(pos.size))
+
+
+@pytest.mark.parametrize("solver", ["cg", "cholesky"])
+def test_the_chunked_program_lands_and_warm_starts_without_a_row_scatter_or_gather(solver):
+    """``jit_als_chunked`` reads and writes its target table as one block:
+    no scatter at all, no gather whose operand is the target, and the table
+    still aliased to the result through the export."""
+    import re
+
+    import jax.numpy as jnp
+
+    from albedo_tpu.ops.als import chunked_bucket_update
+    from albedo_tpu.utils.aot import persistent_aot_executable
+
+    sds = jax.ShapeDtypeStruct
+    args = (sds((30, 8), jnp.float32), sds((8, 8), jnp.float32), sds((20, 8), jnp.float32),
+            sds((6,), jnp.int32), sds((6, 16), jnp.int32), sds((6, 16), jnp.float32),
+            sds((6, 16), jnp.bool_), sds((), jnp.float32), sds((), jnp.float32))
+    statics = dict(solver=solver, cg_steps=3, gather_dtype=None)
+    text = chunked_bucket_update.lower(*args, **statics).as_text()
+    assert "stablehlo.scatter" not in text
+    gathers = re.findall(r'"stablehlo\.gather"\(.*?\) <\{.*?\}> : \((tensor<[^>]*>)', text)
+    assert gathers and "tensor<20x8xf32>" not in gathers        # the gather reads the source
+    assert "stablehlo.dynamic_update_slice" in text
+    compiled, _, _ = persistent_aot_executable(
+        chunked_bucket_update, args, None, statics,
+        key_parts=("test_als_chunked", "block", solver), name="als_chunked", donate_argnums=(2,))
+    hlo = compiled.as_text()
+    assert re.search(r"^HloModule jit_als_chunked\b", hlo, re.M)
+    assert not re.search(r"= \S+ scatter\(", hlo)
+    assert re.search(r"input_output_alias=\{ \{\}: \(2, \{\}", hlo)

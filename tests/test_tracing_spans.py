@@ -336,9 +336,12 @@ def test_compiled_fit_carries_every_scope_and_a_stable_module_name(case, acquisi
 
 
 @pytest.mark.parametrize("solver", ["cg", "cholesky"])
-def test_compiled_chunked_update_carries_the_scatter_scope_beside_the_shared_ones(solver):
+def test_compiled_chunked_update_carries_the_landing_scope_beside_the_shared_ones(solver):
     """The chunked path's one program: ``jit_als_chunked`` in a trace, the
-    shared bodies' scopes, and its own around the landing scatter."""
+    shared bodies' scopes, and its own around the landing - since PR 38 one
+    block write into the table held in dispatch order, under the scope names
+    the row scatter had (``als.chunk.scatter`` > ``als.landing``), and the
+    CG's warm start one slice of it under ``als.warm_start``."""
     from albedo_tpu.ops.als import chunked_bucket_update
 
     sds = jax.ShapeDtypeStruct
@@ -359,8 +362,13 @@ def test_compiled_chunked_update_carries_the_scatter_scope_beside_the_shared_one
         ("als.cg", "als.cg.gram", "als.warm_start") if solver == "cg" else ("als.cholesky",))
     for scope in wanted:
         assert any(f"/{scope}/" in name for name in op_names), scope
-    assert any("/als.chunk.scatter/als.landing/" in n for n in op_names)
+    assert any(re.search(r"/als\.chunk\.scatter/als\.landing/dynamic_update_slice$", n) for n in op_names)
     assert not any(re.search(r"/als\.chunk\.scatter/.*als\.(gather|cg)", n) for n in op_names)
+    # the warm start is a slice of the table, and only the CG reads one
+    warm = [n for n in op_names if "/als.warm_start/" in n]
+    assert bool(warm) == (solver == "cg")
+    assert any(re.search(r"/als\.warm_start/dynamic_slice$", n) for n in warm) == (solver == "cg")
+    assert not any(re.search(r"/als\.(warm_start|landing)/.*(gather|scatter)", n) for n in op_names)
 
 
 @pytest.mark.parametrize("program", ["fused", "chunked"])
