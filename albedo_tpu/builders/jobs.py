@@ -452,7 +452,7 @@ def _report(job: str, metric_name: str, value: float, t0: float) -> None:
 # beside the spans, with every ``*_share`` key the path's report carries.
 _FIT_COUNTERS = (
     "mode", "compile_source", "prep_cached", "dispatches", "rows_per_dispatch",
-    "exact_systems_per_sweep", "exact_lane_systems_per_sweep",
+    "stream_transfers_per_dispatch", "exact_systems_per_sweep", "exact_lane_systems_per_sweep",
 )
 
 

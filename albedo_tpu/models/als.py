@@ -51,9 +51,12 @@ from albedo_tpu.ops.als import (
     gather_reformed_entry_share,
     gather_table,
     gramian,
+    pack_slab,
     relayout_rows,
     scanned_shape,
     seeded_factors,
+    slab_entries,
+    slab_row_ids,
 )
 from albedo_tpu.ops.topk import topk_scores
 from albedo_tpu.utils import capacity as capacity_mod
@@ -267,20 +270,37 @@ def _dispatch_order(buckets: list[Bucket], n_rows: int) -> tuple[np.ndarray, np.
     return order, pos
 
 
+@dataclasses.dataclass(frozen=True)
+class StreamSlab:
+    """One bucket of the chunked fit as it travels: ``data``, the one int32
+    vector ``ops.als.pack_slab`` made of its four arrays, and the bucket's
+    ``shape`` ``(B, L)``, which the program that unpacks it is compiled at."""
+
+    data: np.ndarray
+    shape: tuple[int, int]
+
+    @property
+    def row_ids(self) -> np.ndarray:
+        """The bucket's ``(B,)`` row ids, as packed."""
+        return slab_row_ids(self.data, self.shape)
+
+
 @dataclasses.dataclass
 class StreamLayout:
     """The chunked fit's layout with both factor tables held in DISPATCH
     ORDER (:func:`_dispatch_order`): a bucket's slots are one contiguous
     block of its target table, so ``ops.als.chunked_bucket_update`` warm
     starts from it with one slice and lands it with one block write. The
-    buckets are ``_host_buckets(stream=True)``'s relabelled once: ``idx``
+    buckets are ``_host_buckets(stream=True)``'s relabelled once - ``idx``
     into the source side's positions, ``row_ids`` into the target's (the
-    block's offset + slot, -1 still on padding slots); ``val`` and ``mask``
-    are the same arrays. ``rows`` holds the relayouts' index vectors on the
-    device, per side ``(order, pos)``, uploaded once with the layout."""
+    block's offset + slot, -1 still on padding slots) - and packed, on the
+    same threads, into one :class:`StreamSlab` each: the fit uploads a
+    bucket as one buffer, in one transfer, and packs nothing a sweep.
+    ``rows`` holds the relayouts' index vectors on the device, per side
+    ``(order, pos)``, uploaded once with the layout."""
 
-    user_buckets: list[Bucket]
-    item_buckets: list[Bucket]
+    user_slabs: list[StreamSlab]
+    item_slabs: list[StreamSlab]
     user_order: np.ndarray
     user_pos: np.ndarray
     item_order: np.ndarray
@@ -297,11 +317,12 @@ class StreamLayout:
         def relabel(buckets, source_pos):
             offsets = np.cumsum([0] + [b.shape[0] for b in buckets])
 
-            def one(j: int) -> Bucket:
+            def one(j: int) -> StreamSlab:
                 b = buckets[j]
                 block = offsets[j] + np.arange(b.shape[0], dtype=np.int32)
-                return Bucket(row_ids=np.where(b.row_ids >= 0, block, -1).astype(np.int32),
-                              idx=np.take(source_pos, b.idx), val=b.val, mask=b.mask)
+                data = pack_slab(np.where(b.row_ids >= 0, block, -1),
+                                 np.take(source_pos, b.idx), b.val, b.mask)
+                return StreamSlab(data, b.shape)
 
             if workers and len(buckets) > 1:
                 with ThreadPoolExecutor(max_workers=workers) as pool:
@@ -310,14 +331,15 @@ class StreamLayout:
 
         user_d, item_d = relabel(user, i_pos), relabel(item, u_pos)
 
-        def in_place(buckets, n_table):
+        def in_place(slabs, n_table):
             """Rows a bucket lands as the block its slots are."""
             rows = 0
-            for b in buckets:
-                valid = b.row_ids >= 0
-                start = int(b.row_ids[0])
+            for b in slabs:
+                row_ids = b.row_ids
+                valid = row_ids >= 0
+                start = int(row_ids[0])
                 if (valid[0] and start + b.shape[0] <= n_table
-                        and np.array_equal(b.row_ids[valid], start + np.flatnonzero(valid))):
+                        and np.array_equal(row_ids[valid], start + np.flatnonzero(valid))):
                     rows += int(valid.sum())
             return rows
 
@@ -1128,15 +1150,22 @@ class ImplicitALS:
         ORDER (:meth:`_stream_layout`) from after the seeded draw to before
         the health read, so a bucket's rows are one block of its target
         table: the warm start is one slice and the landing one block write.
+        A bucket travels as ONE buffer in one transfer (:class:`StreamSlab`,
+        packed once with the layout; the program's first ops unpack it):
+        a transfer costs about a millisecond on a v5e whatever its bytes, and
+        four of them a bucket (1,569 ms a sweep through ``jnp.asarray``,
+        PERF.md section 5) with the calls (1,311 ms) came to more than the
+        device's 2,551 ms of programs, so the chip waited 14% of a fit under
+        the uploads. One transfer a bucket puts the host's work a sweep back
+        under the device's: the uploads take 309 ms a sweep, the calls wait
+        for the device again, and the chip is idle 0.5% of a fit (one v5e;
+        PERF.md sections 5 and 6).
         Measured on one v5e at 10M x 1M x 100M stars, rank 128
         (``gh10m-r128.fit-streamed``, PERF.md sections 5 and 6, PR 38):
-        2,797 ms a sweep over 1,107 dispatches against a resident plan that
-        does not fit, where a row scatter landing 70 ns a row (736 ms a
-        sweep) held it at 3,372; the block write lands a row in 4 ns (44 ms
-        a sweep) and the relayouts cost 25 ms a sweep. The device's programs
-        are 2,551 ms of a sweep, and the host now sets the pace: its uploads
-        (1.56 s a sweep, 1.4 GB/s through ``jnp.asarray``) and calls (1.31
-        s) come to more, and the chip waits 14% of a fit under the uploads. Per-shape
+        1,107 dispatches a sweep against a resident plan that does not fit;
+        a row scatter landing 70 ns a row (736 ms a sweep) held the sweep at
+        3,372 ms, the block write lands a row in 4 ns (44 ms a sweep) and
+        the relayouts cost 25 ms a sweep. Per-shape
         executables are acquired through the
         persistent AOT layer, NOT bare jit: chunked fits run in exactly the
         kill-resume chaos that exposed the PR 4 XLA-cache custom-call
@@ -1150,11 +1179,13 @@ class ImplicitALS:
 
         Spans (``CHUNKED_SPANS``): ``fit.prep`` (host bucketing, the
         relabelling into dispatch order and the relayouts' index vectors'
-        upload), ``fit.init`` (the seeded tables), ``fit.relayout`` (twice:
-        the tables into dispatch order, and back into logical order after
-        the last half-sweep), one ``fit.stream`` a half-sweep
+        upload), ``fit.init`` (the seeded tables, waited for), ``fit.relayout``
+        (twice: the tables into dispatch order, and back into logical order
+        once the last half-sweep's buckets have run, waited for), one
+        ``fit.stream`` a half-sweep
         with one ``fit.stream.gramian`` and, a bucket, ``fit.stream.upload``
-        (the slab's four arrays) and ``fit.stream.dispatch`` (the compiled
+        (the slab's one ``jax.device_put``; ``stream_transfers_per_dispatch``
+        in the report counts them) and ``fit.stream.dispatch`` (the compiled
         call; the executable's look-up between them carries no span);
         ``fit.wait`` is the health read that ends the fit. On a cold
         estimator one more ``fit.stream`` comes first, holding one
@@ -1162,12 +1193,18 @@ class ImplicitALS:
         (``fit.acquire`` = ``compile_s`` repeats its wall-clock; the AOT
         layer's branches under it are thread-seconds). The host runs ahead
         of the device: a span is the host's time in the call, and what the
-        device still owes is in ``fit.wait``.
+        device still owes is in ``fit.wait``. It is held back twice a fit,
+        so that no table is allocated beside work still queued: the seeded
+        draw is waited for before the relayouts, and the last buckets
+        before the relayouts back, which keeps the fit's peak at the two
+        relayouts' (a table beside its copy in the other order, with the
+        other side's pair: 11.9 GB at 10M x 1M, rank 128) wherever the chip
+        has room for more (PERF.md section 6).
         """
         with timer.section("fit.prep"):
             layout = self._stream_layout(matrix)
             rows = layout.device_rows()
-        user_buckets, item_buckets = layout.user_buckets, layout.item_buckets
+        user_slabs, item_slabs = layout.user_slabs, layout.item_slabs
         t1 = time.perf_counter()
 
         statics = dict(
@@ -1190,12 +1227,11 @@ class ImplicitALS:
                 # the fixed side's table in the form the gather reads it
                 jax.eval_shape(gather_table, sds((n_source, self.rank), f32)),
                 sds((self.rank, self.rank), f32),
-                sds((n_target, self.rank), f32), sds(shape[:1], i32),
-                sds(shape, i32), sds(shape, f32), sds(shape, jnp.bool_),
+                sds((n_target, self.rank), f32), sds((slab_entries(*shape),), i32),
                 sds((), f32), sds((), f32),
             )
             compiled, _, source_tag = persistent_aot_executable(
-                chunked_bucket_update, args, None, statics,
+                chunked_bucket_update, args, None, {**statics, "shape": shape},
                 key_parts=(
                     *key_parts, self.solver, self.cg_steps, self.gather_dtype,
                     self.rank, n_source, n_target, shape,
@@ -1217,12 +1253,12 @@ class ImplicitALS:
 
         n_user, n_item = layout.user_order.size, layout.item_order.size
         sides = (
-            (n_user, n_item, item_buckets),
-            (n_item, n_user, user_buckets),
+            (n_user, n_item, item_slabs),
+            (n_item, n_user, user_slabs),
         )
         missing = [(acquire, key) for key in dict.fromkeys(
-            (n_source, n_target, b.shape) for n_source, n_target, buckets in sides
-            for b in buckets) if key not in executables]
+            (n_source, n_target, b.shape) for n_source, n_target, slabs in sides
+            for b in slabs) if key not in executables]
         missing += [(acquire_relayout, key) for key in dict.fromkeys((
             (matrix.n_users, n_user), (n_user, matrix.n_users),
             (matrix.n_items, n_item), (n_item, matrix.n_items),
@@ -1239,7 +1275,13 @@ class ImplicitALS:
         timer.add("fit.acquire", compile_s)
 
         with timer.section("fit.init"):
-            user_f, item_f = self._initial_factors(matrix)
+            # Waited for, as the relayouts' one-table-beside-another is all
+            # the fit's head may hold: the eager draw keeps its unscaled user
+            # table until the scaling has run, and a relayout enqueued
+            # behind it is allocated beside both wherever the chip has room
+            # (16.49 GB of a v5e at 10M x 1M, rank 128, against 11.4-11.9
+            # waited for: PERF.md section 6).
+            user_f, item_f = jax.block_until_ready(self._initial_factors(matrix))
             reg = jnp.float32(self.reg_param)
             alpha = jnp.float32(self.alpha)
 
@@ -1252,7 +1294,10 @@ class ImplicitALS:
             user_f = relayout(user_f, "user")
             item_f = relayout(item_f, "item")
 
-        def half_sweep(source, target, buckets):
+        transfers = 0
+
+        def half_sweep(source, target, slabs):
+            nonlocal transfers
             # The chaos hook: an armed kill dies genuinely mid-stream; an
             # armed error/oom surfaces as a failed fit for the pipeline's
             # fail-fast (not retried: is_resource_exhausted) handling.
@@ -1261,19 +1306,19 @@ class ImplicitALS:
                 with timer.section("fit.stream.gramian"):
                     yty = gramian(source)
                     table = gather_table(source)
-                for b in buckets:
+                for b in slabs:
                     with timer.section("fit.stream.upload"):
-                        slab = (jnp.asarray(b.row_ids), jnp.asarray(b.idx),
-                                jnp.asarray(b.val), jnp.asarray(b.mask))
+                        slab = jax.device_put(b.data, dev)
+                        transfers += 1
                     compiled = executables[source.shape[0], target.shape[0], b.shape]
                     with timer.section("fit.stream.dispatch"):
-                        target = compiled(table, yty, target, *slab, reg, alpha)
+                        target = compiled(table, yty, target, slab, reg, alpha)
             return target
 
         for it in range(self.max_iter):
             # MLlib order: item factors first (from users), then users.
-            item_f = half_sweep(user_f, item_f, item_buckets)
-            user_f = half_sweep(item_f, user_f, user_buckets)
+            item_f = half_sweep(user_f, item_f, item_slabs)
+            user_f = half_sweep(item_f, user_f, user_slabs)
             if callback is not None:
                 # Checkpoint-callback host copies, by contract (see fit()),
                 # of the tables in logical order.
@@ -1282,22 +1327,31 @@ class ImplicitALS:
                 callback(it, np.asarray(user_l), np.asarray(item_l))
 
         with timer.section("fit.relayout"):
+            # The last buckets first: the host runs ahead of the device, and
+            # a relayout enqueued behind its queue is allocated beside the
+            # queued buckets' slabs and temporaries (12.01 GB at that size,
+            # where the tables and their logical copies take 11.9).
+            jax.block_until_ready(user_f)
             user_f = relayout(user_f, "user", back=True)
             item_f = relayout(item_f, "item", back=True)
 
-        n_buckets = {"user": len(user_buckets), "item": len(item_buckets)}
-        shapes = [b.shape for b in (*user_buckets, *item_buckets)]
+        n_buckets = {"user": len(user_slabs), "item": len(item_slabs)}
+        dispatches = self.max_iter * sum(n_buckets.values())
+        shapes = [b.shape for b in (*user_slabs, *item_slabs)]
         slots = sorted(b for b, _ in shapes) or [0]
         return _PathRun(
             user_f, item_f, t1, shapes,
             compile_s, "+".join(sorted(compile_sources)) or None,
             own={
                 "chunked_shapes": len(executables),
-                "dispatches": self.max_iter * sum(n_buckets.values()),
+                "dispatches": dispatches,
                 "buckets": n_buckets,
+                # the packed slabs' bytes (ops.als.pack_slab), as they travel
                 "streamed_bytes_per_sweep": sum(
-                    capacity_mod.bucket_slab_bytes(*shape) for shape in shapes
+                    capacity_mod.stream_slab_bytes(*shape) for shape in shapes
                 ),
+                # host -> device transfers in fit.stream.upload a dispatch
+                "stream_transfers_per_dispatch": transfers / max(1, dispatches),
                 # slot rows a dispatch carries, and the share of the padded
                 # entries that travel in a dispatch of more than batch_size
                 # rows (_dispatch_rows: none does in the resident layout)
@@ -1504,7 +1558,8 @@ class ImplicitALS:
         with timer.section("fit.acquire"):
             engine.acquire_local(*sizes, shapes_u, shapes_i, stats, timer,
                                  workers=_bucket_workers() or 1)
-        compile_s = time.perf_counter() - t1
+            # read inside the span, so that ``compile_s`` never exceeds it
+            compile_s = time.perf_counter() - t1
 
         with timer.section("fit.init"):
             if self.init_factors is None:

@@ -61,6 +61,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from albedo_tpu.datasets.ragged import Bucket
 
@@ -678,7 +679,7 @@ def solve_rows(
     """One bucket's solved ``(B, k)`` block, by the kernel ``solver`` names:
     ``"cholesky"`` the exact MLlib-parity solve (``bucket_solve_body``),
     ``"cg"`` the CG warm-started from the bucket's current rows of ``target``
-    (``bucket_cg_body``). Arguments as ``chunked_bucket_update``'s: ``source``
+    (``bucket_cg_body``). Arguments as ``solve_bucket``'s: ``source``
     serves the gather alone, so it comes in the gather's form
     (``gather_table``, built where ``yty`` is); ``target`` and ``row_ids`` are
     read under ``"cg"`` only, by ``warm_start``, unless ``x0`` hands in the
@@ -726,21 +727,66 @@ def solve_bucket(
     return scatter_solved(target, row_ids, solved)
 
 
+# The chunked fit's slab travels as ONE int32 vector a bucket, one host ->
+# device transfer where four arrays took four: a transfer costs about a
+# millisecond on a v5e whatever its bytes, 0.1 MB or 18.9 MB (PERF.md
+# section 6). The indices with the mask folded in come first, then the values'
+# float32 bits, then the row ids: the two large sections start on a whole
+# 1,024 entries wherever ``B * L`` is a multiple of it, as the planner's
+# slot tiers make it for most buckets.
+def slab_entries(n_slots: int, length: int) -> int:
+    """Entries of a ``(n_slots, length)`` bucket's slab (:func:`pack_slab`)."""
+    return n_slots * (2 * length + 1)
+
+
+def pack_slab(row_ids: np.ndarray, idx: np.ndarray, val: np.ndarray,
+              mask: np.ndarray) -> np.ndarray:
+    """A bucket's four host arrays as the one int32 slab the chunked fit
+    uploads (:func:`unpack_slab` undoes it, bit for bit): ``idx`` with the
+    mask folded in as ``~idx`` on a padding entry (every index is a row
+    position, so ``>= 0``, and the sign alone is the mask); ``val`` as its
+    float32 bit pattern; ``row_ids`` whole."""
+    entries = idx.size
+    slab = np.empty(slab_entries(*idx.shape), np.int32)
+    packed = slab[:entries].reshape(idx.shape)
+    packed[...] = idx
+    np.invert(packed, out=packed, where=~mask)
+    slab[entries:2 * entries] = np.ascontiguousarray(val, np.float32).view(np.int32).reshape(-1)
+    slab[2 * entries:] = row_ids
+    return slab
+
+
+def slab_row_ids(slab, shape: tuple[int, int]):
+    """The ``(B,)`` row ids of a ``shape`` bucket's slab (:func:`pack_slab`),
+    on the host or in a program: its last section."""
+    return slab[2 * shape[0] * shape[1]:]
+
+
+def unpack_slab(slab: jax.Array, shape: tuple[int, int]) -> tuple[jax.Array, ...]:
+    """``(row_ids, idx, val, mask)`` of a ``shape`` bucket's slab
+    (:func:`pack_slab`), at the head of its program: slices, a bit cast, a
+    compare and a ``where``, and each index - a padding entry's among them -
+    the one the bucket was packed with."""
+    entries = shape[0] * shape[1]
+    packed = slab[:entries].reshape(shape)
+    val = jax.lax.bitcast_convert_type(slab[entries:2 * entries], jnp.float32).reshape(shape)
+    mask = packed >= 0
+    return slab_row_ids(slab, shape), jnp.where(mask, packed, ~packed), val, mask
+
+
 @functools.partial(
     jax.jit,
     donate_argnums=(2,),  # target: the chunked path must not double-buffer it
-    static_argnames=("solver", "cg_steps", "gather_dtype"),
+    static_argnames=("shape", "solver", "cg_steps", "gather_dtype"),
 )
 def chunked_bucket_update(
     source: jax.Array,   # gather_table of the fixed side's factors, dispatch order
     yty: jax.Array,      # (k, k) gramian of those factors
     target: jax.Array,   # (n_target, k) factors being updated, dispatch order (donated)
-    row_ids: jax.Array,  # (B,) int32 target positions offset + slot, -1 on padding slots
-    idx: jax.Array,      # (B, L) int32 positions in `source`
-    val: jax.Array,      # (B, L) float32 ratings, 0 on padding
-    mask: jax.Array,     # (B, L) bool
+    slab: jax.Array,     # (slab_entries(*shape),) int32: the bucket, pack_slab
     reg: jax.Array,      # () float32 regParam
     alpha: jax.Array,    # () float32 confidence scale
+    shape: tuple[int, int],  # the bucket's (B, L), static
     solver: str = "cholesky",
     cg_steps: int = 3,
     gather_dtype: str | None = None,
@@ -750,6 +796,13 @@ def chunked_bucket_update(
     arrives fresh from the host per call, only the factor tables stay
     device-resident. Same kernels as the fused sweep (``solve_rows``) so the
     fallback is numerics-parity with the resident path.
+
+    The slab is one int32 vector, one transfer (:func:`pack_slab`, done once
+    a matrix when the layout is built); the program's first ops unpack it
+    (:func:`unpack_slab`) into the ``(B,)`` row ids and ``(B, L)`` indices,
+    values and mask it was packed from, bit for bit, so the gather's flat
+    count ``B * L`` and every op after the unpacking are those of a bucket
+    handed over as four arrays.
 
     The landing contract is this path's alone: both tables are held in the
     chunked fit's dispatch order (``models.als.StreamLayout``), where the
@@ -765,7 +818,8 @@ def chunked_bucket_update(
     and no empty row's 0/0 in it. The block never reaches past the table's
     end, so the slice is never clamped.
     """
-    n_slots = row_ids.shape[0]
+    row_ids, idx, val, mask = unpack_slab(slab, shape)
+    n_slots = shape[0]
     offset = row_ids[0]
     x0 = None
     if solver == "cg":

@@ -351,6 +351,17 @@ def bucket_slab_bytes(b: int, ln: int) -> int:
     return b * 4 + b * ln * (4 + 4 + 1)
 
 
+def stream_slab_bytes(b: int, ln: int) -> int:
+    """Bytes of one padded ``(b, ln)`` bucket's slab as the chunked fit
+    uploads it (``ops.als.pack_slab``): one int32 vector of the indices with
+    the mask in their sign, the values' bits and the row ids - a byte an
+    entry less than the four arrays of :func:`bucket_slab_bytes`, which the
+    resident and sharded plans hold."""
+    from albedo_tpu.ops.als import slab_entries
+
+    return 4 * slab_entries(b, ln)
+
+
 def exact_system_bytes(rank: int) -> int:
     """Bytes one system of a bucket holds on the device while
     ``ops.als.bucket_solve_body`` solves it exactly: the three arrays
@@ -639,13 +650,17 @@ def chunked_row_bytes(ln: int, rank: int, gather_dtype: str | None, solver: str)
     length), and ``CHUNKED_ROW_ARRAYS`` rank-vectors. What the chunked fit
     fills a dispatch of one-entry rows by
     (``models.als.ImplicitALS._dispatch_rows``), and what
-    :func:`plan_fit_chunked` prices a bucket by."""
+    :func:`plan_fit_chunked` prices a bucket by. The slab's share is what
+    travels (:func:`stream_slab_bytes`): at 10M x 1M x 100M stars, rank 128,
+    it fills the one-entry dispatches to the same 169,984 / 240,640 rows as
+    the four arrays' did (170,650 / 241,070 rows priced, against 170,924 /
+    241,471; PERF.md section 6)."""
     from albedo_tpu.ops.als import cg_uses_gramian
 
     gb = _dtype_bytes(gather_dtype)
     system = rank * rank * 4 if solver != "cg" or cg_uses_gramian(ln, rank) else 0
     return (
-        bucket_slab_bytes(1, ln) + ln * (rank * gb + gb) + system
+        stream_slab_bytes(1, ln) + ln * (rank * gb + gb) + system
         + CHUNKED_ROW_ARRAYS * rank * 4
     )
 
@@ -676,8 +691,8 @@ def plan_fit_chunked(
     """Price the chunked host-streamed fallback: only the factor tables stay
     resident, in dispatch order (:func:`stream_table_rows`), with the
     relayouts' index vectors (a position a row each way); one bucket's
-    slab, gather block and solve (:func:`chunked_row_bytes` a slot row) is
-    in flight at a time. The shapes
+    slab as it travels, gather block and solve (:func:`chunked_row_bytes` a
+    slot row) is in flight at a time. The shapes
     are the planner's at ``batch_size`` rows: the fit itself fills a dispatch
     of one-entry rows up to its side's worst of them and no further, so their
     worst bounds its own. Once a fit each way, a relayout holds the larger
@@ -698,7 +713,7 @@ def plan_fit_chunked(
     item_rows = stream_table_rows(bucket_shapes_item, n_items)
 
     def row(ln: int) -> int:
-        held = bucket_slab_bytes(1, ln) + ln * (rank * gb + gb) + rank * rank * 4 + rank * 4
+        held = stream_slab_bytes(1, ln) + ln * (rank * gb + gb) + rank * rank * 4 + rank * 4
         return max(chunked_row_bytes(ln, rank, gather_dtype, solver), held)
 
     worst = max(

@@ -356,7 +356,7 @@ SHARED_REPORT_KEYS = {
 OWN_REPORT_KEYS = {
     "resident": {"capacity_cross_check"},
     "chunked": {"chunked_shapes", "dispatches", "buckets", "streamed_bytes_per_sweep",
-                "rows_per_dispatch", "merged_entry_share"},
+                "stream_transfers_per_dispatch", "rows_per_dispatch", "merged_entry_share"},
     "sharded": {"shard_mode", "n_shards", "streamed_buckets", "sharded_shapes",
                 "pipelined", "prefetch_wait_s", "mesh_events",
                 "assembled_bytes_per_sweep", "collective_bytes_per_sweep",

@@ -2,6 +2,8 @@
 device-resident path (both solvers), the admission wiring in ``fit``, the
 als.chunked chaos site, and the over-budget-fit-completes acceptance bar."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -206,15 +208,13 @@ class TestStreamObservability:
         assert counts["fit.stream.acquire.lower_compile"] == report["chunked_shapes"] + 4
         assert counts["fit.relayout"] == 2
         assert counts["fit.admission"] == counts["fit.init"] == counts["fit.wait"] == 1
-        # by hand: int32 row ids, int32 indices, float32 values, one-byte mask
+        # by hand: one int32 slab a bucket - an index (the mask in its sign)
+        # and a value's bits an entry, a row id a slot
         by_hand = sum(
-            b.row_ids.shape[0] * 4 + b.idx.size * 4 + b.val.size * 4 + b.mask.size * 1
-            for b in (*user_buckets, *item_buckets)
+            b.row_ids.shape[0] * 4 + b.idx.size * (4 + 4) for b in (*user_buckets, *item_buckets)
         )
-        assert by_hand == sum(
-            a.nbytes for b in (*user_buckets, *item_buckets)
-            for a in (b.row_ids, b.idx, b.val, b.mask)
-        )
+        layout = est._stream_layout(m)
+        assert by_hand == sum(b.data.nbytes for b in (*layout.user_slabs, *layout.item_slabs))
         assert report["streamed_bytes_per_sweep"] == by_hand
         assert report["upload_s"] == pytest.approx(totals["fit.stream.upload"], abs=1e-3)
         assert report["compile_s"] == pytest.approx(totals["fit.acquire"], abs=1e-3)
@@ -253,18 +253,18 @@ class TestStreamObservability:
         bucket's dispatch would copy the whole target table."""
         import jax.numpy as jnp
 
-        from albedo_tpu.ops.als import gather_table
+        from albedo_tpu.ops.als import gather_table, pack_slab
 
         est, m = _degraded(monkeypatch, 10)
         est.fit(m)
         (key, compiled), *_ = est._chunked_executables(m).items()
         n_source, n_target, (b, l) = key
         target = jnp.ones((n_target, 8), jnp.float32)
+        slab = pack_slab(np.full(b, -1, np.int32), np.zeros((b, l), np.int32),
+                         np.zeros((b, l), np.float32), np.zeros((b, l), bool))
         compiled(   # (the fixed side's table in the form the gather reads it)
             gather_table(jnp.ones((n_source, 8), jnp.float32)), jnp.eye(8, dtype=jnp.float32), target,
-            jnp.full((b,), -1, jnp.int32), jnp.zeros((b, l), jnp.int32),
-            jnp.zeros((b, l), jnp.float32), jnp.zeros((b, l), bool),
-            jnp.float32(0.5), jnp.float32(40.0),
+            jnp.asarray(slab), jnp.float32(0.5), jnp.float32(40.0),
         )
         assert target.is_deleted()
 
@@ -454,7 +454,7 @@ class TestDispatchOrder:
         layout = est._stream_layout(m)
         # what the layout has to show: padding slots, a merged one-entry
         # dispatch, and a row on either side that no bucket holds
-        buckets = (*layout.user_buckets, *layout.item_buckets)
+        buckets = (*layout.user_slabs, *layout.item_slabs)
         assert any((b.row_ids < 0).any() for b in buckets)
         assert any(b.shape[0] > MERGE_KW["batch_size"] and b.shape[1] == 1 for b in buckets)
         for order, n_rows, empty in ((layout.user_order, m.n_users, 17), (layout.item_order, m.n_items, 40)):
@@ -504,24 +504,31 @@ class TestDispatchOrder:
         assert resident.last_fit_report["stream_slot_row_share"] == 0.0
 
     def test_the_relabelled_slabs_map_back_to_the_layout_entry_for_entry(self):
+        from albedo_tpu.ops.als import unpack_slab
+
         m = _holed_matrix()
         est = ImplicitALS(**MERGE_KW, solver="cholesky")
         layout = est._stream_layout(m)
         assert layout is est._stream_layout(m)           # kept with the layout
         user, item = est._host_buckets(m, stream=True)
-        sides = ((user, layout.user_buckets, layout.user_order, layout.item_order),
-                 (item, layout.item_buckets, layout.item_order, layout.user_order))
+        sides = ((user, layout.user_slabs, layout.user_order, layout.item_order),
+                 (item, layout.item_slabs, layout.item_order, layout.user_order))
+        unpack = jax.jit(unpack_slab, static_argnums=1)
         for base, relabelled, target_order, source_order in sides:
             assert [b.shape for b in base] == [b.shape for b in relabelled]
             offset = 0
             for b, r in zip(base, relabelled):
+                row_ids, idx, val, mask = (np.asarray(a) for a in unpack(r.data, r.shape))
+                np.testing.assert_array_equal(row_ids, r.row_ids)
                 valid = b.row_ids >= 0
                 np.testing.assert_array_equal(r.row_ids >= 0, valid)
                 # the block at its offset, a padding slot's row holding no row
                 np.testing.assert_array_equal(r.row_ids[valid], offset + np.flatnonzero(valid))
                 np.testing.assert_array_equal(target_order[offset:offset + b.shape[0]], b.row_ids)
-                np.testing.assert_array_equal(source_order[r.idx], b.idx)
-                assert r.val is b.val and r.mask is b.mask
+                np.testing.assert_array_equal(source_order[idx], b.idx)
+                # the values and the mask as the bucket had them, bit for bit
+                np.testing.assert_array_equal(val.view(np.int32), b.val.view(np.int32))
+                np.testing.assert_array_equal(mask, b.mask)
                 offset += b.shape[0]
         # an order and its inverse
         for order, pos in ((layout.user_order, layout.user_pos), (layout.item_order, layout.item_pos)):
@@ -538,14 +545,13 @@ def test_the_chunked_program_lands_and_warm_starts_without_a_row_scatter_or_gath
 
     import jax.numpy as jnp
 
-    from albedo_tpu.ops.als import chunked_bucket_update
+    from albedo_tpu.ops.als import chunked_bucket_update, slab_entries
     from albedo_tpu.utils.aot import persistent_aot_executable
 
     sds = jax.ShapeDtypeStruct
     args = (sds((30, 8), jnp.float32), sds((8, 8), jnp.float32), sds((20, 8), jnp.float32),
-            sds((6,), jnp.int32), sds((6, 16), jnp.int32), sds((6, 16), jnp.float32),
-            sds((6, 16), jnp.bool_), sds((), jnp.float32), sds((), jnp.float32))
-    statics = dict(solver=solver, cg_steps=3, gather_dtype=None)
+            sds((slab_entries(6, 16),), jnp.int32), sds((), jnp.float32), sds((), jnp.float32))
+    statics = dict(shape=(6, 16), solver=solver, cg_steps=3, gather_dtype=None)
     text = chunked_bucket_update.lower(*args, **statics).as_text()
     assert "stablehlo.scatter" not in text
     gathers = re.findall(r'"stablehlo\.gather"\(.*?\) <\{.*?\}> : \((tensor<[^>]*>)', text)
@@ -558,3 +564,187 @@ def test_the_chunked_program_lands_and_warm_starts_without_a_row_scatter_or_gath
     assert re.search(r"^HloModule jit_als_chunked\b", hlo, re.M)
     assert not re.search(r"= \S+ scatter\(", hlo)
     assert re.search(r"input_output_alias=\{ \{\}: \(2, \{\}", hlo)
+
+
+# ------------------------------------------- the slab: one buffer, one transfer
+
+def _bucket_arrays(n_slots: int, length: int, seed: int):
+    """A bucket's four host arrays with what a layout carries: padding slots
+    (row id -1, no entry), padding entries whose index is not 0, values of
+    every sign (a negative zero among them)."""
+    rng = np.random.default_rng(seed)
+    row_ids = rng.permutation(4 * n_slots)[:n_slots].astype(np.int32)
+    row_ids[-max(1, n_slots // 4):] = -1
+    idx = rng.integers(1, 1 << 30, (n_slots, length)).astype(np.int32)
+    mask = rng.random((n_slots, length)) < 0.6
+    mask[row_ids < 0] = False
+    val = rng.normal(0, 3, (n_slots, length)).astype(np.float32)
+    val.flat[0] = -0.0
+    return row_ids, idx, val, mask
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (8, 1), (13, 1), (13, 3), (16, 8), (7, 40)])
+def test_a_packed_slab_unpacks_in_the_program_to_the_four_arrays_bit_for_bit(shape):
+    import jax.numpy as jnp
+
+    from albedo_tpu.ops.als import pack_slab, slab_entries, unpack_slab
+
+    arrays = _bucket_arrays(*shape, seed=shape[0] * 100 + shape[1])
+    slab = pack_slab(*arrays)
+    assert slab.dtype == np.int32 and slab.shape == (slab_entries(*shape),)
+    assert slab.nbytes == capacity.stream_slab_bytes(*shape)
+    got = jax.jit(unpack_slab, static_argnums=1)(jnp.asarray(slab), shape)
+    for g, want in zip(got, arrays):
+        g = np.asarray(g)
+        assert g.dtype == want.dtype and g.shape == want.shape
+        np.testing.assert_array_equal(g.view(np.uint8), want.view(np.uint8))
+
+
+@functools.partial(jax.jit, donate_argnums=(2,), static_argnames=("solver", "cg_steps", "gather_dtype"))
+def _four_array_update(source, yty, target, row_ids, idx, val, mask, reg, alpha,
+                       solver, cg_steps, gather_dtype):
+    """A bucket's chunked program as it was handed four arrays, before the
+    slab: the same warm start, solve and block write."""
+    import jax.numpy as jnp
+
+    from albedo_tpu.ops.als import solve_rows
+
+    offset = row_ids[0]
+    x0 = None
+    if solver == "cg":
+        x0 = jax.lax.dynamic_slice_in_dim(target, offset, row_ids.shape[0])
+    solved = solve_rows(source, yty, target, row_ids, idx, val, mask, reg, alpha,
+                        solver, cg_steps, gather_dtype, x0=x0)
+    block = jnp.where((row_ids >= 0)[:, None], solved, 0.0)
+    return jax.lax.dynamic_update_slice_in_dim(target, block, offset, 0)
+
+
+def _four_array_fit(est, m):
+    """The chunked fit with every bucket uploaded as its four relabelled
+    arrays: the dispatch-order layout, tables and loop of ``_fit_chunked``."""
+    import jax.numpy as jnp
+
+    from albedo_tpu.ops.als import gather_table, gramian, relayout_rows
+
+    layout = est._stream_layout(m)
+
+    def relabel(buckets, source_pos):
+        out, offset = [], 0
+        for b in buckets:
+            block = offset + np.arange(b.shape[0], dtype=np.int32)
+            out.append((np.where(b.row_ids >= 0, block, -1).astype(np.int32),
+                        np.take(source_pos, b.idx), b.val, b.mask))
+            offset += b.shape[0]
+        return out
+
+    user, item = est._host_buckets(m, stream=True)
+    user_b, item_b = relabel(user, layout.item_pos), relabel(item, layout.user_pos)
+    uf, vf = est._initial_factors(m)
+    uf = relayout_rows(uf, jnp.asarray(layout.user_order))
+    vf = relayout_rows(vf, jnp.asarray(layout.item_order))
+    reg, alpha = jnp.float32(est.reg_param), jnp.float32(est.alpha)
+    statics = dict(solver=est.solver, cg_steps=est.cg_steps, gather_dtype=est.gather_dtype)
+
+    def half_sweep(source, target, buckets):
+        yty, table = gramian(source), gather_table(source)
+        for arrays in buckets:
+            target = _four_array_update(
+                table, yty, target, *(jnp.asarray(a) for a in arrays), reg, alpha, **statics)
+        return target
+
+    for _ in range(est.max_iter):
+        vf = half_sweep(uf, vf, item_b)
+        uf = half_sweep(vf, uf, user_b)
+    return (np.asarray(relayout_rows(uf, jnp.asarray(layout.user_pos))),
+            np.asarray(relayout_rows(vf, jnp.asarray(layout.item_pos))))
+
+
+@pytest.mark.parametrize("solver", ["cg", "cholesky"])
+def test_the_slab_fit_solves_the_tables_of_the_four_array_fit_bit_for_bit(solver):
+    m = _holed_matrix()
+    est = ImplicitALS(**MERGE_KW, solver=solver, chunked=True)
+    model = est.fit(m)
+    assert est.last_fit_report["mode"] == "chunked"
+    user_want, item_want = _four_array_fit(est, m)
+    np.testing.assert_array_equal(model.user_factors.view(np.uint32), user_want.view(np.uint32))
+    np.testing.assert_array_equal(model.item_factors.view(np.uint32), item_want.view(np.uint32))
+
+
+def test_a_bucket_is_one_transfer_a_dispatch(monkeypatch):
+    from albedo_tpu.ops.als import slab_entries
+
+    sent = []
+    device_put = jax.device_put
+
+    def counted(x, *args, **kwargs):
+        sent.append((type(x), getattr(x, "dtype", None), getattr(x, "shape", None)))
+        return device_put(x, *args, **kwargs)
+
+    est, m = _degraded(monkeypatch, 11)
+    est._stream_layout(m)                   # the layout's own uploads before the count
+    monkeypatch.setattr(jax, "device_put", counted)
+    est.fit(m)
+    report = est.last_fit_report
+    counts = report["spans"]["counts"]
+    assert report["mode"] == "chunked"
+    assert report["stream_transfers_per_dispatch"] == 1.0
+    assert counts["fit.stream.upload"] == report["dispatches"] == len(sent)
+    layout = est._stream_layout(m)
+    shapes = [b.shape for b in (*layout.item_slabs, *layout.user_slabs)] * est.max_iter
+    assert sent == [(np.ndarray, np.int32, (slab_entries(*shape),)) for shape in shapes]
+
+
+@pytest.mark.parametrize("warm", [False, True])
+def test_no_relayout_is_enqueued_behind_the_draw_or_the_last_buckets(warm, monkeypatch):
+    """A relayout allocates a table beside the one it reads, so the fit
+    waits before each pair: for the seeded (or warm-start) tables before the
+    relayouts into dispatch order, and for the last half-sweep's table
+    before the relayouts back. Nothing else in the fit waits, and what it
+    returns is what it returned without the waits."""
+    m = _holed_matrix()
+    init = None
+    if warm:
+        rng = np.random.default_rng(5)
+        init = (rng.normal(0, 0.3, (m.n_users, 8)).astype(np.float32),
+                rng.normal(0, 0.3, (m.n_items, 8)).astype(np.float32))
+    kw = dict(MERGE_KW, solver="cg", init_factors=init, chunked=True)
+    want = ImplicitALS(**kw).fit(m)
+    est = ImplicitALS(**kw)
+    layout = est._stream_layout(m)
+    events = []
+    wait = jax.block_until_ready
+
+    def waited(x):
+        events.append(("wait", tuple(a.shape for a in jax.tree.leaves(x))))
+        return wait(x)
+
+    class Relayouts(dict):
+        def __getitem__(self, key):
+            program = super().__getitem__(key)
+
+            def call(table, index):
+                events.append(("relayout", table.shape, index.shape))
+                return program(table, index)
+            return call
+
+    chunked_executables = ImplicitALS._chunked_executables
+    kept = Relayouts()
+
+    def executables(self, matrix, kind="shapes"):
+        return kept if kind == "relayouts" else chunked_executables(self, matrix, kind)
+
+    monkeypatch.setattr(ImplicitALS, "_chunked_executables", executables)
+    monkeypatch.setattr(jax, "block_until_ready", waited)
+    got = est.fit(m)
+    n_user, n_item = layout.user_order.size, layout.item_order.size
+    assert n_user > m.n_users and n_item > m.n_items          # padding slots
+    assert events == [
+        ("wait", ((m.n_users, 8), (m.n_items, 8))),
+        ("relayout", (m.n_users, 8), (n_user,)),
+        ("relayout", (m.n_items, 8), (n_item,)),
+        ("wait", ((n_user, 8),)),
+        ("relayout", (n_user, 8), (m.n_users,)),
+        ("relayout", (n_item, 8), (m.n_items,)),
+    ]
+    np.testing.assert_array_equal(got.user_factors, want.user_factors)
+    np.testing.assert_array_equal(got.item_factors, want.item_factors)

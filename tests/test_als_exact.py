@@ -132,6 +132,14 @@ def bucket_args(rank: int) -> tuple:
             sds((6, 16), jnp.bool_), sds((), jnp.float32), sds((), jnp.float32))
 
 
+def slab_args(rank: int) -> tuple:
+    """``bucket_args`` as ``chunked_bucket_update`` takes them: the bucket's
+    four arrays as one packed slab (``ops.als.pack_slab``), its shape static."""
+    sds = jax.ShapeDtypeStruct
+    source, yty, target, *_, reg, alpha = bucket_args(rank)
+    return source, yty, target, sds((ops.slab_entries(6, 16),), jnp.int32), reg, alpha
+
+
 def stablehlo_of(solver: str, rank: int) -> str:
     return jax.jit(lambda *a: ops.solve_rows(*a, solver, 3, None)).lower(*bucket_args(rank)).as_text()
 
@@ -162,7 +170,7 @@ def test_the_exact_program_calls_no_library_factorisation_and_the_cg_program_did
 def lowered_text(solver: str, rank: int) -> str:
     """The op names (scope paths) of one bucket's compiled program, a line each."""
     lowered = ops.chunked_bucket_update.lower(
-        *bucket_args(rank), solver=solver, cg_steps=3, gather_dtype=None)
+        *slab_args(rank), shape=(6, 16), solver=solver, cg_steps=3, gather_dtype=None)
     return "\n".join(sorted(set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))))
 
 
@@ -192,7 +200,8 @@ def test_the_exact_solve_carries_its_three_sub_scopes_and_the_cg_none(rank):
 def test_scopes_do_not_reach_the_programs_text():
     """``jax.named_scope`` is location metadata: the lowered program, as it
     is hashed and compared between commits, names no scope."""
-    lowered = ops.chunked_bucket_update.lower(*bucket_args(4), solver="cholesky", cg_steps=3, gather_dtype=None)
+    lowered = ops.chunked_bucket_update.lower(
+        *slab_args(4), shape=(6, 16), solver="cholesky", cg_steps=3, gather_dtype=None)
     assert "als." not in lowered.as_text() and "als.cholesky.factor" in lowered.as_text(debug_info=True)
 
 

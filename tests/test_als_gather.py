@@ -447,9 +447,9 @@ def test_rank_128_programs_are_the_parents_text(program, solver, monkeypatch):
         sds = jax.ShapeDtypeStruct
         shape = (64, 304)                    # L >= 2k: the CG on its Gramian
         args = (sds((150, rank), jnp.float32), sds((rank, rank), jnp.float32),
-                sds((400, rank), jnp.float32), sds(shape[:1], jnp.int32), sds(shape, jnp.int32),
-                sds(shape, jnp.float32), sds(shape, jnp.bool_), sds((), jnp.float32), sds((), jnp.float32))
-        kwargs, statics = {}, dict(solver=solver, cg_steps=3, gather_dtype=None)
+                sds((400, rank), jnp.float32), sds((ops.slab_entries(*shape),), jnp.int32),
+                sds((), jnp.float32), sds((), jnp.float32))
+        kwargs, statics = {}, dict(shape=shape, solver=solver, cg_steps=3, gather_dtype=None)
         fn = ops.chunked_bucket_update
 
     def lowered():

@@ -119,7 +119,7 @@ class TestPlans:
         rank = 16
         assert cg_uses_gramian(ln, rank) == (ln >= 32)
         by_hand = (
-            4 + ln * 9                          # row id; index, value, mask
+            4 + ln * 8                          # the slab as it travels: row id; index, value bits
             + ln * (rank * 4 + 4)
             + (rank * rank * 4 if builds_system else 0)
             + capacity.CHUNKED_ROW_ARRAYS * rank * 4
@@ -128,7 +128,7 @@ class TestPlans:
         # admission's price of the rung: never under a system and one
         # rank-vector a row, what it was admitted at before (the short rows
         # under CG: 1024 + 64 B against the eight rank-vectors' 512)
-        held = 4 + ln * 9 + ln * (rank * 4 + 4) + rank * rank * 4 + rank * 4
+        held = 4 + ln * 8 + ln * (rank * 4 + 4) + rank * rank * 4 + rank * 4
         assert (held > by_hand) == (not builds_system)
         plan = capacity.plan_fit_chunked([(64, ln)], [(8, 1)], 500, 300, rank, None, solver)
         # the tables in dispatch order: a row of its own for each padding slot
@@ -144,11 +144,12 @@ class TestPlans:
 
     @pytest.mark.parametrize("solver,worst,total", [
         # the parent priced a (k, k) system a row whatever the solver and one
-        # (B, k) array: a worst bucket of 1,298,038,784 B, the users' (8192, 176)
-        ("cholesky", 1_327_398_912, 12_171_457_252),   # + seven more row arrays on that bucket
-        # that bucket builds no system under CG and would price at 790,528,000
+        # (B, k) array: a worst bucket of 1,298,038,784 B, the users' (8192, 176),
+        # with its slab's four arrays (a byte an entry more than travels since)
+        ("cholesky", 1_325_957_120, 12_170_015_460),   # + seven more row arrays on that bucket
+        # that bucket builds no system under CG and would price at 789,086,208
         # (the items' (239, 8768) the worst): held at what the rung was admitted at
-        ("cg", 1_298_038_784, 12_142_097_124),
+        ("cg", 1_296_596_992, 12_140_655_332),
     ])
     def test_the_streamed_cells_plan_and_verdict(self, solver, worst, total):
         """``gh10m-r128`` (10M x 1M x 100M stars, rank 128): the heaviest
@@ -156,7 +157,9 @@ class TestPlans:
         configuration's degree laws, ``benchmark.stars.degree_sequence``).
         The tables in dispatch order and a relayout's copy of the user table
         beside them: 12.1 GB, over the chip's measured peak of 11.94 GB
-        (PERF.md section 4), and still the degraded rung's verdict."""
+        (PERF.md section 4; 11.89 since the fit waits for its queued work
+        before each pair of relayouts, section 6), and still the degraded
+        rung's verdict."""
         user = [(8192, 176), (6144, 208), (8192, 152), (3072, 280), (8192, 128), (8192, 1)]
         item = [(239, 8768), (207, 10088), (180, 11608), (78, 26880), (8192, 1)]
         plan = capacity.plan_fit_chunked(user, item, 10_000_000, 1_000_000, 128, None, solver)
@@ -171,6 +174,47 @@ class TestPlans:
         verdict = capacity.admit(
             resident, degradable=True, budget=14_372_935_654, fallback_plan=plan)
         assert verdict.verdict == "degrade"
+
+    # The heaviest planner shapes of each configuration's sides (from its
+    # degree laws, ``benchmark.stars.degree_sequence``) and the parent's
+    # prices of them, to the byte: the four-array slab, the resident plan,
+    # and on four devices the replicated rung and the three row-sharded ones
+    # (``gh10m-r128-x4`` has ``gh10m-r128``'s shapes). Only the chunked rung
+    # holds the packed slab.
+    @pytest.mark.parametrize("name,user,item,sizes,solver,slabs,plans", [
+        ("ml25m-r128", [(1971, 1064), (4681, 448), (3013, 696), (8192, 240)],
+         [(103, 20320), (51, 40888), (118, 17664), (8192, 16)],
+         (325_082, 59_047, 128), "cg", 131_964_572,
+         (2_090_157_212, 817_550_247, 524_007_847, 647_325_332, 642_603_961)),
+        ("albedo-r50", [(7489, 280), (6393, 328), (8192, 240)],
+         [(51, 40888), (118, 17664), (22, 94592), (8192, 24)],
+         (450_000, 300_000, 50), "cg", 113_586_412,
+         (922_339_492, 455_584_823, 271_737_211, 324_096_426, 319_371_999)),
+        ("albedo-r50-chol", [(7489, 280), (6393, 328), (8192, 240)],
+         [(51, 40888), (118, 17664), (22, 94592), (8192, 24)],
+         (450_000, 300_000, 50), "cholesky", 113_586_412,
+         (2_440_930_932, 835_232_683, 271_737_211, 241_763_810, 237_039_383)),
+        ("gh10m-r128", [(8192, 176), (6144, 208), (8192, 152)],
+         [(78, 26880), (239, 8768), (11, 190280), (8192, 32)],
+         (10_000_000, 1_000_000, 128), "cg", 94_735_288,
+         (12_655_467_448, 11_611_866_862, 11_671_683_822, 7_373_835_949, 7_369_120_718)),
+    ])
+    def test_the_resident_and_sharded_plans_hold_the_four_array_slabs(
+            self, name, user, item, sizes, solver, slabs, plans):
+        assert sum(capacity.bucket_slab_bytes(b, ln) for b, ln in (*user, *item)) == slabs
+        assert all(capacity.bucket_slab_bytes(b, ln) == b * 4 + b * ln * 9 for b, ln in (*user, *item))
+        args = (user, item, *sizes)
+        kw = dict(gather_dtype=None, mode="allgather", solver=solver)
+        assert (
+            capacity.plan_fit(*args, None, solver=solver).required_bytes,
+            capacity.plan_fit(*args, gather_dtype=None, n_devices=4, solver=solver).required_bytes,
+            capacity.plan_fit_sharded(*args, 4, streamed=False, **kw).required_bytes,
+            capacity.plan_fit_sharded(*args, 4, streamed=True, pipelined=True, **kw).required_bytes,
+            capacity.plan_fit_sharded(*args, 4, streamed=True, pipelined=False, **kw).required_bytes,
+        ) == plans
+        # the chunked rung's slab, as it travels: a byte an entry less
+        assert all(capacity.stream_slab_bytes(b, ln) == capacity.bucket_slab_bytes(b, ln) - b * ln
+                   for b, ln in (*user, *item))
 
     @pytest.mark.parametrize("price,args", [
         (capacity.chunked_row_bytes, (8, 16)),

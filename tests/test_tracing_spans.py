@@ -342,14 +342,14 @@ def test_compiled_chunked_update_carries_the_landing_scope_beside_the_shared_one
     block write into the table held in dispatch order, under the scope names
     the row scatter had (``als.chunk.scatter`` > ``als.landing``), and the
     CG's warm start one slice of it under ``als.warm_start``."""
-    from albedo_tpu.ops.als import chunked_bucket_update
+    from albedo_tpu.ops.als import chunked_bucket_update, slab_entries
 
     sds = jax.ShapeDtypeStruct
     args = (sds((30, RANK), jnp.float32), sds((RANK, RANK), jnp.float32), sds((20, RANK), jnp.float32),
-            sds((6,), jnp.int32), sds((6, 16), jnp.int32), sds((6, 16), jnp.float32),
-            sds((6, 16), jnp.bool_), sds((), jnp.float32), sds((), jnp.float32))
+            sds((slab_entries(6, 16),), jnp.int32), sds((), jnp.float32), sds((), jnp.float32))
     compiled, _, source = persistent_aot_executable(
-        chunked_bucket_update, args, None, dict(solver=solver, cg_steps=3, gather_dtype=None),
+        chunked_bucket_update, args, None,
+        dict(shape=(6, 16), solver=solver, cg_steps=3, gather_dtype=None),
         key_parts=("test_tracing_spans", "chunked", solver), name="als_chunked",
         donate_argnums=(2,),
     )
@@ -376,7 +376,7 @@ def test_grown_gather_keeps_its_scope_and_stays_outside_the_solve(program):
     """A bucket gathered at a grown slot count (``ops.als.gather_slots``):
     the index padding belongs to ``als.gather``, the padding of what the solve
     reads and the cut back to the bucket's own slots to ``als.cg``."""
-    from albedo_tpu.ops.als import chunked_bucket_update, gather_slots
+    from albedo_tpu.ops.als import chunked_bucket_update, gather_slots, slab_entries
 
     if program == "fused":
         m = stars(seed=43)
@@ -387,10 +387,10 @@ def test_grown_gather_keeps_its_scope_and_stays_outside_the_solve(program):
         sds = jax.ShapeDtypeStruct
         assert gather_slots(128, 8) == 129
         args = (sds((30, RANK), jnp.float32), sds((RANK, RANK), jnp.float32), sds((200, RANK), jnp.float32),
-                sds((128,), jnp.int32), sds((128, 8), jnp.int32), sds((128, 8), jnp.float32),
-                sds((128, 8), jnp.bool_), sds((), jnp.float32), sds((), jnp.float32))
+                sds((slab_entries(128, 8),), jnp.int32), sds((), jnp.float32), sds((), jnp.float32))
         compiled, _, _ = persistent_aot_executable(
-            chunked_bucket_update, args, None, dict(solver="cg", cg_steps=3, gather_dtype=None),
+            chunked_bucket_update, args, None,
+            dict(shape=(128, 8), solver="cg", cg_steps=3, gather_dtype=None),
             key_parts=("test_tracing_spans", "chunked-grown"), name="als_chunked", donate_argnums=(2,),
         )
         text = compiled.as_text()
